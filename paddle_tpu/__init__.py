@@ -86,10 +86,9 @@ from . import device as device  # noqa: F401
 from . import hub  # noqa: F401
 from . import onnx  # noqa: F401
 
-# hot start: a boot with FLAGS_executable_cache_dir in the environment
-# gets the persistent executable cache configured BEFORE any compile
-# (model-init jnp programs included) — a no-op string compare when the
-# flag is empty (the compile seams re-check on runtime set_flags)
+# hot start: the persistent executable cache is configured BEFORE any
+# compile (model-init jnp programs included); the compile seams re-check
+# on runtime set_flags. Where it lives: jit.warmup.cache_dir()
 jit.warmup.ensure_executable_cache()
 
 

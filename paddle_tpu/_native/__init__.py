@@ -1,10 +1,16 @@
 """Native runtime loader: compiles native.cpp with the system toolchain on
-first import (cached as _paddle_native.so next to the source), mirroring
-the reference's compiled core (`paddle.base.core`). Falls back to None if
-no compiler is available — callers must degrade gracefully.
+first import, mirroring the reference's compiled core
+(`paddle.base.core`). The built library sits next to the source under a
+name keyed by a hash of native.cpp and the interpreter's ABI tag, so a
+binary built from other source or for another Python is never loaded;
+it is written atomically because fleet replicas import at the same
+time. ``lib`` is None if no compiler is available or the build fails —
+callers must degrade gracefully.
 """
 from __future__ import annotations
 
+import glob
+import hashlib
 import importlib.util
 import os
 import subprocess
@@ -13,14 +19,21 @@ import sysconfig
 
 _here = os.path.dirname(os.path.abspath(__file__))
 _src = os.path.join(_here, "native.cpp")
-_so = os.path.join(_here, "_paddle_native.so")
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    abi = sysconfig.get_config_var("SOABI") or "abi-unknown"
+    return os.path.join(_here, f"_paddle_native.{digest}.{abi}.so")
+
+
+def _build(so: str) -> bool:
     include = sysconfig.get_paths()["include"]
+    tmp = f"{so}.tmp.{os.getpid()}"
     cmd = [
         "g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-        f"-I{include}", _src, "-o", _so, "-lpthread",
+        f"-I{include}", _src, "-o", tmp, "-lpthread",
     ]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
@@ -31,15 +44,21 @@ def _build() -> bool:
         sys.stderr.write(
             f"paddle_tpu: native build failed:\n{proc.stderr[-2000:]}\n")
         return False
+    os.replace(tmp, so)  # atomic: a concurrent importer sees all or nothing
+    for stale in glob.glob(os.path.join(_here, "_paddle_native*.so")):
+        if stale != so:
+            try:
+                os.remove(stale)
+            except OSError:
+                pass
     return True
 
 
 def _load():
-    if not os.path.exists(_so) or (
-            os.path.getmtime(_so) < os.path.getmtime(_src)):
-        if not _build():
-            return None
-    spec = importlib.util.spec_from_file_location("_paddle_native", _so)
+    so = _so_path()
+    if not os.path.exists(so) and not _build(so):
+        return None
+    spec = importlib.util.spec_from_file_location("_paddle_native", so)
     try:
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)

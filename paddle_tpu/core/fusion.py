@@ -363,11 +363,8 @@ def _infer_aval(name, fn, descs, entries, attrs=None):
         eval_args = []
         for d, e in zip(descs, entries):
             if d[0] == "a":
-                try:
-                    s = jax.ShapeDtypeStruct(d[1], d[2], weak_type=d[3])
-                except TypeError:  # older jax: no weak_type kwarg
-                    s = jax.ShapeDtypeStruct(d[1], d[2])
-                eval_args.append(s)
+                eval_args.append(
+                    jax.ShapeDtypeStruct(d[1], d[2], weak_type=d[3]))
             else:
                 eval_args.append(e)  # python scalar, passed verbatim
         out = jax.eval_shape(fn, *eval_args)

@@ -1,51 +1,7 @@
 """Shared mesh-axis classification for the context-parallel attention
 paths (ring_attention / ulysses): conventional batch-like and head-like
-axis names pass through shard_map untouched on their natural dims.
-
-Also the ONE home of the ``shard_map`` symbol: jax moved it from
-``jax.experimental.shard_map`` to ``jax.shard_map`` and 0.4.37 ships a
-window where only the experimental spelling exists — every caller in
-this package (and the tests) imports the alias from here instead of
-betting on a jax version."""
+axis names pass through shard_map untouched on their natural dims."""
 from __future__ import annotations
-
-try:
-    from jax import shard_map as _shard_map_impl
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def _detect_check_kw():
-    """Which replication-check kwarg the resolved shard_map accepts —
-    decided by signature, not import location: some jax releases expose
-    the top-level name while still spelling the kwarg check_rep."""
-    import inspect
-    try:
-        params = inspect.signature(_shard_map_impl).parameters
-    except (TypeError, ValueError):
-        return "check_rep"  # conservative: the 0.4.x spelling
-    for kw in ("check_vma", "check_rep"):
-        if kw in params:
-            return kw
-    return None  # neither: drop the kwarg (it only tunes a safety check)
-
-
-_CHECK_KW = _detect_check_kw()
-
-
-def shard_map(f, *args, **kwargs):
-    """jax.shard_map / jax.experimental.shard_map compat shim: accepts
-    either spelling of the replication-check kwarg and forwards the one
-    the resident jax understands."""
-    if "check_vma" in kwargs:
-        check = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs:
-        check = kwargs.pop("check_rep")
-    else:
-        check = None
-    if check is not None and _CHECK_KW is not None:
-        kwargs[_CHECK_KW] = check
-    return _shard_map_impl(f, *args, **kwargs)
 
 BATCH_AXIS_NAMES = ("dp", "fsdp", "data", "sharding")
 HEAD_AXIS_NAMES = ("mp", "tp", "model")

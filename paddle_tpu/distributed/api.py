@@ -133,7 +133,7 @@ def dtensor_from_fn(fn, mesh: ProcessMesh,
 def _materialize_partial(t: Tensor, mesh: ProcessMesh,
                          placements: List[Placement]) -> Tensor:
     """psum away Partial placements so only Shard/Replicate remain."""
-    from ._mesh_axes import shard_map
+    from jax import shard_map
 
     partial_axes = [mesh.dim_names[i] for i, p in enumerate(placements)
                     if isinstance(p, Partial)]

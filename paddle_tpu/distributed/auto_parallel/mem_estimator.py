@@ -21,7 +21,7 @@ from __future__ import annotations
 
 from collections import defaultdict
 
-from jax._src import core as jcore
+from jax.extend import core as jcore
 
 __all__ = ["estimate_peak_bytes"]
 

@@ -114,7 +114,7 @@ def detect_cluster(probe: bool = False) -> Cluster:
         if len(devs) > 1:
             from jax.sharding import Mesh, PartitionSpec as P
             mesh = Mesh(np.array(devs), ("x",))
-            from .._mesh_axes import shard_map
+            from jax import shard_map
             g = jax.jit(shard_map(
                 lambda a: jax.lax.psum(a, "x"), mesh=mesh,
                 in_specs=P(), out_specs=P()))

@@ -341,7 +341,7 @@ def _cross_process(op_name, arr, group: Group, **kw):
     return this rank's result as a host numpy array
     (all_reduce -> arr.shape, all_gather -> (nranks,) + arr.shape)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from ._mesh_axes import shard_map
+    from jax import shard_map
 
     mesh = _group_mesh(tuple(group.ranks))
     arr = jnp.asarray(arr)

@@ -8,7 +8,9 @@ workers that exit with the restart code.
 
 TPU note: on a TPU pod each *host* is one worker (jax distributed
 single-process-per-host), so --nproc_per_node defaults to 1; the CPU-mesh
-test path uses --devices to emulate N single-chip workers.
+test path uses --devices to emulate N single-chip workers. With
+--nproc_per_node > 1 on a TPU host, worker i is pinned to chip i (a chip
+belongs to one process at a time).
 
 Pod bootstrap (the production multi-controller regime): every launched
 worker that calls ``paddle_tpu.distributed.init_parallel_env()`` brings
@@ -32,6 +34,7 @@ import sys
 import time
 from typing import List, Optional
 
+from ...core.device import env_wants_cpu, one_chip_env
 from ..elastic import ELASTIC_EXIT_CODE, ELASTIC_RESTART_CODE  # noqa: F401
 # (single source of truth for the 101/102 restart protocol —
 # ref: fleet/elastic/manager.py:33-34)
@@ -84,6 +87,10 @@ def _worker_env(args, local_rank: int, nproc: int) -> dict:
     if args.devices:
         devs = args.devices.split(",")
         env["PADDLE_VISIBLE_DEVICES"] = devs[local_rank % len(devs)]
+    if nproc > 1 and not env_wants_cpu(env):
+        # several workers on one TPU host: a chip belongs to one
+        # process, so worker i gets chip i and nothing else
+        env.update(one_chip_env(local_rank))
     return env
 
 

@@ -53,11 +53,8 @@ def init_parallel_env() -> Group:
             # reference picking ProcessGroupGloo for CPU places
             # (ref: parallel.py:978 _new_process_group_impl backend map)
             if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-                try:
-                    jax.config.update(
-                        "jax_cpu_collectives_implementation", "gloo")
-                except Exception:
-                    pass  # older jaxlib: option absent
+                jax.config.update(
+                    "jax_cpu_collectives_implementation", "gloo")
             jax.distributed.initialize(
                 coordinator_address=addr, num_processes=nranks,
                 process_id=rank)

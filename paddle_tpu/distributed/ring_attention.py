@@ -84,7 +84,9 @@ def ring_attention(q, k, v, mesh, axis: str = "sp", causal: bool = True,
     # full-manual shard_map: map the other mesh axes onto their
     # conventional dims (data axes -> batch, model axes -> heads) so dp/tp
     # shardings ride through instead of being all-gathered per device
-    from ._mesh_axes import classify_axes, shard_map
+    from jax import shard_map
+
+    from ._mesh_axes import classify_axes
     batch_axes, head_axes = classify_axes(jmesh, axis)
     spec = P(batch_axes or None, axis, head_axes or None, None)
     fn = shard_map(

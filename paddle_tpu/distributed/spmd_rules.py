@@ -781,7 +781,7 @@ def shard_map_flash_attention(mesh, q, k, v, *, batch_axis=None,
     def local(q_, k_, v_):
         return _fa(q_, k_, v_, causal, scale, dropout_p, seed)
 
-    from ._mesh_axes import shard_map
+    from jax import shard_map
     return shard_map(local, mesh=mesh, in_specs=in_specs,
                      out_specs=out_spec, check_vma=False)(q, k, v)
 
@@ -800,7 +800,7 @@ def shard_map_grouped_matmul(mesh, lhs, rhs, group_sizes, *,
     def local(l_, r_, gs_):
         return _gmm(l_, r_, gs_)
 
-    from ._mesh_axes import shard_map
+    from jax import shard_map
     return shard_map(local, mesh=mesh, in_specs=in_specs,
                      out_specs=out_spec, check_vma=False)(
         lhs, rhs, group_sizes)

@@ -55,7 +55,9 @@ def ulysses_attention(q, k, v, mesh, axis: str = "sp",
     jmesh = mesh.to_jax_mesh() if hasattr(mesh, "to_jax_mesh") else mesh
     sizes = dict(zip(jmesh.axis_names, jmesh.devices.shape))
     n = sizes[axis]
-    from ._mesh_axes import classify_axes, shard_map
+    from jax import shard_map
+
+    from ._mesh_axes import classify_axes
     batch_axes, head_axes = classify_axes(jmesh, axis)
     mp = 1
     for a in head_axes:

@@ -474,9 +474,11 @@ def serve(model_path: str, host: str = "127.0.0.1", port: int = 8866,
     replica SUBPROCESSES instead of one in-process engine: KV-
     pressure-aware placement, failover with bit-equal stream
     recovery, and warm-bundle resurrection of dead replicas (see
-    ``serving_fleet``). The replicas share this process's
-    ``FLAGS_executable_cache_dir`` and ``warm_bundle``, so a recycled
-    replica rejoins without a compile storm.
+    ``serving_fleet``). The replicas share this process's executable
+    cache directory and ``warm_bundle``, so a recycled replica rejoins
+    without a compile storm. The router process loads no model (on a
+    TPU host each replica child claims its own chip), so a fleet
+    serves /generate only and POST /run answers 404.
     """
     import io
     import threading
@@ -485,21 +487,27 @@ def serve(model_path: str, host: str = "127.0.0.1", port: int = 8866,
     from .core.flags import flag_value
     from .jit import warmup as _warmup
     _warmup.ensure_executable_cache()
-    predictor = Predictor(Config(model_path))
-    batcher = _MicroBatcher(predictor, max_batch=max_batch,
-                            window_ms=batch_window_ms)
+    batcher = None
     gen_server = None
     fleet_router = None
     if warm_bundle is None:
         warm_bundle = flag_value("warmup_bundle") or None
     if generate and int(fleet) >= 2:
+        # the router process loads no model: a chip belongs to one
+        # process, and each replica child claims its own (a Predictor
+        # here would initialise the backend and hold them all). The
+        # fleet therefore serves /generate only.
         from .serving_fleet import spawn_fleet
         fleet_router = spawn_fleet(int(fleet), {
             "model": {"kind": "inference_model", "path": model_path},
             "max_slots": max_slots, "max_seq": max_seq, "int8": int8,
             "eos_id": eos_id, "warm_bundle": warm_bundle,
             "supervised": True})
-    elif generate:
+    else:
+        predictor = Predictor(Config(model_path))
+        batcher = _MicroBatcher(predictor, max_batch=max_batch,
+                                window_ms=batch_window_ms)
+    if generate and fleet_router is None:
         from .serving import GenerationServer, PagedLlamaDecodeEngine
         # reuse the predictor's already-loaded Layer (a second
         # load_inference_model would hold the weights twice at startup)
@@ -544,6 +552,11 @@ def serve(model_path: str, host: str = "127.0.0.1", port: int = 8866,
             if self.path == "/generate" and gen_server is None \
                     and fleet_router is None:
                 msg = b"serve(generate=True) not enabled"
+            elif self.path == "/run" and batcher is None:
+                msg = b"serve(fleet=N) serves /generate only"
+            else:
+                msg = None
+            if msg is not None:
                 self.send_response(404)
                 self.send_header("Content-Length", str(len(msg)))
                 self.end_headers()

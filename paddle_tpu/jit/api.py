@@ -32,8 +32,7 @@ def _notify_build(kind: str) -> None:
     from ..observability import flight as _flight
     from .warmup import ensure_executable_cache
     # every whole-step (re)build is about to jit-compile: make sure the
-    # persistent executable cache is configured first (one flag read
-    # when off; builds are rare)
+    # persistent executable cache is configured first (builds are rare)
     ensure_executable_cache()
     _flight.record("jit", "build", kind=kind)
     obs = _build_observer

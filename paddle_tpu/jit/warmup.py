@@ -8,19 +8,21 @@ replica paid full retrace+compile before its first useful step (the
 ~2.9ms vs ~305ms gap on the capture bench). This module closes that
 gap in two layers:
 
-- **Persistent executable cache** (``FLAGS_executable_cache_dir``):
-  wires JAX's persistent compilation cache under every ``jax.jit`` the
-  framework issues, so compiled XLA artifacts live on DISK keyed by
-  program content — a restarted process re-traces (cheap Python) but
-  never re-compiles a program any earlier process already built.
-  :func:`ensure_executable_cache` is called from the compile-issuing
-  seams (CapturedStep builds, ``capture_jit``, fusion programs, the
-  fused optimizer step, ``jit.api`` builds, inference predictors) and
-  from ``paddle_tpu`` import, so enabling the flag — by env var before
-  boot or ``set_flags`` at runtime — covers everything after it.
-  Counters ``executable_cache.{hits,misses,writes}_total`` are
-  installed ONLY when the flag is set; the flags-off path is one
-  string compare.
+- **Persistent executable cache**: wires JAX's persistent compilation
+  cache under every ``jax.jit`` the framework issues, so compiled XLA
+  artifacts live on DISK keyed by program content — a restarted
+  process re-traces (cheap Python) but never re-compiles a program any
+  earlier process already built. The directory is chosen by
+  :func:`cache_dir`: ``JAX_COMPILATION_CACHE_DIR`` from the
+  environment wins (JAX reads it itself; this module then never sets
+  the directory), else ``FLAGS_executable_cache_dir``, else one fixed
+  ``.jax_cache`` beside the package — never a temporary name, because
+  the path is part of the cache key. :func:`ensure_executable_cache`
+  is called from the compile-issuing seams (CapturedStep builds,
+  ``capture_jit``, fusion programs, the fused optimizer step,
+  ``jit.api`` builds, inference predictors) and from ``paddle_tpu``
+  import; it lowers JAX's thresholds so every program is cached and
+  installs the counters ``executable_cache.{hits,misses,writes}_total``.
 
 - **Warm bundle + boot pre-warm** (``FLAGS_warmup_bundle``): the
   compile-issuing seams also :func:`note_program` the signature of
@@ -55,9 +57,9 @@ from ..observability import flight as _flight
 from ..observability import metrics as _om
 from ..utils import fault_injection as _fi
 
-__all__ = ["ensure_executable_cache", "cache_stats", "note_program",
-           "recorded", "clear_recorded", "export_bundle", "load_bundle",
-           "prewarm", "gc_cache_dir", "BUNDLE_VERSION"]
+__all__ = ["ensure_executable_cache", "cache_dir", "cache_stats",
+           "note_program", "recorded", "clear_recorded", "export_bundle",
+           "load_bundle", "prewarm", "gc_cache_dir", "BUNDLE_VERSION"]
 
 define_flag(
     "executable_cache_dir", "",
@@ -65,9 +67,9 @@ define_flag(
     "the framework issues (captured steps, SOT segments, fusion "
     "programs, fused optimizer steps, serving decode/prefill/spec "
     "executables) writes/reads disk-backed compiled artifacts there, "
-    "so a restarted process re-traces but does not re-compile. Empty "
-    "(default) = off. Counters executable_cache.{hits,misses,writes}"
-    "_total are live only while enabled")
+    "so a restarted process re-traces but does not re-compile. "
+    "JAX_COMPILATION_CACHE_DIR in the environment overrides it; empty "
+    "(default) = the fixed .jax_cache directory beside the package")
 define_flag(
     "warmup_bundle", "",
     "Default warm-bundle manifest path for boot pre-warm: consumers "
@@ -84,6 +86,11 @@ define_flag(
     "via warmup.gc_cache_dir(). 0 (default) = never evict")
 
 _dir_flag = _flag_registry["executable_cache_dir"]
+# <checkout>/.jax_cache: fixed by the package's own location (the path is
+# part of the cache key, so it must not move between runs)
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 _bundle_flag = _flag_registry["warmup_bundle"]
 
 BUNDLE_VERSION = 1
@@ -115,56 +122,58 @@ _M_failures = _W.counter(
     "Warm-bundle failures by reason (missing/corrupt/version/program) "
     "— every one degrades to cold compile, never a boot failure")
 
-# enable-once state: the configured dir (None = cache off) and whether
-# the counting wrappers are installed (they stay installed; the flag
-# re-check inside them is not needed because a disabled cache never
-# reaches the wrapped functions)
+# enable-once state: the configured dir and whether the counting
+# wrappers are installed (they stay installed)
 _state: Dict[str, Any] = {"dir": None, "wrapped": False}
 
 
-def ensure_executable_cache() -> bool:
-    """Configure JAX's persistent compilation cache from
-    ``FLAGS_executable_cache_dir``; returns True while enabled. Called
-    from every compile-issuing seam (and ``paddle_tpu`` import) — the
-    flags-off path is one cached flag read + string compare. Flipping
-    the flag at runtime reconfigures on the next compile."""
-    d = str(_dir_flag.value or "").strip() or None
+def _env_dir() -> str:
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+
+
+def cache_dir() -> str:
+    """Where compiled programs persist: ``JAX_COMPILATION_CACHE_DIR``
+    if the environment sets it, else ``FLAGS_executable_cache_dir``,
+    else the fixed ``.jax_cache`` beside the package."""
+    return _env_dir() or str(_dir_flag.value or "").strip() or _DEFAULT_DIR
+
+
+def ensure_executable_cache() -> str:
+    """Configure JAX's persistent compilation cache at :func:`cache_dir`
+    and return that directory. Called from every compile-issuing seam
+    (and ``paddle_tpu`` import) — the steady-state path is one
+    environment read + string compare. Flipping the flag at runtime
+    reconfigures on the next compile. With ``JAX_COMPILATION_CACHE_DIR``
+    set JAX reads the directory itself and this function never sets it;
+    the thresholds and counters are installed either way."""
+    d = cache_dir()
     if _state["dir"] == d:
-        return d is not None
+        return d
     import jax
     from jax._src import compilation_cache as _cc
-    if d is None:
-        jax.config.update("jax_compilation_cache_dir", None)
-    else:
+    if not _env_dir():
         os.makedirs(d, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", d)
-        # cache EVERY program: the framework's small per-step/decode
-        # executables are exactly what a restarted replica re-pays, and
-        # jax's defaults (>=1s compile time) would skip all of them
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-        if not _state["wrapped"]:
-            _install_counters(_cc)
-            _state["wrapped"] = True
-    try:
-        # clear the checked-once latch: a compile that ran BEFORE the
-        # flag was set (model init) must not pin the cache off forever
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — cache config is best-effort
-        pass
+    # cache EVERY program: the framework's small per-step/decode
+    # executables are exactly what a restarted replica re-pays, and
+    # jax's defaults (>=1s compile time) would skip all of them
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    if not _state["wrapped"]:
+        _install_counters(_cc)
+        _state["wrapped"] = True
+    # clear the checked-once latch: a compile that ran BEFORE this call
+    # must not pin the cache to the directory (or the off state) it saw
+    _cc.reset_cache()
     _state["dir"] = d
-    _flight.record("warmup", "cache_configured", dir=d or "<off>")
-    if d is not None:
-        # opportunistic age GC: reconfiguration is the natural "a
-        # replica just booted against this dir" moment, and it is
-        # cold-path (the checked-once latch above guards the hot one)
-        try:
-            gc_cache_dir(directory=d)
-        except Exception:  # noqa: BLE001 — GC must never block boot
-            pass
-    return d is not None
+    _flight.record("warmup", "cache_configured", dir=d)
+    # opportunistic age GC: reconfiguration is the natural "a replica
+    # just booted against this dir" moment, and it is cold-path
+    try:
+        gc_cache_dir(directory=d)
+    except Exception:  # noqa: BLE001 — GC must never block boot
+        pass
+    return d
 
 
 def gc_cache_dir(max_age_days: Optional[float] = None,
@@ -183,8 +192,8 @@ def gc_cache_dir(max_age_days: Optional[float] = None,
         age = float(max_age_days)
     except (TypeError, ValueError):
         return 0
-    d = directory or (str(_dir_flag.value or "").strip() or None)
-    if not d or age <= 0:
+    d = directory or cache_dir()
+    if age <= 0:
         return 0
     cutoff = time.time() - age * 86400.0
     try:

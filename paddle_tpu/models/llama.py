@@ -91,6 +91,36 @@ def _apply_rope(x, cos, sin):
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+def _causal_flash(mesh, q, k, v):
+    """Causal flash attention, [B, L, H, D], under a (possibly sharded)
+    train step. GSPMD cannot partition a Mosaic kernel (jax refuses at
+    lowering: "wrap the call in a shard_map"), so where parameters live
+    on a mesh and the kernel applies, it runs per shard inside shard_map:
+    batch over the data-like mesh axes that divide it, heads over the
+    model-like ones (the `flash_attention` SPMD rule: no collectives).
+    Where the kernel does not apply (CPU mesh tests, untileable shapes)
+    the XLA reference runs and GSPMD partitions it by itself."""
+    from ..ops.pallas import flash_attention as fa
+    b, l, h, d = q.shape
+    if mesh is None or mesh.size == 1 or not fa._use_pallas(l, d):
+        return fa.flash_attention(q, k, v, True, None)
+    from ..distributed._mesh_axes import classify_axes
+    from ..distributed.spmd_rules import shard_map_flash_attention
+
+    def dividing(axes, n):
+        keep, ways = [], 1
+        for a in axes:
+            if n % (ways * mesh.shape[a]) == 0:
+                keep.append(a)
+                ways *= mesh.shape[a]
+        return tuple(keep) or None
+
+    batch_axes, head_axes = classify_axes(mesh, None)
+    return shard_map_flash_attention(
+        mesh, q, k, v, batch_axis=dividing(batch_axes, b),
+        head_axis=dividing(head_axes, h), causal=True)
+
+
 class LlamaAttention(Layer):
     """GQA attention with RoPE; the sdpa is the Pallas flash kernel when
     tiling allows (ref: LlamaAttention in semi_auto_parallel_llama_model.py)."""
@@ -98,6 +128,9 @@ class LlamaAttention(Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
         self.config = config
+        # the jax Mesh the parameters are sharded over (shard_llama sets
+        # it): the flash kernel must then run inside shard_map
+        self.mesh = None
         self.hidden_size = config.hidden_size
         self.num_heads = config.num_attention_heads
         self.num_kv_heads = config.num_key_value_heads
@@ -140,8 +173,7 @@ class LlamaAttention(Layer):
             if rep > 1:
                 ka = jnp.repeat(ka, rep, axis=2)
                 va = jnp.repeat(va, rep, axis=2)
-            from ..ops.pallas.flash_attention import (_sdpa_xla,
-                                                      flash_attention)
+            from ..ops.pallas.flash_attention import _sdpa_xla
             if (self.config.cp_mesh is not None and not cache_arrs
                     and attention_mask is None):
                 from ..distributed.ring_attention import ring_attention
@@ -149,7 +181,7 @@ class LlamaAttention(Layer):
                                      self.config.cp_axis, causal=True)
             elif (not cache_arrs and attention_mask is None
                     and self.config.use_flash_attention):
-                out = flash_attention(qa, ka, va, True, None)
+                out = _causal_flash(self.mesh, qa, ka, va)
             else:
                 # decode (Lq < Lk) and/or explicit-mask path
                 out = _sdpa_xla(qa, ka, va, causal=True,
@@ -322,25 +354,6 @@ class LlamaForCausalLM(Layer):
         return ids
 
 
-@jax.custom_vjp
-def _grad_safe_barrier(lg, lb):
-    return jax.lax.optimization_barrier((lg, lb))
-
-
-def _grad_safe_barrier_fwd(lg, lb):
-    return jax.lax.optimization_barrier((lg, lb)), None
-
-
-def _grad_safe_barrier_bwd(_, ct):
-    return ct
-
-
-# optimization_barrier has no differentiation rule in jax 0.4.37; the
-# barrier only orders the forward dependency chain, so the cotangents
-# pass through untouched
-_grad_safe_barrier.defvjp(_grad_safe_barrier_fwd, _grad_safe_barrier_bwd)
-
-
 class LlamaPretrainingCriterion(Layer):
     """Causal-LM loss: shifted next-token cross entropy
     (ref: LlamaPretrainingCriterion in semi_auto_parallel_llama_model.py)."""
@@ -360,7 +373,7 @@ class LlamaPretrainingCriterion(Layer):
             # collective chain and can race it on the XLA:CPU in-process
             # rendezvous (deadlock in the CP dryrun); on TPU the labels
             # are tiny and the barrier costs nothing
-            lg, lb = _grad_safe_barrier(lg, lb)
+            lg, lb = jax.lax.optimization_barrier((lg, lb))
             # shift the LABELS (tiny int array), not the logits: slicing
             # lg[:, :-1] copies the whole [B, L, V] tensor (262 MB at
             # the 1B-scale geometry) and leaves an odd L-1 chunk size;
@@ -394,6 +407,9 @@ def shard_llama(model: LlamaForCausalLM, mesh, tp_axis: Optional[str] = "mp",
     """
     from ..distributed.api import shard_parameter
 
+    for layer in model.sublayers():
+        if isinstance(layer, LlamaAttention):
+            layer.mesh = mesh.to_jax_mesh()
     for name, p in model.named_parameters():
         if p is None:
             continue

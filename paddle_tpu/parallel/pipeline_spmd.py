@@ -22,9 +22,8 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from ..distributed._mesh_axes import shard_map
 
 __all__ = ["spmd_pipeline", "spmd_pipeline_interleaved",
            "stack_layer_params", "remat_policy"]

@@ -726,9 +726,10 @@ def write_kv_tokens(pool, phys, off, vals):
 _KERNEL_Q_VMEM_BUDGET = 4 * 1024 * 1024
 
 
-def use_kernel_default() -> bool:
+def use_kernel_default(head_dim: int) -> bool:
     """The seam's path decision: the Pallas block-table kernel when
-    ``FLAGS_paged_attention_kernel`` is on AND the backend supports it;
+    ``FLAGS_paged_attention_kernel`` is on AND the backend and the head
+    width support it (``ops.pallas.paged_attention.kernel_available``);
     the pure-jnp tiled walk (the numerics oracle) otherwise. One
     function so engines can count the live path per step without
     re-deriving the policy."""
@@ -736,7 +737,7 @@ def use_kernel_default() -> bool:
     if not flag_value("paged_attention_kernel"):
         return False
     from .ops.pallas import paged_attention as _pk
-    return _pk.kernel_available()
+    return _pk.kernel_available(head_dim)
 
 
 def paged_attention(q, k_pool, v_pool, tables, positions, *,
@@ -766,14 +767,14 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
 
     ``use_kernel`` selects the implementation behind this ONE seam:
     None (default) follows ``FLAGS_paged_attention_kernel`` + backend
-    availability, True forces the Pallas TPU kernel
+    and head-width availability, True forces the Pallas TPU kernel
     (``ops.pallas.paged_attention``), False forces the jnp walk below
     — which stays the numerics ORACLE the kernel is parity-pinned
     against (tests/test_serving_spec.py runs the kernel through the
     Pallas interpreter on CPU and asserts same-numerics).
     """
     if use_kernel is None:
-        use_kernel = use_kernel_default()
+        use_kernel = use_kernel_default(q.shape[3])
     if use_kernel and q.shape[1] * q.shape[2] * q.shape[3] * 4 \
             > _KERNEL_Q_VMEM_BUDGET:
         # the kernel's f32 accumulator scratch (and its q/out tiles)
@@ -782,6 +783,8 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
         # un-chunked whole-prompt prefill can exceed per-core VMEM —
         # those calls take the jnp walk, same numerics
         use_kernel = False
+    from .ops.pallas import count_path
+    count_path("paged_attention", "pallas" if use_kernel else "jnp_walk")
     if use_kernel:
         from .ops.pallas import paged_attention as _pk
         return _pk.paged_attention_kernel(

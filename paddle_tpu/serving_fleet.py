@@ -73,6 +73,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .core.device import env_wants_cpu, one_chip_env
 from .core.flags import flag_value
 from .observability import flight as _flight
 from .observability import metrics as _om
@@ -504,15 +505,42 @@ def replica_main(config: dict) -> None:
         time.sleep(0.2)
 
 
+def _parent_holds_tpu() -> bool:
+    """True when THIS process has already initialised JAX's TPU
+    backend — it then owns every chip of the host, and a child that
+    needs one fails or hangs. Never imports or initialises anything."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized() \
+        and jax.default_backend() == "tpu"
+
+
 def launch_replica(config: dict, env: Optional[dict] = None,
-                   timeout: float = 300.0):
+                   timeout: float = 300.0, chip: int = 0):
     """Spawn one replica subprocess (``python -m
     paddle_tpu.serving_fleet``, config via env) and block for its boot
-    line. Returns ``(proc, port, boot)``."""
+    line. Returns ``(proc, port, boot)``.
+
+    The child inherits this process's environment plus ``env``; the
+    platform is whatever that names (tests run with ``JAX_PLATFORMS=
+    cpu`` and the children inherit it). Unless the child is told
+    ``cpu``, it is pinned to chip ``chip`` of the host through
+    libtpu's own variables — a chip belongs to one process — and the
+    call raises if this process already holds the chips."""
     child_env = dict(os.environ)
-    child_env.setdefault("JAX_PLATFORMS", "cpu")
     if env:
         child_env.update(env)
+    if not env_wants_cpu(child_env):
+        if _parent_holds_tpu():
+            raise RuntimeError(
+                f"launch_replica: this process has initialised the JAX "
+                f"TPU backend and holds the host's chips, so replica "
+                f"{chip} cannot claim one. Spawn the fleet from a "
+                f"process that has not touched JAX, or pass "
+                f"env={{'JAX_PLATFORMS': 'cpu'}} for a CPU fleet")
+        child_env.update(one_chip_env(chip))
     child_env["PADDLE_TPU_REPLICA_CONFIG"] = json.dumps(config)
     proc = subprocess.Popen(
         [sys.executable, "-m", "paddle_tpu.serving_fleet"],
@@ -1181,11 +1209,11 @@ def spawn_fleet(n: int, replica_config: dict,
     """Launch ``n`` replica subprocesses from one config (sharing the
     executable cache + warm bundle the config names) and return the
     router over them, with resurrection wired to relaunch from the
-    same config."""
+    same config. On a TPU host replica ``i`` runs on chip ``i``."""
     def make_spawn(idx: int):
         def spawn(_idx: int) -> ReplicaHandle:
             proc, port, _boot = launch_replica(dict(replica_config),
-                                               env=env)
+                                               env=env, chip=idx)
             return ReplicaHandle(idx, "127.0.0.1", port,
                                  pid=proc.pid, proc=proc, spawn=spawn)
         return spawn
@@ -1194,7 +1222,7 @@ def spawn_fleet(n: int, replica_config: dict,
     for i in range(int(n)):
         spawn = make_spawn(i)
         proc, port, _boot = launch_replica(dict(replica_config),
-                                           env=env)
+                                           env=env, chip=i)
         handles.append(ReplicaHandle(i, "127.0.0.1", port,
                                      pid=proc.pid, proc=proc,
                                      spawn=spawn))

@@ -357,7 +357,7 @@ class TestBucketedOverlapProgram:
         LAST depends on (almost) all — the DAG independence that
         lets async collectives overlap remaining backward compute."""
         import re
-        import jax.core as jcore
+        import jax.extend.core as jcore
         build, ids = fsdp_llama
 
         prev = paddle.get_flags("FLAGS_dist_grad_bucket_bytes")

@@ -3,7 +3,7 @@ boot pre-warm, and zero-downtime weight hot-swap.
 
 Three planes pinned here:
 
-- **Executable cache** (``FLAGS_executable_cache_dir``): compiled XLA
+- **Executable cache** (``jit.warmup.cache_dir()``): compiled XLA
   artifacts persist on disk; a poisoned entry degrades to a counted
   miss + recompile, never a crash. The acceptance scenario runs TWO
   real processes against one cache dir + bundle: the second reaches
@@ -129,11 +129,41 @@ def _pool_invariants(kv):
 # ---------------------------------------------------------------------------
 
 class TestExecutableCache:
-    def test_flag_off_is_noop(self, module_cache):
-        paddle.set_flags({"FLAGS_executable_cache_dir": ""})
+    def test_directory_rules(self, module_cache, tmp_path, monkeypatch):
+        """Where the cache lives, in order: JAX_COMPILATION_CACHE_DIR
+        from the environment (JAX reads it itself; the program then
+        never sets the directory, whatever the flag says), else
+        FLAGS_executable_cache_dir, else ONE fixed .jax_cache beside
+        the package. Counters and thresholds are installed each way."""
+        import jax
+        updates = []
+        real_update = jax.config.update
+
+        def spy(name, value):
+            updates.append(name)
+            real_update(name, value)
+
+        env_dir, flag_dir = str(tmp_path / "env"), str(tmp_path / "flag")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         try:
-            assert warmup.ensure_executable_cache() is False
+            # 3. neither: fixed, derived from the package's location
+            paddle.set_flags({"FLAGS_executable_cache_dir": ""})
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert warmup.cache_dir() == os.path.join(repo, ".jax_cache")
+            assert warmup.cache_dir() == warmup.cache_dir()
+            # 2. the flag
+            paddle.set_flags({"FLAGS_executable_cache_dir": flag_dir})
+            assert warmup.ensure_executable_cache() == flag_dir
+            assert jax.config.jax_compilation_cache_dir == flag_dir
+            # 1. the environment wins and the program sets nothing
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+            monkeypatch.setattr(jax.config, "update", spy)
+            assert warmup.ensure_executable_cache() == env_dir
+            assert "jax_compilation_cache_dir" not in updates
+            assert "jax_persistent_cache_min_compile_time_secs" in updates
+            assert not os.path.exists(env_dir)   # JAX's to create
         finally:
+            monkeypatch.undo()
             paddle.set_flags(
                 {"FLAGS_executable_cache_dir": module_cache})
             warmup.ensure_executable_cache()

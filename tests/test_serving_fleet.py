@@ -24,6 +24,7 @@ pinned at 0) is slow-marked.
 """
 from __future__ import annotations
 
+import io
 import json
 import os
 import signal
@@ -489,6 +490,62 @@ class TestFlakyTransport:
         finally:
             if child.poll() is None:
                 child.kill()
+
+
+class TestReplicaEnvironment:
+    """launch_replica decides nothing about the platform: the child
+    gets this process's environment plus ``env``. Only a child that is
+    NOT told ``cpu`` is pinned to one chip — and refused if this
+    process already holds the chips. Popen is faked: no process."""
+
+    @pytest.fixture
+    def spawned(self, monkeypatch):
+        from paddle_tpu import serving_fleet as sf
+        envs = []
+
+        class FakeProc:
+            pid, returncode = 4242, None
+
+            def __init__(self):
+                self.stdout = io.StringIO(
+                    '{"ok": true, "port": 1, "pid": 4242}\n')
+
+            def poll(self):
+                return None
+
+        def fake_popen(cmd, env=None, **kw):
+            envs.append(env)
+            return FakeProc()
+
+        monkeypatch.setattr(sf.subprocess, "Popen", fake_popen)
+        return envs
+
+    def test_cpu_parent_env_is_inherited_not_added(self, spawned,
+                                                   monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")   # as conftest does
+        launch_replica({"model": {}}, chip=3)
+        assert spawned[0]["JAX_PLATFORMS"] == "cpu"
+        assert "TPU_VISIBLE_CHIPS" not in spawned[0]
+
+    def test_unset_platform_stays_unset_and_pins_chip_i(self, spawned,
+                                                        monkeypatch):
+        monkeypatch.delenv("JAX_PLATFORMS")
+        launch_replica({"model": {}}, chip=3)
+        assert "JAX_PLATFORMS" not in spawned[0]
+        assert spawned[0]["TPU_VISIBLE_CHIPS"] == "3"
+        assert spawned[0]["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+    def test_parent_holding_the_chips_is_refused(self, spawned,
+                                                 monkeypatch):
+        from paddle_tpu import serving_fleet as sf
+        monkeypatch.delenv("JAX_PLATFORMS")
+        monkeypatch.setattr(sf, "_parent_holds_tpu", lambda: True)
+        with pytest.raises(RuntimeError, match="holds the host's chips"):
+            launch_replica({"model": {}})
+        assert spawned == []
+        # an explicit CPU fleet from the same parent is fine
+        launch_replica({"model": {}}, env={"JAX_PLATFORMS": "cpu"})
+        assert len(spawned) == 1
 
 
 # ---------------------------------------------------------------------------

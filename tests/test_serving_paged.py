@@ -174,6 +174,7 @@ class TestPagedVsDense:
         dimension. max_seq=48 is chosen to collide with no other
         dimension of this geometry."""
         import jax
+        import jax.extend.core as jcore
         import jax.numpy as jnp
 
         max_seq = 48
@@ -197,9 +198,9 @@ class TestPagedVsDense:
                 for p in eqn.params.values():
                     for sub in (p if isinstance(p, (list, tuple))
                                 else [p]):
-                        if isinstance(sub, jax.core.Jaxpr):
+                        if isinstance(sub, jcore.Jaxpr):
                             walk(sub)
-                        elif isinstance(sub, jax.core.ClosedJaxpr):
+                        elif isinstance(sub, jcore.ClosedJaxpr):
                             walk(sub.jaxpr)
 
         walk(jaxpr.jaxpr)
@@ -400,12 +401,17 @@ class TestServerInterleave:
 
         eng.step = slow_step
         srv = GenerationServer(eng)
+        # compile prefill (bucket 8) + decode BEFORE any deadline clock
+        # starts: the deadlines below race 50 ms steps, not XLA
+        srv.generate([1, 2, 3], 2, timeout=120)
         blocker = srv.submit([1, 2, 3], 25)        # hogs slot + blocks
         starved = srv.submit([9, 8], 8, deadline=0.3)
         assert starved["done"].wait(60)
         assert isinstance(starved["error"], TimeoutError)
-        active = srv.submit(list(range(1, 6)), 24, deadline=1.2)
         assert blocker["done"].wait(120) and blocker["error"] is None
+        # admitted at once (the slot is free); 24 steps x 50 ms cannot
+        # finish inside the deadline, so it expires while ACTIVE
+        active = srv.submit(list(range(1, 6)), 24, deadline=0.6)
         assert active["done"].wait(120)
         assert isinstance(active["error"], TimeoutError)
         assert len(active["out"]) >= 1             # partials retained

@@ -288,9 +288,6 @@ class TestPagedAttentionKernelSeam:
 
     def test_kernel_matches_jnp_walk_on_every_geometry(self):
         from paddle_tpu.ops.pallas import paged_attention as pk
-        if not pk._HAS_PALLAS:
-            pytest.skip("Pallas unavailable — jnp walk is the only "
-                        "path (skipped, not failed)")
         import jax.numpy as jnp
         from paddle_tpu.serving_cache import paged_attention
         for i, geo in enumerate(self._geometries()):
@@ -313,8 +310,6 @@ class TestPagedAttentionKernelSeam:
         exactly zero (finite output, bit-matching the jnp walk's
         sanitized result)."""
         from paddle_tpu.ops.pallas import paged_attention as pk
-        if not pk._HAS_PALLAS:
-            pytest.skip("Pallas unavailable")
         import jax.numpy as jnp
         from paddle_tpu.serving_cache import paged_attention
         rng = np.random.default_rng(9)
@@ -345,20 +340,47 @@ class TestPagedAttentionKernelSeam:
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                    rtol=1e-6, atol=1e-6)
 
-    def test_flag_kills_kernel_path(self):
-        """FLAGS_paged_attention_kernel=0 forces the jnp walk
-        everywhere regardless of backend."""
-        from paddle_tpu.serving_cache import use_kernel_default
+    def test_seam_chooses_by_platform_flag_and_head_width(self,
+                                                          monkeypatch):
+        """The seam's choice, from what it can observe. On a TPU the
+        kernel runs where head_dim fills whole 128-lane registers and
+        the jnp walk runs below that — Mosaic refuses the kernel's
+        [bs, KVH*D] -> [bs, KVH, D] view at head_dim 16/32/64 (rule
+        found by compiling against a v5e topology; see
+        ops.pallas.paged_attention.kernel_available). Off the TPU, or
+        with FLAGS_paged_attention_kernel=0, always the walk. The walk
+        taken is counted."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu import serving_cache as sc
+        from paddle_tpu.observability import metrics as om
+
+        assert sc.use_kernel_default(128) is False        # CPU host
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert [d for d in (16, 32, 64, 128, 256)
+                if sc.use_kernel_default(d)] == [128, 256]
         paddle.set_flags({"FLAGS_paged_attention_kernel": 0})
         try:
-            assert use_kernel_default() is False
+            assert sc.use_kernel_default(128) is False
         finally:
             paddle.set_flags({"FLAGS_paged_attention_kernel": 1})
+        # a tiny-width engine on the "TPU" takes the walk, and says so
+        walk = om.default_registry().get("pallas.path_selected_total")
+        before = walk.value(kernel="paged_attention", path="jnp_walk")
+        S, T, H, K, D, bs, MB = 1, 1, 2, 1, 16, 8, 2
+        q = jnp.ones((S, T, H, D), jnp.float32)
+        pool = jnp.ones((MB, bs, K, D), jnp.float32)
+        out = sc.paged_attention(
+            q, pool, pool, jnp.arange(MB, dtype=jnp.int32)[None],
+            jnp.full((S, T), 5, jnp.int32), block_size=bs, n_rep=H // K)
+        np.testing.assert_allclose(np.asarray(out), 1.0, rtol=1e-6)
+        assert walk.value(kernel="paged_attention",
+                          path="jnp_walk") == before + 1
 
 
 class TestJaxprPins:
     def _walk_shapes(self, jaxpr):
-        import jax
+        import jax.extend.core as jcore
         shapes = []
 
         def walk(jx):
@@ -370,9 +392,9 @@ class TestJaxprPins:
                 for p in eqn.params.values():
                     for sub in (p if isinstance(p, (list, tuple))
                                 else [p]):
-                        if isinstance(sub, jax.core.Jaxpr):
+                        if isinstance(sub, jcore.Jaxpr):
                             walk(sub)
-                        elif isinstance(sub, jax.core.ClosedJaxpr):
+                        elif isinstance(sub, jcore.ClosedJaxpr):
                             walk(sub.jaxpr)
 
         walk(jaxpr.jaxpr)
@@ -429,11 +451,8 @@ class TestJaxprPins:
         import jax
         import jax.numpy as jnp
         from paddle_tpu import serving_cache
-        from paddle_tpu.ops.pallas import paged_attention as pk
-        if not pk._HAS_PALLAS:
-            pytest.skip("Pallas unavailable")
         monkeypatch.setattr(serving_cache, "use_kernel_default",
-                            lambda: True)
+                            lambda head_dim: True)
         max_seq = 48
         eng = PagedLlamaDecodeEngine(model, max_slots=3,
                                      max_seq=max_seq, block_size=16)

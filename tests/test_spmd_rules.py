@@ -383,9 +383,9 @@ class TestGatherAvoidsGspmdReplicate:
         def local(t_, i_):
             return jnp.take(t_, i_, axis=0)
 
-        from paddle_tpu.distributed._mesh_axes import shard_map
+        from jax import shard_map
         f = jax.jit(shard_map(local, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_spec, check_vma=False))
+                              out_specs=out_spec, check_vma=False))
         tr = jax.device_put(table, NamedSharding(mesh, P(None, None)))
         ids = jax.device_put(jnp.asarray(ids_np),
                              NamedSharding(mesh, P("dp")))
@@ -400,3 +400,58 @@ class TestGatherAvoidsGspmdReplicate:
         assert out.addressable_shards[0].data.shape[0] == 4
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(table)[ids_np])
+
+
+class TestShardedLlamaWrapsFlashKernel:
+    """GSPMD cannot partition a Mosaic kernel: jax refuses at lowering
+    ("wrap the call in a shard_map"), which the CPU mesh never shows
+    because the kernel does not run there. So a Llama sharded by
+    shard_llama calls the flash kernel through the `flash_attention`
+    rule's shard_map, batch on the data-like axes and heads on the
+    model-like ones. Trace only (the platform test is faked; nothing
+    is lowered for Mosaic)."""
+
+    def test_kernel_sits_inside_shard_map_only_when_sharded(
+            self, monkeypatch):
+        import paddle_tpu as paddle
+        from paddle_tpu.distributed import ProcessMesh
+        from paddle_tpu.jit.api import functionalize
+        from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
+                                       shard_llama)
+        from paddle_tpu.ops.pallas import flash_attention as fa
+        monkeypatch.setattr(fa, "_use_pallas", lambda l, d: True)
+
+        def trace(shard):
+            paddle.seed(0)
+            model = LlamaForCausalLM(LlamaConfig.tiny(
+                hidden_size=256, intermediate_size=256,
+                num_hidden_layers=1, num_attention_heads=2,
+                num_key_value_heads=2))
+            if shard:
+                shard_llama(model, ProcessMesh(
+                    np.arange(4).reshape(2, 2), dim_names=["fsdp", "mp"]),
+                    tp_axis="mp", fsdp_axis="fsdp")
+            apply, params, _ = functionalize(model)
+            ids = jnp.zeros((4, 128), jnp.int32)
+            return jax.make_jaxpr(
+                lambda p, i: apply(p, {}, i)[0])(params, ids)
+
+        def find(jaxpr, name, inside=None, found=None):
+            found = [] if found is None else found
+            for e in jaxpr.eqns:
+                if e.primitive.name == name:
+                    found.append(inside)
+                for v in e.params.values():
+                    sub = getattr(v, "jaxpr", v)
+                    if hasattr(sub, "eqns"):
+                        find(sub, name,
+                             e if e.primitive.name == "shard_map"
+                             else inside, found)
+            return found
+
+        plain = find(trace(False).jaxpr, "pallas_call")
+        assert plain == [None]                 # bare kernel, no shard_map
+        (sm,) = find(trace(True).jaxpr, "pallas_call")
+        assert sm is not None
+        # [B, L, H, D]: batch over fsdp, heads over mp, L and D whole
+        assert tuple(sm.params["in_specs"][0]) == ("fsdp", None, "mp", None)

@@ -297,6 +297,18 @@ class TestDeviceModule:
         with pytest.raises(RuntimeError):
             D.XPUPlace(0)
 
+    def test_set_device_never_resolves_to_another_platform(self):
+        """Asking for a TPU on a host without one raises (as does an
+        index the host does not have); the current place is unchanged."""
+        import paddle_tpu as paddle
+        assert paddle.set_device("cpu:0").jax_device().platform == "cpu"
+        for missing in ("tpu", "tpu:0", "cpu:4096"):
+            with pytest.raises(RuntimeError):
+                paddle.set_device(missing)
+        with pytest.raises(RuntimeError):
+            paddle.TPUPlace(0).jax_device()
+        assert paddle.get_device() == "cpu:0"
+
 
 class TestQuantBase:
     def test_quanter_factory(self):
