@@ -72,9 +72,9 @@ from . import text  # noqa: F401
 from . import utils  # noqa: F401
 from . import observability  # noqa: F401  (unified telemetry runtime)
 from . import inference  # noqa: F401
-# NOTE: paddle_tpu.profiler is intentionally NOT imported here — it pulls
-# in the native extension, whose first import compiles C++; users import
-# it explicitly (matching `import paddle.profiler` usage).
+# paddle_tpu.profiler is not re-exported here: users import it explicitly
+# (matching `import paddle.profiler` usage). It is loaded all the same, by
+# the modules whose spans are its RecordEvent (jit.sot, serving).
 from .framework.io import save, load  # noqa: F401
 from .hapi.model import Model, flops, summary  # noqa: F401
 from . import callbacks  # noqa: F401

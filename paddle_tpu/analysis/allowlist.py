@@ -120,11 +120,6 @@ CAPTURE_ALLOWLIST = [
      "installs the warm bundle's rebuilt program into the LRU before "
      "the first step ever runs — the same program-cache bookkeeping "
      "_get_program does at compile time, never replayed state"),
-    ("PTC002", "*`self._prefills` inside the step*",
-     "lazy program-cache instantiation (the per-bucket prefill "
-     "executable), shared by the serving hot path and the "
-     "warm-bundle _prewarm_entry replay: a dict-of-jitted-programs "
-     "fill, not step state — the programs themselves are pure"),
     ("PTC002", "*`self.weight_swaps` inside the step*",
      "hot-swap bookkeeping advances exactly at the step boundary the "
      "swap is defined at: _apply_pending_swap runs between decode "

@@ -42,6 +42,7 @@ import numpy as np
 from ..core.flags import flag_value
 from ..core.tensor import Tensor
 from ..jit.sot import CapturedStep
+from ..profiler import RecordEvent
 
 __all__ = ["DistTrainStep"]
 
@@ -255,8 +256,16 @@ class DistTrainStep:
         return ins, lbls
 
     def __call__(self, *batch_and_labels, num_labels: int = 1):
-        ins, lbls = self._split(batch_and_labels, num_labels)
-        return self._step.step(ins, lbls)
+        # the entry layer's span: the whole call until it returns
+        # (unblocked), `train.step.guard` / `.enqueue` inside it
+        stats = self._step.stats
+        with RecordEvent(
+                "train.step",
+                step=stats["captured_steps"] + stats["eager_steps"]) as span:
+            ins, lbls = self._split(batch_and_labels, num_labels)
+            loss = self._step.step(ins, lbls)
+            span.set(compiled=int(loss is not None))
+        return loss
 
     # -- checkpoint ---------------------------------------------------------
     def _tstates(self):

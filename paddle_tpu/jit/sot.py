@@ -52,6 +52,7 @@ from ..core.flags import _registry as _flag_registry
 from ..core.tensor import Tensor
 from ..observability import flight as _flight
 from ..observability import metrics as _om
+from ..profiler import RecordEvent
 
 __all__ = ["sot_compile", "SOTFunction", "BucketPolicy", "capture",
            "CapturedStep", "capture_jit"]
@@ -911,6 +912,21 @@ def capture(fn=None, bucket_policy: Optional[BucketPolicy] = None,
     return deco
 
 
+def jit_named(fn, name: str, **jit_kwargs):
+    """``jax.jit`` of ``fn`` under ``name``: the XLA module, and with it
+    the device trace's module line, reads ``jit_<name>`` (every character
+    that is no letter, digit or ``_`` becomes ``_``) instead of the Python
+    function's name, so two programs built from one function (a prefill
+    per bucket) stay apart in a trace."""
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = "".join(
+        c if c.isalnum() or c == "_" else "_" for c in name)
+    named.__wrapped__ = fn
+    return jax.jit(named, **jit_kwargs)
+
+
 def capture_jit(fn, donate_argnums=(), name: Optional[str] = None,
                 warm: Optional[Dict[str, Any]] = None):
     """Wrap an already-whole-step function (e.g. the serving decode
@@ -926,12 +942,21 @@ def capture_jit(fn, donate_argnums=(), name: Optional[str] = None,
     rebuild it AOT."""
     from .warmup import ensure_executable_cache, note_program
     ensure_executable_cache()
-    jf = jax.jit(fn, donate_argnums=donate_argnums)
     nm = name or getattr(fn, "__name__", "fn")
+    jf = jit_named(fn, nm, donate_argnums=donate_argnums)
     compiled = [False]
+    called = [False]
 
     def call(*args, **kwargs):
-        out = jf(*args, **kwargs)
+        if called[0]:
+            out = jf(*args, **kwargs)
+        else:
+            # the first call traces and compiles (or reads the
+            # persistent cache): a span with the program's name, under
+            # the iteration or step that paid for it
+            with RecordEvent("jit.compile", name=nm, kind="capture_jit"):
+                out = jf(*args, **kwargs)
+            called[0] = True
         # accounting only (execution above is a bare jax.jit either
         # way); the kill switch mutes ALL of it, and the compile event
         # lands only after the first call actually succeeded
@@ -1023,6 +1048,7 @@ class CapturedStep:
         # a per-step host->device key upload
         self._rng = None
         self._rng_epoch = None
+        self._uncalled = None   # (program, kind) built and not yet called
         self.stats: Dict[str, Any] = {
             "captured_steps": 0, "compiles": 0, "cache_hits": 0,
             "eager_steps": 0, "fallbacks": {}}
@@ -1177,12 +1203,14 @@ class CapturedStep:
         mean_reduce, cast_f32 = self._mean_reduce, self._cast_f32
 
         def loss_value(out, lbls):
-            loss_t = loss_fn(out, *lbls) if loss_fn is not None else out
-            ld = loss_t._data
-            if mean_reduce and ld.ndim > 0:
-                ld = ld.mean()
-            if cast_f32:
-                ld = ld.astype(jnp.float32)
+            with jax.named_scope("loss"):
+                loss_t = loss_fn(out, *lbls) if loss_fn is not None \
+                    else out
+                ld = loss_t._data
+                if mean_reduce and ld.ndim > 0:
+                    ld = ld.mean()
+                if cast_f32:
+                    ld = ld.astype(jnp.float32)
             return ld
 
         if kind == "eval":
@@ -1196,7 +1224,7 @@ class CapturedStep:
                         (loss_fn is not None and lbls) else None
                 return _tree_unwrap(out), ld, new_buffers
 
-            return jax.jit(eval_fn)
+            return jit_named(eval_fn, f"{self._name}_eval")
 
         scaled = kind == "train_scaled"
         tkeys = self._tkeys()
@@ -1243,8 +1271,10 @@ class CapturedStep:
                 g_leaves, found = _unscale_fn(
                     g_leaves, jnp.float32(1.0) / scale)
             from ..optimizer.fused_step import apply_update_tail
-            new_ps, new_ss = apply_update_tail(
-                opt, param_objs, p_leaves, g_leaves, states, lr, cspec)
+            with jax.named_scope("optimizer"):
+                new_ps, new_ss = apply_update_tail(
+                    opt, param_objs, p_leaves, g_leaves, states, lr,
+                    cspec)
             if found is not None:
                 # conditional skip ON DEVICE (the fused scaled step's
                 # mask): non-finite grads keep every param/state leaf
@@ -1268,7 +1298,7 @@ class CapturedStep:
                         (root, count + jnp.uint32(1)))
 
             donate = (0, 1, 2, 4) if self._donate else ()
-            return jax.jit(step_fn, donate_argnums=donate)
+            return jit_named(step_fn, self._name, donate_argnums=donate)
 
         # train_scaled: the whole GradScaler iteration in ONE program —
         # scale, backward, unscale + finite check, masked update, and
@@ -1296,7 +1326,8 @@ class CapturedStep:
                     (new_scale, new_good, new_bad), found)
 
         donate = (0, 1, 2, 4, 5) if self._donate else ()
-        return jax.jit(scaled_step_fn, donate_argnums=donate)
+        return jit_named(scaled_step_fn, f"{self._name}_scaled",
+                         donate_argnums=donate)
 
     def _get_program(self, kind: str, sig, n_ins: int,
                      scaler_statics=None, arrays=None):
@@ -1317,6 +1348,7 @@ class CapturedStep:
                              sig_to_json)
         ensure_executable_cache()
         jitted = self._build(kind, n_ins, scaler_statics)
+        self._uncalled = (jitted, kind)
         self._cache[sig] = jitted
         self._trim()
         self.stats["compiles"] += 1
@@ -1334,6 +1366,17 @@ class CapturedStep:
                        else None),
             "sig": sig_to_json(sig)})
         return jitted
+
+    def _call(self, jitted, *args):
+        """Call a program. The first call of one `_get_program` has just
+        built traces and compiles it (or reads the persistent cache): a
+        `jit.compile` span with the step's name, beside the
+        `capture_compile` flight event."""
+        if self._uncalled is None or self._uncalled[0] is not jitted:
+            return jitted(*args)
+        kind, self._uncalled = self._uncalled[1], None
+        with RecordEvent("jit.compile", name=self._name, kind=kind):
+            return jitted(*args)
 
     def _trim(self):
         cap = max(int(_capture_cache_flag.value or 8), 1)
@@ -1420,7 +1463,53 @@ class CapturedStep:
         grad unscale + finite check, device-masked update and the
         dynamic-loss-scale bookkeeping — the scaler's scale/counters
         ride as donated 0-d device carries and the skip decision
-        never syncs to host."""
+        never syncs to host.
+
+        Two host spans split the call: ``train.step.guard`` (gates,
+        signature, program lookup, leaf gathering) and
+        ``train.step.enqueue`` (the jitted call until it returns,
+        unblocked)."""
+        with RecordEvent("train.step.guard"):
+            ready = self._guard_step(inputs, labels, scaler)
+        if ready is None:
+            return None
+        jitted, arrays, tkeys, scaler, (params, buffers, states) = ready
+        from ..optimizer.fused_step import _lr_device
+        opt, swap = self.optimizer, self._swap
+        with RecordEvent("train.step.enqueue"):
+            if scaler is None:
+                (loss, new_params, new_buffers, new_ss,
+                 self._rng) = self._call(
+                    jitted, params, buffers, states, _lr_device(opt),
+                    self._next_rng(), *arrays)
+            else:
+                # donated carries: a live handle on the scale buffer (a
+                # held get_loss_scaling snapshot) copies before donation
+                carry = tuple(self._safe_leaf(v)
+                              for v in scaler.capture_carry())
+                (loss, new_params, new_buffers, new_ss, self._rng,
+                 new_carry, found) = self._call(
+                    jitted, params, buffers, states, _lr_device(opt),
+                    self._next_rng(), carry, *arrays)
+                scaler.absorb_captured(new_carry, found)
+        for k, t in swap.params.items():
+            t._data = new_params[k]
+        for k, t in swap.buffers.items():
+            t._data = new_buffers[k]
+        for k, ns in zip(tkeys, new_ss):
+            opt._states[id(swap.params[k])] = ns
+        opt._global_step += 1
+        if self._strict:  # hapi semantics: step() + clear_grad()
+            for p in opt._parameter_list:
+                p.grad = None
+        self.stats["captured_steps"] += 1
+        if _M_flag.value:
+            _M_captured._v += 1  # inline fast cell: per-step hot path
+        return Tensor(loss)
+
+    def _guard_step(self, inputs, labels, scaler):
+        """Everything of ``step`` before the jitted call: the program
+        and its donation-safe arguments, or None for the eager path."""
         if scaler is not None and not scaler.is_enable():
             scaler = None
         if self._strict:
@@ -1464,39 +1553,9 @@ class CapturedStep:
         if gathered is None:
             self._fallback("aliased")
             return None
-        params, buffers, states = gathered
         from ..core import fusion
         fusion.capture_handoff()
-        from ..optimizer.fused_step import _lr_device
-        opt, swap = self.optimizer, self._swap
-        if scaler is None:
-            loss, new_params, new_buffers, new_ss, self._rng = jitted(
-                params, buffers, states, _lr_device(opt),
-                self._next_rng(), *arrays)
-        else:
-            # donated carries: a live handle on the scale buffer (a
-            # held get_loss_scaling snapshot) copies before donation
-            carry = tuple(self._safe_leaf(v)
-                          for v in scaler.capture_carry())
-            (loss, new_params, new_buffers, new_ss, self._rng,
-             new_carry, found) = jitted(
-                params, buffers, states, _lr_device(opt),
-                self._next_rng(), carry, *arrays)
-            scaler.absorb_captured(new_carry, found)
-        for k, t in swap.params.items():
-            t._data = new_params[k]
-        for k, t in swap.buffers.items():
-            t._data = new_buffers[k]
-        for k, ns in zip(tkeys, new_ss):
-            opt._states[id(swap.params[k])] = ns
-        opt._global_step += 1
-        if self._strict:  # hapi semantics: step() + clear_grad()
-            for p in opt._parameter_list:
-                p.grad = None
-        self.stats["captured_steps"] += 1
-        if _M_flag.value:
-            _M_captured._v += 1  # inline fast cell: per-step hot path
-        return Tensor(loss)
+        return jitted, arrays, tkeys, scaler, gathered
 
     def forward(self, inputs, labels=()):
         """One captured eval/inference forward. Returns ``(out, loss)``
@@ -1531,7 +1590,8 @@ class CapturedStep:
         root, count = self._next_rng()
         key = jax.random.fold_in(root, count)
         self._rng = (root, count + jnp.uint32(1))
-        out, loss, new_buffers = jitted(params, buffers, key, *arrays)
+        out, loss, new_buffers = self._call(jitted, params, buffers, key,
+                                            *arrays)
         for k, t in swap.buffers.items():
             t._data = new_buffers[k]
         from .api import _tree_wrap

@@ -227,18 +227,23 @@ class LlamaDecoderLayer(Layer):
 
     def forward(self, hidden_states, attention_mask=None, cache=None,
                 position_offset=0):
-        residual = hidden_states
-        h = self.input_layernorm(hidden_states)
-        if cache is not None:
-            h, new_cache = self.self_attn(h, attention_mask, cache,
-                                          position_offset)
-        else:
-            h = self.self_attn(h, attention_mask, None, position_offset)
-        h = residual + h
-        residual = h
-        h = self.post_attention_layernorm(h)
-        h = self.mlp(h)
-        h = residual + h
+        # the two scopes split a layer's operations (each with its norm
+        # and residual add) in an xprof view of the trace; metadata only
+        with jax.named_scope("llama.attn"):
+            residual = hidden_states
+            h = self.input_layernorm(hidden_states)
+            if cache is not None:
+                h, new_cache = self.self_attn(h, attention_mask, cache,
+                                              position_offset)
+            else:
+                h = self.self_attn(h, attention_mask, None,
+                                   position_offset)
+            h = residual + h
+        with jax.named_scope("llama.mlp"):
+            residual = h
+            h = self.post_attention_layernorm(h)
+            h = self.mlp(h)
+            h = residual + h
         if cache is not None:
             return h, new_cache
         return h
@@ -256,7 +261,8 @@ class LlamaModel(Layer):
 
     def forward(self, input_ids, attention_mask=None, caches=None,
                 position_offset=0):
-        h = self.embed_tokens(input_ids)
+        with jax.named_scope("llama.embed"):
+            h = self.embed_tokens(input_ids)
         if self.config.sequence_parallel:
             h = _seq_constraint(h)
         new_caches = [] if caches is not None else None
@@ -333,10 +339,10 @@ class LlamaForCausalLM(Layer):
     def forward(self, input_ids, attention_mask=None, caches=None,
                 position_offset=0):
         out = self.llama(input_ids, attention_mask, caches, position_offset)
-        if caches is not None:
-            h, new_caches = out
-            return self._logits(h), new_caches
-        return self._logits(out)
+        h, new_caches = out if caches is not None else (out, None)
+        with jax.named_scope("llama.head"):
+            logits = self._logits(h)
+        return logits if caches is None else (logits, new_caches)
 
     def generate(self, input_ids, max_new_tokens=32):
         """Greedy decode with per-layer KV caches (inference parity check,

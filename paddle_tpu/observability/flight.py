@@ -19,8 +19,9 @@ overlap-efficiency numerator — and a ``dist_step`` summary carrying
 the step's host dispatch duration), checkpoint save/restore/
 corruption-fallback, elastic membership transitions, watchdog timeouts
 and the per-request serving lifecycle (submit → queued → admitted →
-[prefilled] → decode → finished/expired/rejected, keyed by
-``trace_id``), plus the paged KV block pool's allocator
+[prefill_chunk per turn at the chunked prefill, with the prompt tokens
+``start``/``tokens`` it ran and its ``bucket`` → prefilled] → decode →
+finished/expired/rejected, keyed by ``trace_id``), plus the paged KV block pool's allocator
 (``block_alloc`` / ``block_free`` / ``block_exhausted`` — a pool
 running dry reads straight out of a dump next to the starved
 requests' queue time) and its prefix-sharing radix cache
@@ -87,25 +88,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ..core.flags import _registry as _flag_registry, define_flag
 from . import metrics as _metrics
-
-_native_now = None
-
-
-def _now_us() -> float:
-    """timeline._now_us semantics (host-tracer µs once the native lib is
-    loaded, perf_counter µs before) with the resolved native clock
-    cached — the append hot path must not pay a sys.modules lookup per
-    event."""
-    global _native_now
-    f = _native_now
-    if f is not None:
-        return f()
-    mod = sys.modules.get("paddle_tpu._native")
-    lib = getattr(mod, "lib", None)
-    if lib is not None:
-        _native_now = lib.tracer_now
-        return _native_now()
-    return time.perf_counter() * 1e6
+from .clock import now_us as _now_us
 
 __all__ = [
     "record", "enabled", "events", "clear", "dropped", "appended",

@@ -14,35 +14,22 @@ Phase durations also feed the process registry
 ``observability.snapshot()`` answers "what did the last N steps look
 like" without a trace file.
 
-Clock: the native host tracer's monotonic-µs clock when the extension is
-already loaded (so span and counter timestamps share one timebase),
-``time.perf_counter`` otherwise — on Linux both read CLOCK_MONOTONIC.
+Clock: ``observability.clock.now_us``, which spans and flight events share.
 """
 from __future__ import annotations
 
 import os
-import sys
-import time
 import weakref
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
 from . import metrics as _metrics
+from .clock import now_us as _now_us
 
 __all__ = ["StepTimer", "chrome_events", "active_timers"]
 
 # ring cap per timer: a counter event is ~100 bytes; 20k steps ~ 2MB
 _EVENT_CAP = 20000
-
-
-def _now_us() -> float:
-    # never triggers the native C++ build: only use the clock if the
-    # extension is ALREADY loaded (then span timestamps share its base)
-    mod = sys.modules.get("paddle_tpu._native")
-    lib = getattr(mod, "lib", None)
-    if lib is not None:
-        return lib.tracer_now()
-    return time.perf_counter() * 1e6
 
 
 _timers: "weakref.WeakSet" = weakref.WeakSet()

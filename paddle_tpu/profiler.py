@@ -5,7 +5,10 @@ scheduler states), paddle/fluid/platform/profiler/host_tracer.h:26
 (RecordEvent spans), chrometracing_logger.cc (Chrome trace export). The
 host plane is the C++ tracer in paddle_tpu._native; the device plane is
 jax.profiler (XLA/xplane), which TensorBoard renders — the same division
-the reference draws between HostTracer and CudaTracer/CUPTI.
+the reference draws between HostTracer and CudaTracer/CUPTI. RecordEvent
+is the one span primitive and writes both: the native plane while a
+Profiler runs, and a host span beside the device operations, on their
+clock, while a jax.profiler trace runs.
 """
 from __future__ import annotations
 
@@ -13,7 +16,10 @@ import json
 import os
 from typing import Optional
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from ._native import lib as _lib
+from .observability.clock import now_us
 
 __all__ = ["Profiler", "RecordEvent", "ProfilerTarget",
            "export_chrome_tracing"]
@@ -28,24 +34,47 @@ class ProfilerTarget:
 class RecordEvent:
     """Host-span annotation (ref: paddle.profiler.RecordEvent; native analog
     platform/profiler/event_tracing.h RecordEvent). Usable as context
-    manager or begin()/end() pair.
+    manager or begin()/end() pair. ``attrs`` are small counts or ids.
+
+    One object writes both planes. While a ``Profiler`` runs, the span
+    lands in the native host tracer (``export_chrome_tracing``); while a
+    ``jax.profiler`` trace runs (``Profiler(targets=[TPU])``, or
+    ``jax.profiler.start_trace``), it is a ``TraceAnnotation`` in the
+    ``/host:CPU`` plane of the same ``.xplane.pb`` as the device
+    operations, on their clock, with ``attrs`` as the event's stats. With
+    neither running it costs about a microsecond, so the program's own
+    spans (``serving.*``, ``train.*``, ``jit.compile``) are always there
+    and no switch turns them on.
 
     Reentrant: a second ``begin()`` before ``end()`` nests (each ``end``
     closes the most recent open ``begin``, LIFO) instead of silently
     dropping the first span's start."""
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, /, **attrs):
         self.name = name
-        self._starts: list = []
+        self.attrs = attrs
+        self._open: list = []       # (native start or None, annotation)
 
     def begin(self):
-        if _lib is not None and _lib.tracer_enabled():
-            self._starts.append(_lib.tracer_now())
+        native = _lib is not None and _lib.tracer_enabled()
+        ann = _TraceAnnotation(self.name, **self.attrs)
+        ann.__enter__()
+        self._open.append((now_us() if native else None, ann))
+
+    def set(self, **attrs):
+        """Attach counts that are known only once the work is done to the
+        innermost open span (the xplane's stats; the native plane keeps
+        names and times only)."""
+        if self._open:
+            self._open[-1][1].set_metadata(**attrs)
 
     def end(self):
-        if _lib is not None and self._starts:
-            _lib.tracer_record(self.name, self._starts.pop(),
-                               _lib.tracer_now())
+        if not self._open:
+            return
+        start, ann = self._open.pop()
+        ann.__exit__(None, None, None)
+        if start is not None:
+            _lib.tracer_record(self.name, start, now_us())
 
     def __enter__(self):
         self.begin()
@@ -76,7 +105,7 @@ class Profiler:
     def start(self):
         if _lib is not None:
             _lib.tracer_start()
-            self._step_t0 = _lib.tracer_now()
+            self._step_t0 = now_us()
         # timer_only (ref: Profiler(timer_only=True) — step timing
         # without event collection) keeps the cheap host plane but skips
         # the device (XLA) trace entirely
@@ -117,7 +146,7 @@ class Profiler:
         self._step_count += 1
         if _lib is not None and _lib.tracer_enabled() \
                 and self._step_t0 is not None:
-            now = _lib.tracer_now()
+            now = now_us()
             _lib.tracer_record(f"ProfileStep#{self._step_count}",
                                self._step_t0, now)
             self._step_t0 = now

@@ -71,6 +71,7 @@ import numpy as np
 
 from .observability import flight as _flight
 from .observability import metrics as _om
+from .profiler import RecordEvent as _span
 from .utils import fault_injection as _fi
 
 __all__ = ["LlamaDecodeEngine", "PagedLlamaDecodeEngine",
@@ -661,11 +662,15 @@ class LlamaDecodeEngine:
     def step(self) -> np.ndarray:
         """One decode iteration for ALL slots; returns next token per
         slot (garbage for inactive slots — callers consult .active)."""
-        nxt, self.k_cache, self.v_cache = self._decode(
-            self.params, self.k_cache, self.v_cache,
-            jnp.asarray(self.last_ids), jnp.asarray(self.pos))
+        with _span("serving.decode.prepare"):
+            ids = jnp.asarray(self.last_ids)
+            pos = jnp.asarray(self.pos)
+        with _span("serving.decode.enqueue"):
+            nxt, self.k_cache, self.v_cache = self._decode(
+                self.params, self.k_cache, self.v_cache, ids, pos)
         self._count_pa_path()
-        nxt = np.asarray(nxt)
+        with _span("serving.decode.fetch"):
+            nxt = np.asarray(nxt)
         for s in range(self.max_slots):
             if self.active[s]:
                 self.pos[s] += 1
@@ -865,12 +870,13 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         # captured-step accounting exactly like the dense one
         self._decode = self._capture_jit(self._decode_impl,
                                          donate_argnums=(1,),
-                                         name="serving.paged_decode",
+                                         name="serving.decode",
                                          warm={"program": "decode",
                                                **self._warm_geo()})
         self._decode_collect = None
         self._prefills: Dict[int, object] = {}
         self._prefill_state: Dict[int, dict] = {}
+        self.last_chunk: Dict[str, int] = {}
         # prefix-sharing state: the boundary copy-on-write program is
         # built lazily (first block-aligned hit), per-request hit
         # accounting feeds the server's req["prefix_hit_tokens"]
@@ -960,20 +966,23 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         v = self._mm(x, lp["v_proj"]).reshape(S, T, kvh, self.head_dim)
         q = self._rope(q, positions)
         k = self._rope(k, positions)
-        kvl = self._write_kv(kvl, k, v, positions, tables, wmask)
-        att = self._sc.paged_attention(
-            q, kvl["k"], kvl["v"], tables, positions,
-            block_size=self.block_size, n_rep=self.n_rep,
-            n_tiles=n_tiles, k_scale=kvl.get("ksc"),
-            v_scale=kvl.get("vsc"), use_kernel=self._pa_kernel)
+        with jax.named_scope("paged.kv_write"):
+            kvl = self._write_kv(kvl, k, v, positions, tables, wmask)
+        with jax.named_scope("paged.attn"):
+            att = self._sc.paged_attention(
+                q, kvl["k"], kvl["v"], tables, positions,
+                block_size=self.block_size, n_rep=self.n_rep,
+                n_tiles=n_tiles, k_scale=kvl.get("ksc"),
+                v_scale=kvl.get("vsc"), use_kernel=self._pa_kernel)
         h = res + self._mm(att.reshape(S, T, H), lp["o_proj"])
-        res = h
-        x = self._rms(h, lp["post_ln"])
-        ff = self._mm(jax.nn.silu(
-            self._mm(x, lp["gate_proj"]).astype(jnp.float32)).astype(
-                x.dtype) * self._mm(x, lp["up_proj"]),
-            lp["down_proj"])
-        return res + ff, kvl
+        with jax.named_scope("paged.mlp"):
+            res = h
+            x = self._rms(h, lp["post_ln"])
+            ff = self._mm(jax.nn.silu(
+                self._mm(x, lp["gate_proj"]).astype(jnp.float32)).astype(
+                    x.dtype) * self._mm(x, lp["up_proj"]),
+                lp["down_proj"])
+            return res + ff, kvl
 
     def _forward_paged(self, params, kv, ids, positions, tables,
                        n_tiles, wmask):
@@ -987,10 +996,11 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                                        n_tiles, wmask)
             for key in out_kv:
                 out_kv[key].append(kvl[key])
-        h = self._rms(h, params["norm"])
-        logits = self._mm(h, params["head"])
-        # same MXU-vs-fused-argmax barrier as the dense engine
-        logits = jax.lax.optimization_barrier(logits)
+        with jax.named_scope("paged.head"):
+            h = self._rms(h, params["norm"])
+            logits = self._mm(h, params["head"])
+            # same MXU-vs-fused-argmax barrier as the dense engine
+            logits = jax.lax.optimization_barrier(logits)
         return logits, out_kv
 
     def _decode_impl(self, params, kv, last_ids, pos, tables, act):
@@ -1245,6 +1255,17 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         self.active[slot] = False
         return True
 
+    def _prefill_program(self, b: int):
+        """The prefill executable of bucket ``b``, each under its own
+        name (``jit_serving_prefill_b<b>`` in a device trace)."""
+        if b not in self._prefills:
+            self._prefills[b] = self._capture_jit(
+                self._prefill_impl, donate_argnums=(1,),
+                name=f"serving.prefill_b{b}",
+                warm={"program": "prefill", "bucket": b,
+                      **self._warm_geo()})
+        return self._prefills[b]
+
     def prefill_chunk(self, slot: int) -> Optional[int]:
         """Run the next prompt chunk for ``slot``. Returns None while
         prefill is incomplete; on the final chunk, activates the slot
@@ -1260,19 +1281,16 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
             else max(8, min(self.prefill_chunk_len, self._chunk_cap))
         c = min(limit, n - start)
         b = min(self._bucket(c), self.prefill_chunk_len)
-        if b not in self._prefills:
-            self._prefills[b] = self._capture_jit(
-                self._prefill_impl, donate_argnums=(1,),
-                name="serving.paged_prefill",
-                warm={"program": "prefill", "bucket": b,
-                      **self._warm_geo()})
-        padded = np.zeros((1, b), np.int32)
-        padded[0, :c] = ids[start:start + c]
-        row = jnp.asarray(self._kv.block_tables[slot])
-        tok, self.kvs = self._prefills[b](
-            self.params, self.kvs, jnp.asarray(padded), row,
-            jnp.int32(start), jnp.int32(c), jnp.int32(n))
+        with _span("serving.prefill.enqueue"):
+            padded = np.zeros((1, b), np.int32)
+            padded[0, :c] = ids[start:start + c]
+            row = jnp.asarray(self._kv.block_tables[slot])
+            tok, self.kvs = self._prefill_program(b)(
+                self.params, self.kvs, jnp.asarray(padded), row,
+                jnp.int32(start), jnp.int32(c), jnp.int32(n))
         st["next"] = start + c
+        # what this turn did, for the loop's span and flight event
+        self.last_chunk = {"start": start, "tokens": c, "bucket": b}
         # publish every fully-written prompt block into the radix
         # tree as soon as its last token lands: a concurrent
         # admission can hit a prefix whose OWNER is still prefilling
@@ -1288,7 +1306,8 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                     and slot in self._draft._prefill_state:
                 self._draft.prefill_chunk(slot)
             return None
-        first = int(tok)
+        with _span("serving.prefill.fetch"):
+            first = int(tok)
         del self._prefill_state[slot]
         self.pos[slot] = n
         self.active[slot] = True
@@ -1341,24 +1360,27 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         fallback, direct use) must not punch holes in the draft's
         history, or every later speculation window would propose from
         garbage and acceptance would silently collapse."""
-        self._extend_tables()
         draft = self._draft
-        act = jnp.asarray(self.active)
-        ids = jnp.asarray(self.last_ids)
-        pos = jnp.asarray(self.pos)
-        if draft is not None:
-            for s in range(self.max_slots):
-                if self.active[s]:
-                    draft._shared_write_guard(s)
-                    draft._kv.ensure_token(s, int(self.pos[s]))
-            _, draft.kvs = draft._decode(
-                draft.params, draft.kvs, ids, pos,
-                jnp.asarray(draft._kv.block_tables), act)
-        nxt, self.kvs = self._decode(
-            self.params, self.kvs, ids, pos,
-            jnp.asarray(self._kv.block_tables), act)
+        with _span("serving.decode.prepare"):
+            self._extend_tables()
+            act = jnp.asarray(self.active)
+            ids = jnp.asarray(self.last_ids)
+            pos = jnp.asarray(self.pos)
+            tables = jnp.asarray(self._kv.block_tables)
+        with _span("serving.decode.enqueue"):
+            if draft is not None:
+                for s in range(self.max_slots):
+                    if self.active[s]:
+                        draft._shared_write_guard(s)
+                        draft._kv.ensure_token(s, int(self.pos[s]))
+                _, draft.kvs = draft._decode(
+                    draft.params, draft.kvs, ids, pos,
+                    jnp.asarray(draft._kv.block_tables), act)
+            nxt, self.kvs = self._decode(
+                self.params, self.kvs, ids, pos, tables, act)
         self._count_pa_path()
-        nxt = np.asarray(nxt)
+        with _span("serving.decode.fetch"):
+            nxt = np.asarray(nxt)
         for s in range(self.max_slots):
             if self.active[s]:
                 self.pos[s] += 1
@@ -1582,17 +1604,11 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         elif prog == "prefill":
             b = int(meta.get("bucket", 0) or
                     min(self._bucket(1), self.prefill_chunk_len))
-            if b not in self._prefills:
-                # same warm meta as the prefill_chunk registration:
-                # a bundle RE-exported by this prewarmed replica must
-                # carry the geometry too, or its entries would bypass
-                # the freshness check downstream
-                self._prefills[b] = self._capture_jit(
-                    self._prefill_impl, donate_argnums=(1,),
-                    name="serving.paged_prefill",
-                    warm={"program": "prefill", "bucket": b,
-                          **self._warm_geo()})
-            self._prefills[b]._jitted.lower(
+            # the same registration as prefill_chunk's, warm meta
+            # included: a bundle RE-exported by this prewarmed replica
+            # must carry the geometry too, or its entries would bypass
+            # the freshness check downstream
+            self._prefill_program(b)._jitted.lower(
                 self.params, self.kvs,
                 jnp.asarray(np.zeros((1, b), np.int32)),
                 jnp.asarray(self._kv.block_tables[0]),
@@ -2214,26 +2230,36 @@ class GenerationServer:
         decode step, so already-admitted slots keep streaming."""
         for slot in list(self._prefilling):
             req = self._prefilling[slot]
-            try:
-                first = self.engine.prefill_chunk(slot)
-            except Exception as e:  # noqa: BLE001 — per-request
+            tid = req.get("trace_id")
+            with _span("serving.prefill", trace_id=tid,
+                       slot=slot) as span:
+                try:
+                    first = self.engine.prefill_chunk(slot)
+                except Exception as e:  # noqa: BLE001 — per-request
+                    if self._fenced():
+                        return  # zombie: recovery owns the request now
+                    del self._prefilling[slot]
+                    self._release_slot(slot, evicted=True)
+                    self._fail(req, e)
+                    return
                 if self._fenced():
-                    return  # zombie: recovery owns the request now
-                del self._prefilling[slot]
-                self._release_slot(slot, evicted=True)
-                self._fail(req, e)
-                return
-            if self._fenced():
-                return  # zombie woke from a wedged chunk: commit
-                # nothing — the new loop re-admitted this request
-            if first is not None:
-                del self._prefilling[slot]
-                req["out"].append(first)
-                self._slots[slot] = req
-                _flight.record("serving", "prefilled",
-                               trace_id=req.get("trace_id"), slot=slot,
-                               prompt_len=int(req["prompt"].shape[0]))
-                self._finish_if_done(slot, req)
+                    return  # zombie woke from a wedged chunk: commit
+                    # nothing — the new loop re-admitted this request
+                # the turn this request got: which prompt tokens, in
+                # which bucket (getattr: duck-typed fake engines keep
+                # the bare paged contract)
+                chunk = getattr(self.engine, "last_chunk", {})
+                span.set(**chunk)
+                _flight.record("serving", "prefill_chunk", trace_id=tid,
+                               slot=slot, **chunk)
+                if first is not None:
+                    del self._prefilling[slot]
+                    req["out"].append(first)
+                    self._slots[slot] = req
+                    _flight.record("serving", "prefilled",
+                                   trace_id=tid, slot=slot,
+                                   prompt_len=int(req["prompt"].shape[0]))
+                    self._finish_if_done(slot, req)
             return
 
     def _finish_if_done(self, slot, req):
@@ -2344,6 +2370,44 @@ class GenerationServer:
                           "steps_run": self.steps_run}
         done.set()
 
+    def _admit_spanned(self, admit, *args) -> None:
+        """Run one of the two admission paths as a `serving.admit` span
+        that says how many requests it admitted."""
+        with _span("serving.admit") as span:
+            before = self.admitted
+            admit(*args)
+            span.set(admitted=self.admitted - before)
+
+    def _admit_parked(self, req) -> None:
+        """Admit the request the idle loop was parked for, directly."""
+        slot = self._free_slots()[0]
+        if not self._paged:
+            self._admit_one(req, slot)
+        elif self._admit_paged(req, slot) == "defer":
+            self._waiting.append(req)
+
+    def _launch_counts(self) -> Dict[str, int]:
+        """What the next decode launch reads, counted before it runs:
+        `rows` active slots, `live_tokens` = sum over them of `pos + 1`
+        (each row's history and the token it writes), `max_ctx` the
+        longest of them, which bounds the paged kernel's block walk."""
+        eng = self.engine
+        ctx = np.asarray(eng.pos)[np.asarray(eng.active, bool)] + 1
+        return {"rows": int(ctx.size), "live_tokens": int(ctx.sum()),
+                "max_ctx": int(ctx.max()) if ctx.size else 0}
+
+    def _sweep(self) -> None:
+        """The step boundary's housekeeping, as one span: deadlines,
+        then gauges AFTER the completion/expiry sweep (a scrape between
+        steps must not report finished requests as in-flight), then the
+        admission policy's evidence (EWMAs of blocks/backlog/throughput)
+        and its brownout/shed levels."""
+        with _span("serving.sweep"):
+            self._expire_active()
+            self._expire_queued()
+            self._set_gauges()
+            self.policy.on_step(self)
+
     def _loop(self):
         # the epoch captured here fences THIS incarnation: after a
         # supervisor restart (crash or stall), a zombie of the old
@@ -2358,97 +2422,99 @@ class GenerationServer:
                 return  # fenced: a supervisor replaced this loop
             self._beat = time.monotonic()  # stall-watchdog heartbeat
             try:
-                self._apply_pending_swap()
-                self._admit()
-                if self._paged and self._prefilling:
-                    self._run_prefill()
-                if not self._slots:
-                    if self._prefilling or self._waiting:
-                        # prompts still chunking / requests waiting on
-                        # blocks: keep cycling (no decode batch yet)
-                        self._expire_active()
-                        self._expire_queued()
-                        self._set_gauges()
-                        self.policy.on_step(self)
+                # one root span per pass; every child is on this thread,
+                # so a trace's idle gaps read admit / prefill / decode /
+                # commit / sweep instead of "host, unattributed"
+                with _span("serving.iter", step=self.steps_run,
+                           active=len(self._slots),
+                           prefilling=len(self._prefilling),
+                           waiting=self._q.qsize() + len(self._waiting)):
+                    if self._swap_req is not None:
+                        with _span("serving.swap"):
+                            self._apply_pending_swap()
+                    self._admit_spanned(self._admit)
+                    if self._paged and self._prefilling:
+                        self._run_prefill()
+                    if not self._slots:
+                        if self._prefilling or self._waiting:
+                            # prompts still chunking / requests waiting
+                            # on blocks: keep cycling (no decode batch
+                            # yet)
+                            self._sweep()
+                            continue
+                        if self._stopping.is_set() and self._q.empty():
+                            break  # drained: nothing active or queued
+                        # idle: block for the next request and admit it
+                        # DIRECTLY — a get-then-requeue would let
+                        # requests submitted in the window jump ahead
+                        # of it (FIFO)
+                        self._set_gauges()  # idle: a scrape must read 0
+                        self._idle = True   # parked, not stalled
+                        try:
+                            with _span("serving.idle"):
+                                req = self._q.get()
+                        finally:
+                            self._idle = False
+                        if self._epoch != my_epoch:
+                            # fenced while parked: the request belongs
+                            # to the NEW loop — hand it back and exit
+                            if req is not self._STOP:
+                                self._q.put(req)
+                            return
+                        if req is self._STOP:
+                            continue
+                        self._admit_spanned(self._admit_parked, req)
                         continue
-                    if self._stopping.is_set() and self._q.empty():
-                        break  # drained: nothing active, nothing queued
-                    # idle: block for the next request and admit it
-                    # DIRECTLY — a get-then-requeue would let requests
-                    # submitted in the window jump ahead of it (FIFO)
-                    self._set_gauges()  # idle: a scrape must read 0
-                    self._idle = True   # parked, not stalled
-                    try:
-                        req = self._q.get()
-                    finally:
-                        self._idle = False
+                    # fault-injection site: a kill-point armed here
+                    # simulates a crash mid-decode — the loop thread
+                    # dies (KillPoint is a BaseException) and the
+                    # flight recorder's threading.excepthook dump
+                    # carries every in-flight request's lifecycle trail
+                    _fi.fire("serving.decode")
+                    eng = self.engine
+                    spec = bool(self._paged and eng.spec_ready())
+                    with _span("serving.decode", step=self.steps_run + 1,
+                               spec=int(spec), **self._launch_counts()):
+                        if spec:
+                            # speculative iteration: up to spec_k
+                            # committed tokens per slot for one step's
+                            # host fetch; the greedy stream is bit-equal
+                            # to plain stepping, so requests cut off
+                            # mid-window (eos / budget) see exactly the
+                            # tokens they would have anyway
+                            toks, counts = eng.spec_step()
+                        else:
+                            # plain stepping is the counts == 1 case of
+                            # the same commit loop
+                            toks = eng.step()[:, None]
+                            counts = np.ones(eng.max_slots, np.int32)
                     if self._epoch != my_epoch:
-                        # fenced while parked: the request belongs to
-                        # the NEW loop — hand it back and exit
-                        if req is not self._STOP:
-                            self._q.put(req)
-                        return
-                    if req is self._STOP:
-                        continue
-                    if self._paged:
-                        verdict = self._admit_paged(
-                            req, self._free_slots()[0])
-                        if verdict == "defer":
-                            self._waiting.append(req)
-                        continue
-                    self._admit_one(req, self._free_slots()[0])
-                    continue
-                # fault-injection site: a kill-point armed here
-                # simulates a crash mid-decode — the loop thread dies
-                # (KillPoint is a BaseException) and the flight
-                # recorder's threading.excepthook dump carries every
-                # in-flight request's lifecycle trail
-                _fi.fire("serving.decode")
-                eng = self.engine
-                if self._paged and eng.spec_ready():
-                    # speculative iteration: up to spec_k committed
-                    # tokens per slot for one step's host fetch; the
-                    # greedy stream is bit-equal to plain stepping,
-                    # so requests cut off mid-window (eos / budget)
-                    # see exactly the tokens they would have anyway
-                    toks, counts = eng.spec_step()
-                else:
-                    # plain stepping is the counts == 1 case of the
-                    # same commit loop
-                    toks = eng.step()[:, None]
-                    counts = np.ones(eng.max_slots, np.int32)
-                if self._epoch != my_epoch:
-                    return  # fenced mid-step (stall restart): the new
-                    # loop owns the slots — do not commit or fail
-                self.steps_run += 1
-                _M_steps.inc()
-                for slot in list(self._slots):
-                    req = self._slots[slot]
-                    before = len(req["out"])
-                    for j in range(int(counts[slot])):
-                        tok = int(toks[slot, j])
-                        req["out"].append(tok)
-                        if len(req["out"]) >= req["max_new"]:
-                            break
-                        if eng.eos_id is not None \
-                                and tok == eng.eos_id:
-                            break
-                    self.tokens_delivered += len(req["out"]) - before
-                    _flight.record("serving", "decode",
-                                   trace_id=req.get("trace_id"),
-                                   step=self.steps_run,
-                                   tokens=len(req["out"]))
-                    self._finish_if_done(slot, req)
-                self._expire_active()
-                self._expire_queued()
-                # gauges AFTER the completion/expiry sweep: a scrape
-                # between steps must not report finished requests as
-                # in-flight
-                self._set_gauges()
-                # step boundary: feed the admission policy its
-                # evidence (EWMAs of blocks/backlog/throughput) and
-                # let it move brownout/shed levels
-                self.policy.on_step(self)
+                        return  # fenced mid-step (stall restart): the
+                        # new loop owns the slots — do not commit or fail
+                    self.steps_run += 1
+                    _M_steps.inc()
+                    with _span("serving.commit") as span:
+                        delivered = self.tokens_delivered
+                        for slot in list(self._slots):
+                            req = self._slots[slot]
+                            before = len(req["out"])
+                            for j in range(int(counts[slot])):
+                                tok = int(toks[slot, j])
+                                req["out"].append(tok)
+                                if len(req["out"]) >= req["max_new"]:
+                                    break
+                                if eng.eos_id is not None \
+                                        and tok == eng.eos_id:
+                                    break
+                            self.tokens_delivered += \
+                                len(req["out"]) - before
+                            _flight.record("serving", "decode",
+                                           trace_id=req.get("trace_id"),
+                                           step=self.steps_run,
+                                           tokens=len(req["out"]))
+                            self._finish_if_done(slot, req)
+                        span.set(tokens=self.tokens_delivered - delivered)
+                    self._sweep()
             except Exception as e:  # noqa: BLE001 — fail loudly, stay up
                 if self._epoch != my_epoch:
                     return  # fenced: the slots hold RE-ADMITTED

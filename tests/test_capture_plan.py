@@ -659,14 +659,15 @@ class TestRepoStepFixtures:
             # decision is allocator method calls, not step-state
             # mutation, so it adds NO findings beyond the hit record
             "PagedLlamaDecodeEngine.begin_request": {"PTC002": 4},
-            # prefill_chunk: program-cache insert, prompt staging into
-            # the padded host buffer, slot activation bookkeeping
+            # prefill_chunk: prompt staging into the padded host
+            # buffer (the per-bucket program-cache insert lives in
+            # _prefill_program, no step), slot activation bookkeeping
             # (pos/active/last_ids), the draft-mirror last_ids seed +
             # the final-chunk first-token fetch (the radix
             # commit_prefix after each chunk is an allocator call —
             # no new finding)
             "PagedLlamaDecodeEngine.prefill_chunk":
-                {"PTC002": 6, "PTC003": 1},
+                {"PTC002": 5, "PTC003": 1},
             # spec_step: commit bookkeeping (pos/last_ids) between the
             # propose/verify executables + the ONE window fetch
             # (tokens + accepted counts, both hoisted to the tail)
