@@ -2,28 +2,38 @@
 
 TPU kernel behind the ``serving_cache.paged_attention`` seam: the
 pure-jnp tiled walk (the CPU/tier-1 numerics oracle) streams each
-slot's mapped KV blocks through XLA gathers; on TPU that per-tile
-gather loop is the remaining decode roofline gap (ROADMAP item 1b).
-This kernel keeps the identical flat ``(q, pools, tables, positions)``
-signature and the identical online-softmax tiling, but lets the Mosaic
-pipeline move blocks HBM->VMEM via **scalar-prefetched block-table
-indexing** (the vLLM-style recipe): the grid walks (slot, tile) and
-each tile's BlockSpec index_map reads ``tables[s, t]`` — prefetched to
-SMEM before the body runs — so the next physical block's DMA overlaps
-the current tile's MXU work instead of round-tripping a gather.
+slot's mapped KV blocks through XLA gathers. This kernel keeps the
+identical flat ``(q, pools, tables, positions)`` signature and the same
+online softmax, and walks only what is live:
+
+- the grid is one step a slot. The block table and each slot's own
+  block count (``max_t positions[s, t] // block_size + 1``, capped by
+  the caller's ``n_tiles``) are scalar-prefetched to SMEM; the pools
+  stay in HBM (``memory_space=pl.ANY``);
+- inside a step a ``fori_loop`` runs over that slot's blocks a group
+  of ``C`` at a time (``group_blocks``: from static shapes, aiming at
+  ``_GROUP_TOKENS`` tokens). Each loop step starts the async copies of
+  the next group (one a block, K and V, ids read from the table: one
+  block is one contiguous ``[bs, KVH*D]`` slab, so one copy brings all
+  KV heads) into the other half of a two-slot VMEM buffer, waits for
+  its own, and computes on it as one ``[C*bs, KVH*D]`` tile;
+- a slot's last group is ragged: blocks past the slot's count are not
+  fetched, their columns are masked.
 
 Contract (shared with the jnp walk, parity-pinned in
 tests/test_serving_spec.py):
 
-- row ``(s, t)`` attends every column ``c <= positions[s, t]``;
-- GQA runs against the UNEXPANDED pools (``n_rep`` query heads per KV
-  head, grouped batched dots — never a repeated pool);
+- row ``(s, t)`` attends every column ``c <= positions[s, t]`` of the
+  first ``n_tiles`` blocks; unmapped (-1) table entries read block 0;
+- GQA runs against the UNEXPANDED pools: a KV head is a 128-lane slice
+  of the tile, its ``n_rep`` query heads are the rows of one dot;
+- operands go to the MXU in the pool's dtype with float32
+  accumulation; m, l, acc and the softmax are float32;
 - ``k_scale``/``v_scale`` switch the tile load to int8-dequant mode;
-- recycled-block garbage (NaN/inf from a previous request) is
-  sanitized per tile, so masked columns contribute exactly zero;
-- tiles at or past ``n_tiles`` are skipped (``@pl.when``), so short
-  histories pay only their own compute (their DMAs land on the
-  clamped block and are overlapped anyway).
+- a masked column contributes exactly zero whatever a recycled or
+  never-fetched block holds (NaN/inf from a previous request): its
+  score is replaced before the softmax and V's non-finite values are
+  zeroed before the PV dot.
 
 ``interpret=True`` runs the same kernel through the Pallas interpreter
 — how the CPU parity test asserts same-numerics without a TPU.
@@ -39,9 +49,16 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .grouped_matmul import _dot_precision
+
 __all__ = ["paged_attention_kernel", "kernel_available"]
 
 _NEG_INF = -1e30
+_LANES = 128
+# what a group of blocks aims at and may cost (see group_blocks)
+_GROUP_TOKENS = 512
+_KV_VMEM_BUDGET = 4 * 1024 * 1024
+_SCORE_VMEM_BUDGET = 1024 * 1024
 
 
 def kernel_available(head_dim: int, interpret: bool = False) -> bool:
@@ -50,89 +67,170 @@ def kernel_available(head_dim: int, interpret: bool = False) -> bool:
     anywhere through the interpreter, for CPU parity tests).
 
     The shape rule is Mosaic's, found by compiling against a v5e
-    topology (jax 0.9.0, libtpu 0.0.34): the kernel views a K/V tile
-    ``[bs, KVH*D]`` as ``[bs, KVH, D]``, which splits the lane dimension
-    and is refused (``infer-vector-layout: unsupported shape cast``)
-    unless D fills whole 128-lane registers. head_dim 128 and 256
-    compile for every (KVH, n_rep, T, block_size, pool dtype) tried —
-    KVH 1..32, n_rep 1..8, T 1..64, block_size 1..128, bf16/f32/int8
-    pools; head_dim 16, 32 and 64 are refused (64 compiles only in the
-    degenerate KVH = n_rep = 1 f32 case). tests/test_serving_spec.py
-    pins the rule."""
+    topology (jax 0.9.0, libtpu 0.0.34): the kernel takes a KV head as
+    the lane slice ``[h*D, (h+1)*D)`` of a K/V tile ``[tokens, KVH*D]``
+    and a block as one DMA of ``[bs, KVH*D]``, so D has to fill whole
+    128-lane registers. head_dim 128 and 256 compile for every (KVH,
+    n_rep, T, block_size, pool dtype) tried: KVH 1..32, n_rep 1..8, T
+    1..256, block_size 2..128, bf16/f32/int8 pools; a block thinner
+    than one 32-bit sublane row (block_size 1 in bf16, 1-2 in int8) is
+    refused ("slice shape must be aligned to tiling") and the seam
+    sends it to the jnp walk. The old kernel's lane-splitting reshape
+    was refused below head_dim 128; this one's slices compile at
+    head_dim 64 and 32 too where KVH*D is a multiple of 128, but have
+    not run on a chip, so those widths stay with the walk.
+    tests/test_serving_spec.py pins the rule."""
     if interpret:
         return True
     return jax.default_backend() == "tpu" and head_dim % 128 == 0
 
 
-def _kernel(tables_ref, pos_ref, nt_ref, *refs, block_size, n_rep, T,
-            kvh, head_dim, dequant):
-    """One (slot, tile) program. Scalar-prefetch refs: the flat block
-    table (drives the BlockSpec index maps — see the pallas_call),
-    per-row positions, and the live tile count. Tensor refs:
-    q [1, T, H*D] | k/v tile [1, bs, K*D] | (k/v scale [1, bs, K]) |
-    out [1, T, H*D]; scratch: m/l [K, T*R] + acc [K, T*R, D] carries
-    that live across the sequential tile dimension of the grid."""
-    if dequant:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s = refs
-        ks_ref = vs_ref = None
+def group_blocks(block_size: int, kv_width: int, pool_dtype, T: int,
+                 n_rep: int, max_blocks: int, dequant: bool = False) -> int:
+    """Blocks the kernel fetches and computes on at once (``C``), from
+    static shapes only. A group of ``C * block_size`` tokens aims at
+    ``_GROUP_TOKENS``, less where the double-buffered K and V tiles
+    (``4 * tokens * kv_width`` pool elements, plus the scales of an
+    int8 pool) or the float32 score tile (``T * n_rep`` rows) would
+    pass their VMEM budgets, and never more than the table holds. One
+    block a group where blocks do not stack into one tile for free
+    (``block_size`` short of the sublane packing of what the tile is
+    computed in: 8 rows for 4-byte, 16 for 2-byte, 32 for 1-byte
+    elements)."""
+    itemsize = jnp.dtype(pool_dtype).itemsize
+    packing = 8 if dequant else 32 // itemsize
+    if block_size % packing:
+        return 1
+    per_token = 4 * kv_width * itemsize + (
+        4 * _LANES * 4 if dequant else 0)
+    tokens = min(_GROUP_TOKENS, _KV_VMEM_BUDGET // per_token,
+                 _SCORE_VMEM_BUDGET // (4 * T * n_rep))
+    return int(max(1, min(tokens // block_size, max_blocks)))
+
+
+def _vmem_limit(TR, K, D, G, q_dtype, pool_dtype, dequant) -> int:
+    """Scoped-VMEM limit for one slot's program, from its shapes: the
+    double-buffered q/out/position blocks and K/V group buffers, the
+    lane-padded m/l and acc state, and the live float32 score tiles;
+    half as much again for what Mosaic keeps, and never under the
+    compiler's own default."""
+    rows = K * TR
+    need = (4 * rows * D * jnp.dtype(q_dtype).itemsize
+            + 2 * TR * _LANES * 4
+            + 4 * G * K * D * jnp.dtype(pool_dtype).itemsize
+            + (4 * G * _LANES * 4 + 2 * G * D * 4 if dequant else 0)
+            + 2 * rows * _LANES * 4 + rows * D * 4
+            + 6 * TR * max(G, _LANES) * 4)
+    return max(16 * 1024 * 1024, need * 3 // 2)
+
+
+def group_tokens(block_size: int, *shapes) -> int:
+    """Tokens a loop step of the kernel covers (``group_blocks``'
+    arguments): what a slot's walk is rounded up to, and what
+    ``serving.decode``'s ``walk_tokens`` counts with."""
+    return block_size * group_blocks(block_size, *shapes)
+
+
+def _kernel(tables_ref, nblk_ref, *refs, block_size, C, kvh, head_dim,
+            dequant, cdtype):
+    """One slot's program. Scalar-prefetch refs (SMEM): the block table
+    and each slot's live block count. Tensor refs: q [1, K, T*R, D] and
+    row positions [1, T*R, 1] in VMEM | K and V pools [NB, bs, K*D]
+    (and their scales [NB, bs, K]) left in HBM | out [1, K, T*R, D].
+    Scratch: the two-slot group buffers [2, C, bs, .] the DMAs land in,
+    their semaphores [stream, slot], and the float32 online-softmax
+    state m/l [K, T*R, 1], acc [K, T*R, D]."""
+    n = 4 if dequant else 2                # K, V (and their scales)
+    q_ref, pos_ref, o_ref = refs[0], refs[1], refs[2 + n]
+    bufs = refs[3 + n:3 + 2 * n]
+    streams = tuple(zip(refs[2:2 + n], bufs))
+    sems, m_s, l_s, acc_s = refs[3 + 2 * n:]
     s = pl.program_id(0)
-    t = pl.program_id(1)
-    R, D = n_rep, head_dim
+    D, G = head_dim, C * block_size
+    nb = nblk_ref[s]                       # this slot's own blocks
+    n_groups = (nb + C - 1) // C
 
-    @pl.when(t == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, _NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+    def copies(g, half, act):
+        """Start, or wait for, the copy of every live block of group
+        ``g`` into buffer ``half`` (blocks past the slot's count are
+        never fetched)."""
+        def one(j, carry):
+            # unmapped (-1) entries clamp to block 0, as the jnp
+            # walk's max(tables, 0): the mask is by position only
+            blk = jnp.maximum(tables_ref[s, g * C + j], 0)
+            for i, (hbm, buf) in enumerate(streams):
+                getattr(pltpu.make_async_copy(
+                    hbm.at[blk], buf.at[half, j], sems.at[i, half]),
+                    act)()
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(C, nb - g * C), one, 0)
 
-    @pl.when(t < nt_ref[0])
-    def _tile():
-        k_t = k_ref[0].reshape(block_size, kvh, D)
-        v_t = v_ref[0].reshape(block_size, kvh, D)
-        if dequant:
-            k_t = k_t.astype(jnp.float32) * ks_ref[0][..., None]
-            v_t = v_t.astype(jnp.float32) * vs_ref[0][..., None]
-        # recycled blocks may hold non-finite garbage from a previous
-        # request — same sanitization as the jnp walk, masked columns
-        # must contribute EXACTLY zero (0 * NaN = NaN in the PV dot)
-        k_t = jnp.nan_to_num(k_t.astype(jnp.float32))
-        v_t = jnp.nan_to_num(v_t.astype(jnp.float32))
-        # grouped GQA: [K, T*R, D] x [K, bs, D] batched over KV heads,
-        # never expanding the pools n_rep-fold
-        q = q_ref[0].reshape(T, kvh, R, D).transpose(1, 0, 2, 3)
-        q = q.reshape(kvh, T * R, D).astype(jnp.float32)
-        kt = k_t.transpose(1, 0, 2)                    # [K, bs, D]
-        vt = v_t.transpose(1, 0, 2)
-        scores = jax.lax.dot_general(
-            q, kt, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [K, T*R, bs]
-        scores = scores * (1.0 / float(np.sqrt(D)))
-        cols = t * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (T, block_size), 1)
-        posv = jnp.stack([pos_ref[s, i] for i in range(T)])
-        ok = cols <= posv[:, None]                     # [T, bs]
-        okr = jnp.repeat(ok, R, axis=0)                # rows t*R + r
-        scores = jnp.where(okr[None], scores, _NEG_INF)
-        m_new = jnp.maximum(m_s[...], jnp.max(scores, axis=-1))
-        # a fully-masked row has scores == m_new == -1e30: exp gives 1,
-        # re-mask p so its contribution is exactly zero (jnp-walk rule)
-        p = jnp.where(okr[None], jnp.exp(scores - m_new[..., None]),
-                      0.0)
-        corr = jnp.exp(m_s[...] - m_new)
-        l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1)
-        pv = jax.lax.dot_general(
-            p, vt, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [K, T*R, D]
-        acc_s[...] = acc_s[...] * corr[..., None] + pv
-        m_s[...] = m_new
+    m_s[...] = jnp.full_like(m_s, _NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
 
-    @pl.when(t == pl.num_programs(1) - 1)
-    def _done():
-        out = acc_s[...] / jnp.maximum(l_s[...], 1e-30)[..., None]
-        out = out.reshape(kvh, T, R, D).transpose(1, 0, 2, 3)
-        o_ref[0] = out.reshape(T, kvh * R * D).astype(o_ref.dtype)
+    @pl.when(n_groups > 0)
+    def _first():
+        copies(0, 0, "start")
+
+    posv = pos_ref[0]                                  # [T*R, 1]
+    scale = 1.0 / float(np.sqrt(D))
+    # operands go to the MXU in the pool's dtype, accumulation is float32
+    prec = _dot_precision(jnp.promote_types(q_ref.dtype, cdtype))
+
+    def group(g, carry):
+        half = g % 2
+
+        @pl.when(g + 1 < n_groups)
+        def _prefetch():
+            copies(g + 1, 1 - half, "start")
+
+        copies(g, half, "wait")
+        cols = g * G + jax.lax.broadcasted_iota(
+            jnp.int32, (posv.shape[0], G), 1)
+        # a column is attended up to the row's position, and only where
+        # the walk reaches (the caller's n_tiles may stop it short)
+        ok = (cols <= posv) & (cols < nb * block_size)  # [T*R, G]
+        for h in range(kvh):
+            lanes = slice(h * D, (h + 1) * D)
+            k_t = bufs[0][half, :, :, lanes]           # [C, bs, D]
+            v_t = bufs[1][half, :, :, lanes]
+            if dequant:
+                k_t = k_t.astype(jnp.float32) * bufs[2][half, :, :, h:h + 1]
+                v_t = v_t.astype(jnp.float32) * bufs[3][half, :, :, h:h + 1]
+            # a masked column must contribute EXACTLY zero whatever a
+            # recycled or never-fetched block holds. K needs no care:
+            # its scores are replaced below, never multiplied. V does
+            # (0 * NaN = NaN in the PV dot): non-finite values go to 0,
+            # compared in float32 (the v5e's VPU has no bf16 compare)
+            v_t = v_t.astype(jnp.float32)
+            v_t = jnp.where(jnp.abs(v_t) <= float(jnp.finfo(cdtype).max),
+                            v_t, 0.0)
+            k_t = k_t.reshape(G, D).astype(cdtype)
+            v_t = v_t.reshape(G, D).astype(cdtype)
+            sc = jax.lax.dot_general(
+                q_ref[0, h], k_t, (((1,), (1,)), ((), ())),
+                precision=prec,
+                preferred_element_type=jnp.float32) * scale  # [T*R, G]
+            sc = jnp.where(ok, sc, _NEG_INF)
+            m_old = m_s[h]
+            m_new = jnp.maximum(m_old, jnp.max(sc, axis=-1,
+                                               keepdims=True))
+            # a fully-masked row has sc == m_new == -1e30: exp gives 1,
+            # re-mask p so its contribution is exactly zero
+            p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
+            corr = jnp.exp(m_old - m_new)
+            l_s[h] = l_s[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_s[h] = acc_s[h] * corr + jax.lax.dot_general(
+                p.astype(cdtype), v_t, (((1,), (0,)), ((), ())),
+                precision=prec,
+                preferred_element_type=jnp.float32)    # [T*R, D]
+            m_s[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, group, 0)
+    o_ref[0] = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)).astype(
+        o_ref.dtype)
 
 
 @functools.partial(
@@ -141,54 +239,67 @@ def _paged_attention_call(q, k_pool, v_pool, tables, positions,
                           n_tiles, k_scale, v_scale, *, block_size,
                           n_rep, interpret):
     S, T, H, D = q.shape
-    K = k_pool.shape[2]
+    NB, _, K, _ = k_pool.shape
     MB = tables.shape[1]
+    R, TR = n_rep, T * n_rep
     dequant = k_scale is not None
+    C = group_blocks(block_size, K * D, k_pool.dtype, T, R, MB, dequant)
+    # int8 pools dequantise to the queries' dtype, as the jnp walk does
+    cdtype = q.dtype if dequant else k_pool.dtype
     kernel = functools.partial(
-        _kernel, block_size=block_size, n_rep=n_rep, T=T, kvh=K,
-        head_dim=D, dequant=dequant)
-
-    def _phys(s, t, tables_ref, pos_ref, nt_ref):
-        # unmapped (-1) and beyond-n_tiles entries clamp to block 0:
-        # the DMA still lands somewhere valid, @pl.when skips/masks
-        # the compute exactly like the jnp walk's max(tables, 0)
-        return jnp.maximum(tables_ref[s, t], 0)
-
-    q_spec = pl.BlockSpec(
-        (1, T, H * D), lambda s, t, tr, pr, nr: (s, 0, 0))
-    kv_spec = pl.BlockSpec(
-        (1, block_size, K * D),
-        lambda s, t, tr, pr, nr: (_phys(s, t, tr, pr, nr), 0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [q.reshape(S, T, H * D),
-            k_pool.reshape(k_pool.shape[0], block_size, K * D),
-            v_pool.reshape(v_pool.shape[0], block_size, K * D)]
+        _kernel, block_size=block_size, C=C, kvh=K, head_dim=D,
+        dequant=dequant, cdtype=cdtype)
+    positions = positions.astype(jnp.int32)
+    # each slot walks its own blocks only: its longest row's, capped
+    # by the caller's n_tiles and by the table
+    nblk = jnp.minimum(jnp.max(positions, axis=1) // block_size + 1,
+                       jnp.minimum(jnp.asarray(n_tiles, jnp.int32), MB))
+    # rows of one KV head together, (t, r)-major: q is laid out once
+    # here, not once a tile in the kernel
+    q_rows = q.reshape(S, T, K, R, D).transpose(0, 2, 1, 3, 4).reshape(
+        S, K, TR, D)
+    pos_rows = jnp.repeat(positions, R, axis=1)[..., None]
+    row_spec = pl.BlockSpec((1, K, TR, D), lambda s, tr, nr: (s, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [row_spec,
+                pl.BlockSpec((1, TR, 1), lambda s, tr, nr: (s, 0, 0)),
+                hbm, hbm]
+    args = [q_rows, pos_rows,
+            k_pool.reshape(NB, block_size, K * D),
+            v_pool.reshape(NB, block_size, K * D)]
+    scratch = [pltpu.VMEM((2, C, block_size, K * D), k_pool.dtype),
+               pltpu.VMEM((2, C, block_size, K * D), v_pool.dtype)]
     if dequant:
-        sc_spec = pl.BlockSpec(
-            (1, block_size, K),
-            lambda s, t, tr, pr, nr: (_phys(s, t, tr, pr, nr), 0, 0))
-        in_specs += [sc_spec, sc_spec]
-        args += [k_scale, v_scale]
+        # Mosaic copies whole 128-lane rows only: the [NB, bs, K] scales
+        # (lane-padded in HBM as they are) get their lanes made explicit
+        pad = ((0, 0), (0, 0), (0, -K % _LANES))
+        in_specs += [hbm, hbm]
+        args += [jnp.pad(k_scale, pad), jnp.pad(v_scale, pad)]
+        scratch += [pltpu.VMEM((2, C) + args[-1].shape[1:], sc.dtype)
+                    for sc in (k_scale, v_scale)]
+    scratch += [pltpu.SemaphoreType.DMA((len(args) - 2, 2)),
+                pltpu.VMEM((K, TR, 1), jnp.float32),
+                pltpu.VMEM((K, TR, 1), jnp.float32),
+                pltpu.VMEM((K, TR, D), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, MB),
+        num_scalar_prefetch=2,
+        grid=(S,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, T, H * D), lambda s, t, tr, pr, nr: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((K, T * n_rep), jnp.float32),
-            pltpu.VMEM((K, T * n_rep), jnp.float32),
-            pltpu.VMEM((K, T * n_rep, D), jnp.float32),
-        ],
+        out_specs=row_spec,
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, T, H * D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, K, TR, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit(
+                TR, K, D, C * block_size, q.dtype, k_pool.dtype, dequant)),
         interpret=interpret,
-    )(tables.astype(jnp.int32), positions.astype(jnp.int32),
-      jnp.asarray(n_tiles, jnp.int32).reshape(1), *args)
-    return out.reshape(S, T, H, D)
+    )(tables.astype(jnp.int32), nblk, *args)
+    return out.reshape(S, K, T, R, D).transpose(0, 2, 1, 3, 4).reshape(
+        S, T, H, D)
 
 
 def paged_attention_kernel(q, k_pool, v_pool, tables, positions, *,
@@ -198,7 +309,7 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, positions, *,
     """Flat-signature drop-in for ``serving_cache.paged_attention``
     (q [S, T, H, D], pools [num_blocks, bs, KVH, D], tables
     [S, max_blocks], positions [S, T]); ``n_tiles`` may be traced —
-    it rides in as a scalar-prefetch operand bounding the live tiles.
+    it caps every slot's own block count.
     """
     if n_tiles is None:
         n_tiles = tables.shape[1]

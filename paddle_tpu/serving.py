@@ -529,10 +529,10 @@ class LlamaDecodeEngine:
         materializes a ``[*, max_seq]`` score row (the two historical
         ``jax.nn.softmax(scores)`` sites lived here), GQA stays a
         grouped contraction against the UNEXPANDED caches, and the
-        Pallas kernel accelerates the dense engine too. The walk still
-        streams every max_seq column (all tiles): the dense cache IS
-        capacity-sized — O(active tokens) streaming is precisely what
-        the paged engine's block tables buy."""
+        Pallas kernel accelerates the dense engine too. The jnp walk
+        streams every max_seq column (all tiles) of the
+        capacity-sized dense cache; the kernel stops each slot at its
+        own length (the columns past it were masked: same numbers)."""
         S, M = k_all.shape[0], k_all.shape[1]
         ts = self._attend_tile
         nb = M // ts
@@ -545,6 +545,16 @@ class LlamaDecodeEngine:
         return self._sc.paged_attention(
             q, k_pool, v_pool, tables, positions, block_size=ts,
             n_rep=self.n_rep, use_kernel=self._pa_kernel)
+
+    def walk_group_tokens(self, T: int = 1) -> int:
+        """Tokens the paged-attention kernel fetches and computes on a
+        loop step at this engine's shapes (``T`` rows a slot): a slot
+        at ``pos`` walks ``pos + 1`` rounded up to it."""
+        from .ops.pallas.paged_attention import group_tokens
+        ts = self._attend_tile
+        return group_tokens(
+            ts, self.cfg.num_key_value_heads * self.head_dim, self.dtype,
+            T, self.n_rep, self.max_seq // ts)
 
     def _block(self, lp, h, kc_l, vc_l, positions, write_cols):
         """One decoder layer over [S, T, H] with fixed-cache K/V
@@ -952,6 +962,13 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                        for p in pools]
                 for name, pools in kvs.items()}
 
+    def walk_group_tokens(self, T: int = 1) -> int:
+        from .ops.pallas.paged_attention import group_tokens
+        return group_tokens(
+            self.block_size, self.cfg.num_key_value_heads * self.head_dim,
+            self.kvs["k"][0].dtype, T, self.n_rep,
+            self._kv.block_tables.shape[1], self.kv_quant == "int8")
+
     def _block_paged(self, lp, h, kvl, positions, tables, n_tiles,
                      wmask):
         """One decoder layer over [S, T, H] with block-pool K/V writes
@@ -1006,9 +1023,10 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
     def _decode_impl(self, params, kv, last_ids, pos, tables, act):
         """One token for every slot: ids [S,1], pos [S] = write
         position, tables [S, max_blocks] block tables, act [S] bool
-        (inactive slots neither write nor advance). The block walk is
-        bounded by the LONGEST active history, so short batches pay
-        only their own tiles."""
+        (inactive slots neither write nor advance). ``n_tiles`` caps
+        the block walk at the LONGEST history; behind the seam the
+        jnp walk runs that far for every slot, the Pallas kernel stops
+        each slot at its own last block."""
         positions = pos[:, None]                        # [S, 1]
         n_tiles = jnp.max(pos) // self.block_size + 1
         logits, kv = self._forward_paged(params, kv, last_ids,
@@ -2390,11 +2408,18 @@ class GenerationServer:
         """What the next decode launch reads, counted before it runs:
         `rows` active slots, `live_tokens` = sum over them of `pos + 1`
         (each row's history and the token it writes), `max_ctx` the
-        longest of them, which bounds the paged kernel's block walk."""
+        longest of them, and `walk_tokens`, what the paged kernel
+        walks for them: each slot's `pos + 1` rounded up to the
+        kernel's group of blocks. `live_tokens / walk_tokens` is the
+        live share of the walk; `rows * max_ctx` is what walking every
+        slot to the longest context cost."""
         eng = self.engine
         ctx = np.asarray(eng.pos)[np.asarray(eng.active, bool)] + 1
+        # an engine that is not this module's has no kernel to ask
+        group = getattr(eng, "walk_group_tokens", lambda: 1)()
         return {"rows": int(ctx.size), "live_tokens": int(ctx.sum()),
-                "max_ctx": int(ctx.max()) if ctx.size else 0}
+                "max_ctx": int(ctx.max()) if ctx.size else 0,
+                "walk_tokens": int((-(-ctx // group) * group).sum())}
 
     def _sweep(self) -> None:
         """The step boundary's housekeeping, as one span: deadlines,

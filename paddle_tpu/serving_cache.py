@@ -783,6 +783,11 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
         # un-chunked whole-prompt prefill can exceed per-core VMEM —
         # those calls take the jnp walk, same numerics
         use_kernel = False
+    if use_kernel and block_size * k_pool.dtype.itemsize < 4:
+        # the kernel copies one block at a time, and Mosaic copies no
+        # slab thinner than a 32-bit sublane row (block_size 1 in
+        # bf16, 1-2 in int8: no engine's default): same numerics
+        use_kernel = False
     from .ops.pallas import count_path
     count_path("paged_attention", "pallas" if use_kernel else "jnp_walk")
     if use_kernel:

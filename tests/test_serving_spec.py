@@ -302,6 +302,132 @@ class TestPagedAttentionKernelSeam:
                     np.asarray(ref), np.asarray(got), rtol=1e-6,
                     atol=1e-6, err_msg=f"geometry {geo} quant={quant}")
 
+    # id: (S, T, H, KVH, D, block_size, first positions, pool dtype,
+    #      extras). The table holds one group of blocks and four more
+    # (a count the group does not divide); a position is a number or
+    # "block-2" (a history of one block less one token), "group-1" |
+    # "group" (a group's last | the next group's first column), "last"
+    # (the window ends at max_seq - 1)
+    RAGGED = {
+        "ragged_slots_T1": (5, 1, 4, 2, 8, 16,
+                            [0, "block-2", "group-1", "group", "last"],
+                            "float32", {}),
+        "small_blocks_f32": (3, 1, 4, 2, 8, 8, ["last", 100, "group"],
+                             "float32", {}),
+        "yi_geometry_bf16": (3, 1, 32, 4, 128, 16, [5, 200, "last"],
+                             "bfloat16", {}),
+        "yi_geometry_f32": (2, 1, 32, 4, 128, 16, [17, "group"],
+                            "float32", {}),
+        "mistral_geometry": (2, 1, 32, 8, 128, 16, [31, "group-1"],
+                             "float32", {}),
+        "verify_window_T5": (4, 5, 4, 2, 8, 16,
+                             [0, 11, "group-1", "last"], "float32", {}),
+        # a later prefill chunk: one slot, 64 rows from mid-block,
+        # across a group's edge
+        "prefill_chunk_mid_block": (1, 64, 4, 2, 8, 16, ["group-41"],
+                                    "float32", {}),
+        "prefill_chunk_yi_bf16": (1, 64, 32, 4, 128, 16, [23],
+                                  "bfloat16", {}),
+        "int8_pools_ragged": (4, 1, 4, 2, 8, 16,
+                              [0, "block-2", "group-1", "last"],
+                              "float32", {"quant": True}),
+        "int8_pools_verify": (2, 5, 4, 2, 8, 16, ["group-1", 40],
+                              "float32", {"quant": True}),
+        # entries past each slot's history unmapped; slots 0 and 1
+        # share their first three blocks (a shared prefix)
+        "unmapped_and_shared_tables": (
+            3, 1, 4, 2, 8, 16, [60, "group", 0], "float32",
+            {"unmap_tail": True, "share": 3}),
+        # the caller stops the walk short of a slot's own bound: the
+        # columns past it are not attended, on either path
+        "n_tiles_below_a_slots_bound": (
+            3, 1, 4, 2, 8, 16, ["last", 40, "group"], "float32",
+            {"n_tiles": 3}),
+        "n_tiles_one_block_short": (
+            3, 1, 4, 2, 8, 16, ["last", 40, "group"], "float32",
+            {"n_tiles": -1}),
+    }
+
+    @pytest.mark.parametrize("name", sorted(RAGGED))
+    def test_kernel_matches_jnp_walk_on_ragged_batches(self, name):
+        """Each slot walks its own blocks only, a group at a time:
+        every way a slot's history can sit against the groups, at the
+        served geometries, agrees with the jnp walk."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas import paged_attention as pk
+        from paddle_tpu.serving_cache import (absmax_quantize,
+                                              paged_attention)
+        S, T, H, K, D, bs, first, dtype, extra = self.RAGGED[name]
+        rng = np.random.default_rng(sorted(self.RAGGED).index(name))
+        dt = jnp.dtype(dtype)
+        quant = bool(extra.get("quant"))
+        geo = (bs, K * D, jnp.int8 if quant else dt, T, H // K)
+        MB = pk.group_tokens(*geo, 1 << 20, quant) // bs + 4
+        group = pk.group_tokens(*geo, MB, quant)
+        assert bs < group < bs * MB, "several blocks, several groups"
+        where = {"block-2": bs - 2, "group-1": group - 1, "group": group,
+                 "group-41": group - 41, "last": bs * MB - T}
+        pos = (np.asarray([where.get(f, f) for f in first],
+                          np.int32)[:, None]
+               + np.arange(T, dtype=np.int32)[None, :])
+        assert 0 <= pos.min() and pos.max() < bs * MB
+        NB = S * MB + 3
+        q = jnp.asarray(rng.standard_normal((S, T, H, D)), dt)
+        kp = jnp.asarray(rng.standard_normal((NB, bs, K, D)), dt)
+        vp = jnp.asarray(rng.standard_normal((NB, bs, K, D)), dt)
+        tables = rng.permutation(NB)[:S * MB].reshape(S, MB).astype(
+            np.int32)
+        if extra.get("share"):
+            tables[1, :extra["share"]] = tables[0, :extra["share"]]
+        if extra.get("unmap_tail"):
+            for s_ in range(S):
+                tables[s_, pos[s_].max() // bs + 1:] = -1
+        kw = dict(block_size=bs, n_rep=H // K)
+        if "n_tiles" in extra:
+            kw["n_tiles"] = jnp.asarray(extra["n_tiles"] % (MB + 1),
+                                        jnp.int32)
+        if quant:
+            kq, ks = absmax_quantize(kp.reshape(NB * bs, K, D))
+            vq, vs = absmax_quantize(vp.reshape(NB * bs, K, D))
+            kw.update(k_scale=ks.reshape(NB, bs, K),
+                      v_scale=vs.reshape(NB, bs, K))
+            kp, vp = kq.reshape(NB, bs, K, D), vq.reshape(NB, bs, K, D)
+        tables, pos = jnp.asarray(tables), jnp.asarray(pos)
+        ref = paged_attention(q, kp, vp, tables, pos, use_kernel=False,
+                              **kw)
+        got = pk.paged_attention_kernel(q, kp, vp, tables, pos,
+                                        interpret=True, **kw)
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        # bfloat16: both paths round p and the output to 8 bits, a
+        # group at a time against a block at a time
+        tol = 2e-2 if dtype == "bfloat16" else 1e-5
+        np.testing.assert_allclose(
+            np.asarray(ref, np.float32), np.asarray(got, np.float32),
+            rtol=tol, atol=tol)
+
+    def test_group_size_comes_from_static_shapes(self):
+        """What a group is at the shapes that are served, and where it
+        falls back to one block: blocks that do not stack into a tile
+        for free, or a block that is a group already."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas import paged_attention as pk
+        bf16, f32, i8 = jnp.bfloat16, jnp.float32, jnp.int8
+        # (bs, KVH*D, pool dtype, T, n_rep, max_blocks, dequant) -> C
+        for args, want in [
+                ((16, 512, bf16, 1, 8, 128, False), 32),   # Yi decode
+                ((16, 512, bf16, 64, 8, 128, False), 32),  # Yi chunk
+                ((16, 1024, bf16, 1, 4, 128, False), 32),  # Mistral
+                ((16, 512, i8, 1, 8, 128, True), 32),      # int8 pool
+                ((128, 512, bf16, 1, 8, 16, False), 4),    # dense tile
+                ((16, 512, bf16, 1, 8, 3, False), 3),      # short table
+                ((8, 512, bf16, 1, 8, 128, False), 1),     # half a tile
+                ((4, 512, f32, 1, 8, 128, False), 1),
+                ((8, 512, f32, 1, 8, 128, False), 64),
+                ((16, 4096, f32, 1, 1, 128, False), 4),    # wide K/V
+                ((16, 512, bf16, 256, 8, 128, False), 8)]:  # wide scores
+            assert pk.group_blocks(*args) == want, (args, want)
+        assert pk.group_tokens(16, 512, bf16, 1, 8, 128) == 512
+
     def test_kernel_sanitizes_recycled_garbage(self):
         """The MASKED-garbage contract, kernel side: an unmapped
         table entry (-1) clamps its gather to physical block 0 — fill
@@ -447,13 +573,15 @@ class TestJaxprPins:
         """The acceptance pin holds on the KERNEL path too: with the
         seam forced to the Pallas kernel, the paged decode step's
         jaxpr (pallas_call inner jaxpr included) still carries no
-        [*, max_seq]-shaped intermediate."""
+        [*, max_seq]-shaped intermediate. (The kernel computes on a
+        group of blocks at a time, 512 tokens here, so max_seq has to
+        be longer than a group for the pin to say anything.)"""
         import jax
         import jax.numpy as jnp
         from paddle_tpu import serving_cache
         monkeypatch.setattr(serving_cache, "use_kernel_default",
                             lambda head_dim: True)
-        max_seq = 48
+        max_seq = 1040
         eng = PagedLlamaDecodeEngine(model, max_slots=3,
                                      max_seq=max_seq, block_size=16)
         args = (eng.params, eng.kvs, jnp.asarray(eng.last_ids),
@@ -464,6 +592,52 @@ class TestJaxprPins:
         offenders = [(p, s) for p, s in self._walk_shapes(jaxpr)
                      if max_seq in s]
         assert offenders == [], offenders
+
+
+    def test_kernel_grid_is_one_step_a_slot_at_the_cells_geometry(self):
+        """The serving cell's decode call (32 slots, 128-entry tables
+        of 16-token blocks, Yi's heads, bf16 pool of 4096 blocks): the
+        pallas_call walks at most an eighth of the (slot, table entry)
+        steps the old grid took whatever was live (one a slot, in
+        fact), its pools stay where they are (no block spec brings
+        them in), and nothing outside or inside it is a gather or has
+        a max_seq-sized axis."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas import paged_attention as pk
+        S, T, H, K, D, bs, NB, MB = 32, 1, 32, 4, 128, 16, 4096, 128
+        sds = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v, t, p, n: pk.paged_attention_kernel(
+                q, k, v, t, p, block_size=bs, n_rep=H // K, n_tiles=n))(
+            sds((S, T, H, D), jnp.bfloat16),
+            sds((NB, bs, K, D), jnp.bfloat16),
+            sds((NB, bs, K, D), jnp.bfloat16),
+            sds((S, MB), jnp.int32), sds((S, T), jnp.int32),
+            sds((), jnp.int32))
+        calls = []
+
+        def find(jx):
+            for eqn in jx.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    calls.append(eqn)
+                for p in eqn.params.values():
+                    inner = getattr(p, "jaxpr", p)
+                    if hasattr(inner, "eqns"):
+                        find(inner)
+
+        find(jaxpr.jaxpr)
+        assert len(calls) == 1
+        gm = calls[0].params["grid_mapping"]
+        assert int(np.prod(gm.grid)) == S <= S * MB // 8
+        pools = [bm for bm in gm.block_mappings
+                 if bm.array_aval.shape == (NB, bs, K * D)]
+        assert len(pools) == 2
+        for bm in pools:        # left in HBM, fetched by the kernel
+            assert "any" in str(bm.transformed_block_aval).lower(), bm
+        shapes = self._walk_shapes(jaxpr)
+        assert not [p for p, _ in shapes if "gather" in p]
+        assert not [(p, sh) for p, sh in shapes if bs * MB in sh]
 
 
 class TestSpecCapture:

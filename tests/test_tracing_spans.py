@@ -114,7 +114,8 @@ SERVING_SPANS = {
     "serving.prefill": {"trace_id", "slot", "start", "tokens", "bucket"},
     "serving.prefill.enqueue": set(),
     "serving.prefill.fetch": set(),
-    "serving.decode": {"step", "rows", "live_tokens", "max_ctx", "spec"},
+    "serving.decode": {"step", "rows", "live_tokens", "max_ctx",
+                       "walk_tokens", "spec"},
     "serving.decode.prepare": set(),
     "serving.decode.enqueue": set(),
     "serving.decode.fetch": set(),
@@ -234,17 +235,62 @@ def test_launch_counts_reads_the_engines_slot_state(model):
         eng = srv.engine
         eng.pos[:] = (11, 30)
         eng.active[:] = (True, True)
+        # the table is 8 blocks of 8 tokens: one group of the kernel's
+        assert eng.walk_group_tokens() == 64
         assert srv._launch_counts() == {"rows": 2, "live_tokens": 43,
-                                        "max_ctx": 31}
+                                        "max_ctx": 31, "walk_tokens": 128}
         eng.active[:] = (False, True)
         assert srv._launch_counts() == {"rows": 1, "live_tokens": 31,
-                                        "max_ctx": 31}
+                                        "max_ctx": 31, "walk_tokens": 64}
         eng.active[:] = False
         assert srv._launch_counts() == {"rows": 0, "live_tokens": 0,
-                                        "max_ctx": 0}
+                                        "max_ctx": 0, "walk_tokens": 0}
         eng.pos[:] = 0
     finally:
         srv.shutdown()
+
+
+def _round_up(n, to):
+    return -(-n // to) * to
+
+
+def test_walk_tokens_rounds_each_slot_up_to_the_kernels_group(model):
+    """`walk_tokens` is what the paged kernel walks for a launch: every
+    active slot's `pos + 1` rounded up to the group of blocks the kernel
+    chose for the engine's shapes, so it lies between what is live and
+    what walking every slot to the longest context would cost."""
+    from paddle_tpu.ops.pallas import paged_attention as pk
+    eng = PagedLlamaDecodeEngine(model, max_slots=3, max_seq=2048,
+                                 block_size=16, prefill_chunk=CHUNK)
+    srv = GenerationServer(eng)
+    try:
+        group = eng.walk_group_tokens()
+        assert group == pk.group_tokens(
+            16, CFG["num_key_value_heads"] * eng.head_dim,
+            eng.kvs["k"][0].dtype, 1, eng.n_rep, 2048 // 16) == 512
+        eng.pos[:] = (11, 600, 1023)
+        eng.active[:] = (True, True, False)
+        c = srv._launch_counts()
+        assert c == {"rows": 2, "live_tokens": 613, "max_ctx": 601,
+                     "walk_tokens": 512 + 1024}
+        assert (c["live_tokens"] < c["walk_tokens"]
+                < c["rows"] * _round_up(c["max_ctx"], group))
+        eng.active[:] = True
+        assert srv._launch_counts()["walk_tokens"] == 512 + 1024 + 1024
+        eng.active[:] = False
+        eng.pos[:] = 0
+    finally:
+        srv.shutdown()
+
+
+def test_decode_spans_carry_walk_tokens_between_live_and_the_old_walk(traced):
+    group = traced["eng"].walk_group_tokens()
+    spans = _named(traced, "serving.decode")
+    assert len(spans) >= 8
+    for _, _, _, st, _ in spans:
+        assert st["live_tokens"] <= st["walk_tokens"] \
+            <= st["rows"] * _round_up(st["max_ctx"], group), st
+        assert st["walk_tokens"] % group == 0
 
 
 def test_commit_spans_count_the_tokens_delivered(traced):
