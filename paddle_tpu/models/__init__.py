@@ -15,3 +15,6 @@ from .gpt import GPTConfig, GPTForCausalLM, shard_gpt  # noqa: F401
 from .bert import BertConfig, BertForMaskedLM, BertModel  # noqa: F401
 from .ernie_moe import ErnieMoEConfig, ErnieMoEForCausalLM  # noqa: F401
 from .llama_pipe import LlamaForCausalLMPipe  # noqa: F401
+from .cohere2_moe import (  # noqa: F401
+    Cohere2MoeConfig, Cohere2MoeForCausalLM,
+)

@@ -31,7 +31,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import count_path
 
 __all__ = ["grouped_matmul", "grouped_matmul_reference",
-           "tile_expert_ids"]
+           "tile_expert_ids", "expert_rows_matmul"]
 
 
 def grouped_matmul_reference(lhs, rhs, group_sizes):
@@ -283,3 +283,116 @@ def grouped_matmul(lhs, rhs, group_sizes, block_t: int = 128,
             valid = (jnp.arange(t) < total)[:, None]
             return out * valid.astype(out.dtype)
     return _gmm_pallas(lhs, rhs, tile_ids, block_t)
+
+
+# ---------------------------------------------------------------------------
+# forward-only rows-by-expert matmul for serving: ragged groups, static
+# shapes, empty tiles skipped
+# ---------------------------------------------------------------------------
+
+def _rows_kernel(ids_ref, nv_ref, lhs_ref, rhs_ref, out_ref):
+    """One row tile x one column tile of its expert's weight. Tiles past
+    the live ones (``nv_ref[0]``) fetch nothing new (their block indices
+    repeat the last live tile's) and write zeros."""
+    del ids_ref
+    live = pl.program_id(1) < nv_ref[0]
+
+    @pl.when(live)
+    def _dot():
+        out_ref[...] = jnp.dot(
+            lhs_ref[...], rhs_ref[0],
+            precision=_dot_precision(
+                jnp.promote_types(lhs_ref.dtype, rhs_ref.dtype)),
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+    @pl.when(jnp.logical_not(live))
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+
+def _rows_block_n(k: int, n: int, itemsize: int) -> int:
+    """Columns of the weight tile: the widest of 512/256/128 that divides
+    ``n`` and keeps one ``[k, block_n]`` tile at 4 MiB or less (two are
+    in flight)."""
+    for bn in (512, 256, 128):
+        if n % bn == 0 and k * bn * itemsize <= 4 * 1024 * 1024:
+            return bn
+    return 128
+
+
+@functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
+def _expert_rows_matmul_call(lhs, rhs, tile_ids, n_live, *, block_t,
+                             interpret=False):
+    m, k = lhs.shape
+    e, _, n = rhs.shape
+    bn = _rows_block_n(k, n, jnp.dtype(rhs.dtype).itemsize)
+    n_tiles = m // block_t
+
+    def last_live(nv):
+        return jnp.maximum(nv[0] - 1, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        # column tiles outer, row tiles inner: consecutive row tiles of
+        # one expert reuse its weight tile, so each expert that has rows
+        # is streamed once, and the dead tiles at the end repeat the
+        # last live tile's blocks, which Pallas does not fetch again
+        grid=(n // bn, n_tiles),
+        in_specs=[
+            pl.BlockSpec((block_t, k), lambda j, i, ids, nv: (
+                jnp.minimum(i, last_live(nv)), 0)),
+            pl.BlockSpec((1, k, bn), lambda j, i, ids, nv: (
+                ids[jnp.minimum(i, last_live(nv))], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((block_t, bn), lambda j, i, ids, nv: (i, j)),
+    )
+    need = 2 * (k * bn + block_t * k + block_t * bn) \
+        * jnp.dtype(rhs.dtype).itemsize + 2 * block_t * bn * 4
+    return pl.pallas_call(
+        _rows_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(32 * 1024 * 1024, need * 3 // 2)),
+        interpret=interpret,
+    )(tile_ids.astype(jnp.int32), jnp.reshape(n_live, (1,)).astype(jnp.int32),
+      lhs, rhs)
+
+
+def expert_rows_matmul(lhs, rhs, tile_ids, n_live, block_t: int,
+                       use_kernel: Optional[bool] = None,
+                       interpret: bool = False):
+    """Rows sorted by expert times their expert's matrix, forward only,
+    for serving: ``lhs [M, K]`` holds groups of rows, each group padded
+    to whole tiles of ``block_t`` rows (padding rows zero); ``tile_ids
+    [M // block_t]`` names the expert of each tile (non-decreasing over
+    the first ``n_live`` tiles; what follows is ignored); ``rhs [E, K,
+    N]``. Returns ``[M, N]``; rows of tiles past ``n_live`` are zero.
+
+    Unlike :func:`grouped_matmul` the group sizes are traced values and
+    ``M`` is the static worst case, so most tiles are dead in most
+    calls: the Pallas path skips their weight fetch and their dot. Off
+    the TPU (or for shapes the kernel does not tile) the reference runs:
+    every row against its tile's expert by a one-hot contraction. The
+    choice is counted (``pallas.path_selected_total{kernel=
+    "expert_rows_matmul"}``)."""
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu"
+    use_kernel = bool(use_kernel or interpret) and m % block_t == 0 \
+        and block_t % 8 == 0 and k % 128 == 0 and n % 128 == 0
+    count_path("expert_rows_matmul", "pallas" if use_kernel else "reference")
+    if use_kernel:
+        return _expert_rows_matmul_call(lhs, rhs, tile_ids, n_live,
+                                        block_t=int(block_t),
+                                        interpret=bool(interpret))
+    tiles = m // block_t
+    live = (jnp.arange(tiles) < n_live)
+    oh = jax.nn.one_hot(tile_ids, rhs.shape[0], dtype=jnp.float32) \
+        * live[:, None]
+    out = jnp.einsum("tbk,te,ekn->tbn", lhs.reshape(tiles, block_t, k),
+                     oh.astype(lhs.dtype), rhs,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(m, n).astype(lhs.dtype)

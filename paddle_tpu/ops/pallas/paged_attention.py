@@ -18,13 +18,18 @@ online softmax, and walks only what is live:
   KV heads) into the other half of a two-slot VMEM buffer, waits for
   its own, and computes on it as one ``[C*bs, KVH*D]`` tile;
 - a slot's last group is ragged: blocks past the slot's count are not
-  fetched, their columns are masked.
+  fetched, their columns are masked;
+- with ``lower`` (a window layer: each row's first visible position)
+  the walk starts at the slot's first live block, ``min_t lower[s, t]
+  // block_size``, and masks the ragged head of it. Without it nothing
+  of this is in the program: a full-attention call compiles as before.
 
 Contract (shared with the jnp walk, parity-pinned in
 tests/test_serving_spec.py):
 
-- row ``(s, t)`` attends every column ``c <= positions[s, t]`` of the
-  first ``n_tiles`` blocks; unmapped (-1) table entries read block 0;
+- row ``(s, t)`` attends every column ``lower[s, t] <= c <=
+  positions[s, t]`` (``lower`` 0 when not given) of the first
+  ``n_tiles`` blocks; unmapped (-1) table entries read block 0;
 - GQA runs against the UNEXPANDED pools: a KV head is a 128-lane slice
   of the tile, its ``n_rep`` query heads are the rows of one dot;
 - operands go to the MXU in the pool's dtype with float32
@@ -132,23 +137,35 @@ def group_tokens(block_size: int, *shapes) -> int:
 
 
 def _kernel(tables_ref, nblk_ref, *refs, block_size, C, kvh, head_dim,
-            dequant, cdtype):
+            dequant, cdtype, bounded):
     """One slot's program. Scalar-prefetch refs (SMEM): the block table
-    and each slot's live block count. Tensor refs: q [1, K, T*R, D] and
-    row positions [1, T*R, 1] in VMEM | K and V pools [NB, bs, K*D]
-    (and their scales [NB, bs, K]) left in HBM | out [1, K, T*R, D].
-    Scratch: the two-slot group buffers [2, C, bs, .] the DMAs land in,
-    their semaphores [stream, slot], and the float32 online-softmax
-    state m/l [K, T*R, 1], acc [K, T*R, D]."""
+    and each slot's live block count (and, ``bounded``, its first live
+    block). Tensor refs: q [1, K, T*R, D] and row positions [1, T*R, 1]
+    (and, ``bounded``, the rows' first visible positions, same shape) in
+    VMEM | K and V pools [NB, bs, K*D] (and their scales [NB, bs, K])
+    left in HBM | out [1, K, T*R, D]. Scratch: the two-slot group
+    buffers [2, C, bs, .] the DMAs land in, their semaphores [stream,
+    slot], and the float32 online-softmax state m/l [K, T*R, 1], acc
+    [K, T*R, D]."""
+    if bounded:
+        first_ref, refs = refs[0], refs[1:]
     n = 4 if dequant else 2                # K, V (and their scales)
-    q_ref, pos_ref, o_ref = refs[0], refs[1], refs[2 + n]
+    q_ref, pos_ref = refs[0], refs[1]
+    if bounded:
+        lo_ref, refs = refs[2], refs[:2] + refs[3:]
+    o_ref = refs[2 + n]
     bufs = refs[3 + n:3 + 2 * n]
     streams = tuple(zip(refs[2:2 + n], bufs))
     sems, m_s, l_s, acc_s = refs[3 + 2 * n:]
     s = pl.program_id(0)
     D, G = head_dim, C * block_size
     nb = nblk_ref[s]                       # this slot's own blocks
-    n_groups = (nb + C - 1) // C
+    if bounded:
+        # a window layer's walk: blocks [b0, nb), b0 the first live one
+        b0 = jnp.minimum(first_ref[s], nb)
+        n_groups = (nb - b0 + C - 1) // C
+    else:
+        n_groups = (nb + C - 1) // C
 
     def copies(g, half, act):
         """Start, or wait for, the copy of every live block of group
@@ -157,13 +174,19 @@ def _kernel(tables_ref, nblk_ref, *refs, block_size, C, kvh, head_dim,
         def one(j, carry):
             # unmapped (-1) entries clamp to block 0, as the jnp
             # walk's max(tables, 0): the mask is by position only
-            blk = jnp.maximum(tables_ref[s, g * C + j], 0)
+            idx = g * C + j
+            if bounded:
+                idx = idx + b0
+            blk = jnp.maximum(tables_ref[s, idx], 0)
             for i, (hbm, buf) in enumerate(streams):
                 getattr(pltpu.make_async_copy(
                     hbm.at[blk], buf.at[half, j], sems.at[i, half]),
                     act)()
             return carry
-        jax.lax.fori_loop(0, jnp.minimum(C, nb - g * C), one, 0)
+        left = nb - g * C
+        if bounded:
+            left = left - b0
+        jax.lax.fori_loop(0, jnp.minimum(C, left), one, 0)
 
     m_s[...] = jnp.full_like(m_s, _NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
@@ -188,9 +211,15 @@ def _kernel(tables_ref, nblk_ref, *refs, block_size, C, kvh, head_dim,
         copies(g, half, "wait")
         cols = g * G + jax.lax.broadcasted_iota(
             jnp.int32, (posv.shape[0], G), 1)
+        if bounded:
+            cols = cols + b0 * block_size
         # a column is attended up to the row's position, and only where
         # the walk reaches (the caller's n_tiles may stop it short)
         ok = (cols <= posv) & (cols < nb * block_size)  # [T*R, G]
+        if bounded:
+            # the ragged head of the first block, and every row's own
+            # bound where the rows of a chunk differ
+            ok = ok & (cols >= lo_ref[0])
         for h in range(kvh):
             lanes = slice(h * D, (h + 1) * D)
             k_t = bufs[0][half, :, :, lanes]           # [C, bs, D]
@@ -236,9 +265,10 @@ def _kernel(tables_ref, nblk_ref, *refs, block_size, C, kvh, head_dim,
 @functools.partial(
     jax.jit, static_argnames=("block_size", "n_rep", "interpret"))
 def _paged_attention_call(q, k_pool, v_pool, tables, positions,
-                          n_tiles, k_scale, v_scale, *, block_size,
+                          n_tiles, k_scale, v_scale, lower, *, block_size,
                           n_rep, interpret):
     S, T, H, D = q.shape
+    bounded = lower is not None
     NB, _, K, _ = k_pool.shape
     MB = tables.shape[1]
     R, TR = n_rep, T * n_rep
@@ -248,7 +278,7 @@ def _paged_attention_call(q, k_pool, v_pool, tables, positions,
     cdtype = q.dtype if dequant else k_pool.dtype
     kernel = functools.partial(
         _kernel, block_size=block_size, C=C, kvh=K, head_dim=D,
-        dequant=dequant, cdtype=cdtype)
+        dequant=dequant, cdtype=cdtype, bounded=bounded)
     positions = positions.astype(jnp.int32)
     # each slot walks its own blocks only: its longest row's, capped
     # by the caller's n_tiles and by the table
@@ -259,14 +289,21 @@ def _paged_attention_call(q, k_pool, v_pool, tables, positions,
     q_rows = q.reshape(S, T, K, R, D).transpose(0, 2, 1, 3, 4).reshape(
         S, K, TR, D)
     pos_rows = jnp.repeat(positions, R, axis=1)[..., None]
-    row_spec = pl.BlockSpec((1, K, TR, D), lambda s, tr, nr: (s, 0, 0, 0))
+    row_spec = pl.BlockSpec((1, K, TR, D), lambda s, *_: (s, 0, 0, 0))
+    pos_spec = pl.BlockSpec((1, TR, 1), lambda s, *_: (s, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [row_spec,
-                pl.BlockSpec((1, TR, 1), lambda s, tr, nr: (s, 0, 0)),
-                hbm, hbm]
-    args = [q_rows, pos_rows,
-            k_pool.reshape(NB, block_size, K * D),
-            v_pool.reshape(NB, block_size, K * D)]
+    in_specs = [row_spec, pos_spec]
+    args = [q_rows, pos_rows]
+    prefetch = [tables.astype(jnp.int32), nblk]
+    if bounded:
+        lower = jnp.maximum(lower.astype(jnp.int32), 0)
+        prefetch.append(jnp.min(lower, axis=1) // block_size)
+        in_specs.append(pos_spec)
+        args.append(jnp.repeat(lower, R, axis=1)[..., None])
+    n_rows = len(args)
+    in_specs += [hbm, hbm]
+    args += [k_pool.reshape(NB, block_size, K * D),
+             v_pool.reshape(NB, block_size, K * D)]
     scratch = [pltpu.VMEM((2, C, block_size, K * D), k_pool.dtype),
                pltpu.VMEM((2, C, block_size, K * D), v_pool.dtype)]
     if dequant:
@@ -277,12 +314,12 @@ def _paged_attention_call(q, k_pool, v_pool, tables, positions,
         args += [jnp.pad(k_scale, pad), jnp.pad(v_scale, pad)]
         scratch += [pltpu.VMEM((2, C) + args[-1].shape[1:], sc.dtype)
                     for sc in (k_scale, v_scale)]
-    scratch += [pltpu.SemaphoreType.DMA((len(args) - 2, 2)),
+    scratch += [pltpu.SemaphoreType.DMA((len(args) - n_rows, 2)),
                 pltpu.VMEM((K, TR, 1), jnp.float32),
                 pltpu.VMEM((K, TR, 1), jnp.float32),
                 pltpu.VMEM((K, TR, D), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(S,),
         in_specs=in_specs,
         out_specs=row_spec,
@@ -297,23 +334,24 @@ def _paged_attention_call(q, k_pool, v_pool, tables, positions,
             vmem_limit_bytes=_vmem_limit(
                 TR, K, D, C * block_size, q.dtype, k_pool.dtype, dequant)),
         interpret=interpret,
-    )(tables.astype(jnp.int32), nblk, *args)
+    )(*prefetch, *args)
     return out.reshape(S, K, T, R, D).transpose(0, 2, 1, 3, 4).reshape(
         S, T, H, D)
 
 
 def paged_attention_kernel(q, k_pool, v_pool, tables, positions, *,
                            block_size: int, n_rep: int, n_tiles=None,
-                           k_scale=None, v_scale=None,
+                           k_scale=None, v_scale=None, lower=None,
                            interpret: bool = False):
     """Flat-signature drop-in for ``serving_cache.paged_attention``
     (q [S, T, H, D], pools [num_blocks, bs, KVH, D], tables
     [S, max_blocks], positions [S, T]); ``n_tiles`` may be traced —
-    it caps every slot's own block count.
+    it caps every slot's own block count. ``lower [S, T]`` is each
+    row's first visible position (a window layer's ``pos - W + 1``).
     """
     if n_tiles is None:
         n_tiles = tables.shape[1]
     return _paged_attention_call(
         q, k_pool, v_pool, tables, positions, n_tiles, k_scale,
-        v_scale, block_size=int(block_size), n_rep=int(n_rep),
+        v_scale, lower, block_size=int(block_size), n_rep=int(n_rep),
         interpret=bool(interpret))

@@ -187,6 +187,47 @@ def _quantize_w(w_t):
     return jnp.asarray(q), jnp.asarray(step.astype(np.float32))
 
 
+class _LlamaServe:
+    """The model's side of the seam between engine and model, for the
+    Llama code: the first user of it. A model hands the engine (through
+    ``model.serve_model()``; a model without one is a Llama) its cache
+    spec (for each layer a kind, ``full`` or ``window`` of ``W``
+    positions, and KV heads x head_dim), its parameters as the engine's
+    pytree, and a step a layer ``(h, the layer's pools, positions, the
+    kind's block table) -> (h, pools, counts)``. The engine keeps slots,
+    tables, chunking, donation, warm-up and the loop. Llama's layer math
+    stays on the engine (``_block_paged``; the dense engine shares it)."""
+
+    n_aux = 0            # small integers a launch hands back with its token
+    aux_names = ()
+    supports_int8 = True
+    supports_speculation = True
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    def cache_spec(self, n_layers: int) -> list:
+        cfg = self.cfg
+        return [{"kind": "full", "window": None,
+                 "kv_heads": cfg.num_key_value_heads,
+                 "head_dim": cfg.hidden_size // cfg.num_attention_heads,
+                 "q_heads": cfg.num_attention_heads}] * n_layers
+
+    def build_params(self, eng, sd):
+        return eng._build_llama_params(sd)
+
+    def embed(self, eng, params, ids):
+        return jnp.take(params["emb"], ids, axis=0).astype(eng.dtype)
+
+    def layer(self, eng, li, lp, h, kvl, positions, tables, n_tiles, wmask):
+        h, kvl = eng._block_paged(lp, h, kvl, positions, tables, n_tiles,
+                                  wmask)
+        return h, kvl, None
+
+    def head(self, eng, params, h):
+        return eng._mm(eng._rms(h, params["norm"]), params["head"])
+
+
 class LlamaDecodeEngine:
     """Compiled decode engine for a LlamaForCausalLM.
 
@@ -213,8 +254,27 @@ class LlamaDecodeEngine:
             raise ValueError(
                 f"num_layers must be in [1, {cfg.num_hidden_layers}], "
                 f"got {num_layers}")
-        self.head_dim = cfg.hidden_size // cfg.num_attention_heads
-        self.n_rep = cfg.num_attention_heads // cfg.num_key_value_heads
+        # the seam: what this model asks of a cache and how its layer steps
+        self._m = model.serve_model() if hasattr(model, "serve_model") \
+            else _LlamaServe(cfg)
+        if not getattr(self, "paged", False) \
+                and not isinstance(self._m, _LlamaServe):
+            raise NotImplementedError(
+                "the dense engine runs the Llama block only; serve "
+                f"{type(model).__name__} from PagedLlamaDecodeEngine")
+        if self.int8 and not self._m.supports_int8:
+            raise NotImplementedError(
+                f"int8 projections are not built for {type(model).__name__}")
+        self.cache_spec = self._m.cache_spec(self.n_layers)
+        self.head_dim = self.cache_spec[0]["head_dim"]
+        self.n_rep = self.cache_spec[0]["q_heads"] \
+            // self.cache_spec[0]["kv_heads"]
+        windows = {sp["window"] for sp in self.cache_spec if sp["window"]}
+        if len(windows) > 1:
+            raise NotImplementedError(
+                f"window layers of several widths {sorted(windows)}")
+        # the window of the model's window layers (None: every layer full)
+        self.window = windows.pop() if windows else None
 
         dt = jnp.bfloat16 if str(cfg.dtype) == "bfloat16" else jnp.float32
         self.dtype = dt
@@ -263,11 +323,16 @@ class LlamaDecodeEngine:
         self._init_cache()
 
     def _build_params(self, sd) -> Dict[str, object]:
-        """Device param pytree from a name -> array/Tensor state dict:
-        the same prep ``__init__`` does — dtype cast, TRANSPOSED
-        projections, optional int8 quantization, layer truncation — so
-        a swapped-in tree is layout-identical to a boot-time one and
-        the compiled step programs are reused as-is."""
+        """Device param pytree from a name -> array/Tensor state dict,
+        as the model lays it out (``serve_model().build_params``): a
+        swapped-in tree is layout-identical to a boot-time one and the
+        compiled step programs are reused as-is."""
+        return self._m.build_params(self, sd)
+
+    def _build_llama_params(self, sd) -> Dict[str, object]:
+        """Llama's tree: the same prep ``__init__`` does — dtype cast,
+        TRANSPOSED projections, optional int8 quantization, layer
+        truncation."""
         cfg, dt = self.cfg, self.dtype
 
         def get(name):
@@ -827,14 +892,18 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                  kv_quant: Optional[str] = None,
                  prefill_chunk: Optional[int] = None,
                  num_layers: Optional[int] = None,
-                 share_params: Optional[Dict[str, object]] = None):
+                 share_params: Optional[Dict[str, object]] = None,
+                 prefix_cache: Optional[bool] = None):
         from .core.flags import flag_value
         self.block_size = int(block_size or
                               flag_value("serving_block_size"))
         mbs = -(-int(max_seq) // self.block_size)
         auto = int(max_slots) * mbs  # dense capacity parity
-        self.num_blocks = int(num_blocks or
-                              flag_value("serving_num_blocks") or auto)
+        # one pool size, or one a kind of layer ({"full": n, "window": n})
+        # for a model whose cache spec has window layers
+        self.num_blocks = dict(num_blocks) if isinstance(num_blocks, dict) \
+            else int(num_blocks or flag_value("serving_num_blocks") or auto)
+        self._prefix_cache = prefix_cache
         if kv_quant not in (None, "bfloat16", "int8"):
             raise ValueError(
                 f"kv_quant must be None, 'bfloat16' or 'int8', got "
@@ -849,32 +918,60 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
 
     def _alloc_pools(self) -> Dict[str, list]:
         """Fresh zeroed block pools (per-layer K/V + optional int8
-        scales) — built at boot and again at crash recovery
-        (``reset_state``), where the donated pool pytree may be
-        mid-donation."""
-        kvh = self.cfg.num_key_value_heads
+        scales, each layer's sized by its kind) — built at boot and
+        again at crash recovery (``reset_state``), where the donated
+        pool pytree may be mid-donation."""
         pool_dt = {"int8": jnp.int8,
                    "bfloat16": jnp.bfloat16}.get(self.kv_quant,
                                                  self.dtype)
-        NB, bs, L = self.num_blocks, self.block_size, self.n_layers
-        kv = {"k": [jnp.zeros((NB, bs, kvh, self.head_dim), pool_dt)
-                    for _ in range(L)],
-              "v": [jnp.zeros((NB, bs, kvh, self.head_dim), pool_dt)
-                    for _ in range(L)]}
+        bs = self.block_size
+        shapes = [(self.num_blocks[sp["kind"]] if self._kinded
+                   else self.num_blocks, bs, sp["kv_heads"], sp["head_dim"])
+                  for sp in self.cache_spec]
+        kv = {"k": [jnp.zeros(sh, pool_dt) for sh in shapes],
+              "v": [jnp.zeros(sh, pool_dt) for sh in shapes]}
         if self.kv_quant == "int8":
-            kv["ksc"] = [jnp.zeros((NB, bs, kvh), jnp.float32)
-                         for _ in range(L)]
-            kv["vsc"] = [jnp.zeros((NB, bs, kvh), jnp.float32)
-                         for _ in range(L)]
+            kv["ksc"] = [jnp.zeros(sh[:3], jnp.float32) for sh in shapes]
+            kv["vsc"] = [jnp.zeros(sh[:3], jnp.float32) for sh in shapes]
         return kv
 
     def _init_cache(self) -> None:
         from . import serving_cache as _sc
         self._sc = _sc
-        self._kv = _sc.PagedKVCache(
-            max_slots=self.max_slots, max_seq=self.max_seq,
-            block_size=self.block_size, num_blocks=self.num_blocks)
+        kinds = sorted({sp["kind"] for sp in self.cache_spec})
+        # a model with window layers gets a table and an allocator a
+        # kind; every other model the one table it always had
+        self._kinded = kinds != ["full"]
+        if self._kinded:
+            if self.kv_quant == "int8":
+                raise NotImplementedError(
+                    "an int8 KV pool is not built for window layers")
+            given = self.num_blocks if isinstance(self.num_blocks, dict) \
+                else {}
+            # a kind with no size given holds what every slot may hold
+            # at once: max_seq in a full table, window + chunk + a block
+            # in a window table
+            self._kv = _sc.KindedKVCache(
+                self.max_slots, self.max_seq, self.block_size,
+                {k: {"num_blocks": given.get(k),
+                     "window": self.window if k == "window" else None,
+                     "window_slack": self.prefill_chunk_len}
+                 for k in kinds}, prefix_cache=self._prefix_cache)
+            self.num_blocks = {k: c.num_blocks
+                               for k, c in self._kv.kinds.items()}
+        else:
+            if isinstance(self.num_blocks, dict):
+                self.num_blocks = int(self.num_blocks["full"])
+            self._kv = _sc.PagedKVCache(
+                max_slots=self.max_slots, max_seq=self.max_seq,
+                block_size=self.block_size, num_blocks=self.num_blocks,
+                prefix_cache=self._prefix_cache)
         self.kvs = self._alloc_pools()
+        # counts a launch hands back (a model's `aux_names`) that no
+        # fetch has read yet: a prompt chunk that is not its prompt's
+        # last is never fetched, the next fetch reads them
+        self._aux_pending: List[object] = []
+        self.last_aux: Dict[str, int] = {}
         # the pool pytree is donated each step/chunk: K/V writes land
         # in place in HBM, and capture_jit keeps the paged step inside
         # captured-step accounting exactly like the dense one
@@ -933,7 +1030,7 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                            self._kv.max_blocks_per_slot - 1)
         phys = jnp.take_along_axis(tables, bidx, axis=1)
         ok = jnp.logical_and(wmask, phys >= 0)
-        phys = jnp.where(ok, phys, self.num_blocks).reshape(-1)
+        phys = jnp.where(ok, phys, kvl["k"].shape[0]).reshape(-1)
         off = (positions % self.block_size).reshape(-1)
         kf = k.reshape((S * T,) + k.shape[2:])
         vf = v.reshape((S * T,) + v.shape[2:])
@@ -965,9 +1062,10 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
     def walk_group_tokens(self, T: int = 1) -> int:
         from .ops.pallas.paged_attention import group_tokens
         return group_tokens(
-            self.block_size, self.cfg.num_key_value_heads * self.head_dim,
+            self.block_size,
+            self.cache_spec[0]["kv_heads"] * self.head_dim,
             self.kvs["k"][0].dtype, T, self.n_rep,
-            self._kv.block_tables.shape[1], self.kv_quant == "int8")
+            self._kv.max_blocks_per_slot, self.kv_quant == "int8")
 
     def _block_paged(self, lp, h, kvl, positions, tables, n_tiles,
                      wmask):
@@ -1005,20 +1103,33 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                        n_tiles, wmask):
         """Shared chunked-prefill/decode body: ids [S, T] -> logits
         [S, T, V]; the pool pytree is donated, writes land in place."""
-        h = jnp.take(params["emb"], ids, axis=0).astype(self.dtype)
+        h = self._m.embed(self, params, ids)
         out_kv = {key: [] for key in kv}
+        aux = None
         for li, lp in enumerate(params["layers"]):
             kvl = {key: kv[key][li] for key in kv}
-            h, kvl = self._block_paged(lp, h, kvl, positions, tables,
-                                       n_tiles, wmask)
+            # each layer reads the block table of its kind
+            tab = tables[self.cache_spec[li]["kind"]] \
+                if isinstance(tables, dict) else tables
+            h, kvl, counts = self._m.layer(self, li, lp, h, kvl, positions,
+                                           tab, n_tiles, wmask)
+            if counts is not None:       # summed over the layers
+                aux = counts if aux is None else aux + counts
             for key in out_kv:
                 out_kv[key].append(kvl[key])
         with jax.named_scope("paged.head"):
-            h = self._rms(h, params["norm"])
-            logits = self._mm(h, params["head"])
+            logits = self._m.head(self, params, h)
             # same MXU-vs-fused-argmax barrier as the dense engine
             logits = jax.lax.optimization_barrier(logits)
-        return logits, out_kv
+        return logits, out_kv, aux
+
+    @staticmethod
+    def _with_aux(tok, aux):
+        """The launch's token(s) with the model's counts behind them, so
+        that the one fetch a launch has brings both."""
+        if aux is None:
+            return tok
+        return jnp.concatenate([tok.reshape(-1), aux.astype(jnp.int32)])
 
     def _decode_impl(self, params, kv, last_ids, pos, tables, act):
         """One token for every slot: ids [S,1], pos [S] = write
@@ -1029,11 +1140,11 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         each slot at its own last block."""
         positions = pos[:, None]                        # [S, 1]
         n_tiles = jnp.max(pos) // self.block_size + 1
-        logits, kv = self._forward_paged(params, kv, last_ids,
-                                         positions, tables, n_tiles,
-                                         act[:, None])
+        logits, kv, aux = self._forward_paged(params, kv, last_ids,
+                                              positions, tables, n_tiles,
+                                              act[:, None])
         nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return nxt, kv
+        return self._with_aux(nxt, aux), kv
 
     def _prefill_impl(self, params, kv, ids, table_row, start, nvalid,
                       true_len):
@@ -1047,13 +1158,13 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         offs = jnp.arange(B)
         positions = (start + offs)[None, :]             # [1, B]
         wmask = (offs < nvalid)[None, :]
-        tables = table_row[None, :]
+        tables = jax.tree.map(lambda r: r[None, :], table_row)
         n_tiles = (start + nvalid - 1) // self.block_size + 1
-        logits, kv = self._forward_paged(params, kv, ids, positions,
-                                         tables, n_tiles, wmask)
+        logits, kv, aux = self._forward_paged(params, kv, ids, positions,
+                                              tables, n_tiles, wmask)
         last = jnp.clip(true_len - 1 - start, 0, B - 1)
         tok = jnp.argmax(logits[0, last, :]).astype(jnp.int32)
-        return tok, kv
+        return self._with_aux(tok, aux), kv
 
     def _decode_collect_impl(self, params, kv, last_ids, pos, buf, i,
                              tables, act):
@@ -1098,8 +1209,8 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         positions = pos[:, None] + jnp.arange(k + 1)[None, :]
         n_tiles = (jnp.max(pos) + k) // self.block_size + 1
         wmask = jnp.broadcast_to(act[:, None], positions.shape)
-        logits, kv = self._forward_paged(params, kv, ids, positions,
-                                         tables, n_tiles, wmask)
+        logits, kv, _ = self._forward_paged(params, kv, ids, positions,
+                                            tables, n_tiles, wmask)
         t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         match = (draft_tok == t[:, :k]).astype(jnp.int32)
         n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
@@ -1118,6 +1229,7 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         TARGET'S device arrays, so the draft costs only its own KV
         pool, never a second weight set."""
         from .core.flags import flag_value
+        self._refuse_speculation()
         n = int(num_layers or flag_value("serving_spec_draft_layers")
                 or max(1, self.n_layers // 2))
         if not 1 <= n <= self.n_layers:
@@ -1146,6 +1258,7 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         reservation; rejected suffixes roll their blocks back
         (``PagedKVCache.truncate``). Returns self (chainable)."""
         from .core.flags import flag_value
+        self._refuse_speculation()
         k = int(spec_tokens or flag_value("serving_spec_tokens"))
         if k < 1:
             raise ValueError(f"spec_tokens must be >= 1, got {k}")
@@ -1184,6 +1297,61 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
             warm={"program": "spec_verify", "k": k,
                   **self._warm_geo()})
         return self
+
+    def _refuse_speculation(self) -> None:
+        if not self._m.supports_speculation or self._kinded:
+            raise NotImplementedError(
+                "speculative decoding is not built for this model: a "
+                "rejected window would have to be rolled back out of a "
+                "window layer's table, whose freed blocks are gone")
+
+    def _tables_dev(self, slot: Optional[int] = None):
+        """The block table(s) as a launch takes them: the one array, or
+        one a kind of layer; ``slot`` narrows to that slot's row."""
+        def dev(t):
+            return jnp.asarray(t if slot is None else t[slot])
+        bt = self._kv.block_tables
+        if not self._kinded:
+            return dev(bt)
+        # a window table is rewritten in place between launches (entries
+        # freed behind the window): each launch gets a snapshot, since
+        # jnp.asarray may alias host memory that a launch still in
+        # flight reads (a prompt chunk is not waited for)
+        return {k: dev(t.copy()) for k, t in bt.items()}
+
+    def _defer_aux(self, result) -> None:
+        """Keep an unfetched launch's result (a prompt chunk that is not
+        its prompt's last: nobody waits for it) for the next fetch to
+        read its counts from."""
+        if self._m.n_aux:
+            self._aux_pending.append(result)
+
+    def _take_aux(self, fetched: np.ndarray) -> np.ndarray:
+        """A fetched launch result's token(s), without the model's
+        counts that ride behind them (``_with_aux``). The counts of this
+        launch and of every launch since the last fetch become
+        ``last_aux`` (``aux_names`` summed, ``moe_launches`` how many
+        launches they cover)."""
+        n = self._m.n_aux
+        if not n:
+            return fetched
+        tot = fetched[-n:].astype(np.int64)
+        for pending in self._aux_pending:
+            tot = tot + np.asarray(pending)[-n:]
+        self.last_aux = dict(zip(self._m.aux_names, (int(x) for x in tot)),
+                             moe_launches=len(self._aux_pending) + 1)
+        self._aux_pending.clear()
+        return fetched[:-n]
+
+    def _chunk_counts(self, start: int, tokens: int, bucket: int) -> dict:
+        """What a prompt chunk's turn did, for the loop's span and
+        flight event; with window layers, also the positions one of
+        them reads for these rows."""
+        out = {"start": start, "tokens": tokens, "bucket": bucket}
+        if self.window is not None:
+            out["window_tokens"] = start + tokens \
+                - max(start - self.window + 1, 0)
+        return out
 
     def _device_cow(self, slot: int, src: int, dst: int) -> None:
         """Run the boundary copy-on-write on device: block ``src`` ->
@@ -1302,13 +1470,18 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         with _span("serving.prefill.enqueue"):
             padded = np.zeros((1, b), np.int32)
             padded[0, :c] = ids[start:start + c]
-            row = jnp.asarray(self._kv.block_tables[slot])
+            if self._kinded:
+                # window tables move on with the prompt: blocks behind
+                # the chunk's first row's window go back, the chunk's
+                # own are mapped (a full table's were at admission)
+                self._kv.advance(slot, start, start + c - 1)
+            row = self._tables_dev(slot)
             tok, self.kvs = self._prefill_program(b)(
                 self.params, self.kvs, jnp.asarray(padded), row,
                 jnp.int32(start), jnp.int32(c), jnp.int32(n))
         st["next"] = start + c
         # what this turn did, for the loop's span and flight event
-        self.last_chunk = {"start": start, "tokens": c, "bucket": b}
+        self.last_chunk = self._chunk_counts(start, c, b)
         # publish every fully-written prompt block into the radix
         # tree as soon as its last token lands: a concurrent
         # admission can hit a prefix whose OWNER is still prefilling
@@ -1323,9 +1496,10 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
             if self._draft is not None \
                     and slot in self._draft._prefill_state:
                 self._draft.prefill_chunk(slot)
+            self._defer_aux(tok)
             return None
         with _span("serving.prefill.fetch"):
-            first = int(tok)
+            first = int(self._take_aux(np.asarray(tok)).reshape(-1)[0])
         del self._prefill_state[slot]
         self.pos[slot] = n
         self.active[slot] = True
@@ -1384,7 +1558,7 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
             act = jnp.asarray(self.active)
             ids = jnp.asarray(self.last_ids)
             pos = jnp.asarray(self.pos)
-            tables = jnp.asarray(self._kv.block_tables)
+            tables = self._tables_dev()
         with _span("serving.decode.enqueue"):
             if draft is not None:
                 for s in range(self.max_slots):
@@ -1398,7 +1572,7 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                 self.params, self.kvs, ids, pos, tables, act)
         self._count_pa_path()
         with _span("serving.decode.fetch"):
-            nxt = np.asarray(nxt)
+            nxt = self._take_aux(np.asarray(nxt))
         for s in range(self.max_slots):
             if self.active[s]:
                 self.pos[s] += 1
@@ -1508,6 +1682,10 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         engine's contract over the block pool. Blocks for the whole
         window are mapped up front so the device-side table stays
         valid without host round-trips."""
+        if self._kinded or self._m.n_aux:
+            raise NotImplementedError(
+                "a device-resident decode window is not built for a "
+                "model with window layers; use step()")
         if not self.active.all():
             raise ValueError(
                 "decode_steps advances EVERY slot; use step() when "
@@ -1614,7 +1792,7 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         # writer didn't
         ids = jnp.asarray(np.zeros((S, 1), np.int32))
         pos = jnp.asarray(np.zeros(S, np.int32))
-        tables = jnp.asarray(self._kv.block_tables)
+        tables = self._tables_dev()
         act = jnp.asarray(np.zeros(S, bool))
         if prog == "decode":
             self._decode._jitted.lower(
@@ -1629,7 +1807,7 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
             self._prefill_program(b)._jitted.lower(
                 self.params, self.kvs,
                 jnp.asarray(np.zeros((1, b), np.int32)),
-                jnp.asarray(self._kv.block_tables[0]),
+                self._tables_dev(0),
                 _I32, _I32, _I32).compile()
         elif prog == "spec_draft":
             draft = self._draft
@@ -1658,7 +1836,7 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         avals = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             (self.params, self.kvs, jnp.asarray(self.last_ids),
-             jnp.asarray(self.pos), jnp.asarray(self._kv.block_tables),
+             jnp.asarray(self.pos), self._tables_dev(),
              jnp.asarray(self.active)))
         exported = jax.export.export(jax.jit(self._decode_impl))(*avals)
         return exported.serialize()
@@ -2266,7 +2444,11 @@ class GenerationServer:
                 # the turn this request got: which prompt tokens, in
                 # which bucket (getattr: duck-typed fake engines keep
                 # the bare paged contract)
-                chunk = getattr(self.engine, "last_chunk", {})
+                chunk = dict(getattr(self.engine, "last_chunk", {}))
+                if first is not None:
+                    # a model's own counts (the experts' rows), read in
+                    # the fetch of a prompt's last chunk
+                    chunk.update(getattr(self.engine, "last_aux", {}))
                 span.set(**chunk)
                 _flight.record("serving", "prefill_chunk", trace_id=tid,
                                slot=slot, **chunk)
@@ -2417,9 +2599,14 @@ class GenerationServer:
         ctx = np.asarray(eng.pos)[np.asarray(eng.active, bool)] + 1
         # an engine that is not this module's has no kernel to ask
         group = getattr(eng, "walk_group_tokens", lambda: 1)()
-        return {"rows": int(ctx.size), "live_tokens": int(ctx.sum()),
-                "max_ctx": int(ctx.max()) if ctx.size else 0,
-                "walk_tokens": int((-(-ctx // group) * group).sum())}
+        out = {"rows": int(ctx.size), "live_tokens": int(ctx.sum()),
+               "max_ctx": int(ctx.max()) if ctx.size else 0,
+               "walk_tokens": int((-(-ctx // group) * group).sum())}
+        window = getattr(eng, "window", None)
+        if window is not None:
+            # what a window layer reads of them: the last `window` each
+            out["window_tokens"] = int(np.minimum(ctx, window).sum())
+        return out
 
     def _sweep(self) -> None:
         """The step boundary's housekeeping, as one span: deadlines,
@@ -2499,7 +2686,8 @@ class GenerationServer:
                     eng = self.engine
                     spec = bool(self._paged and eng.spec_ready())
                     with _span("serving.decode", step=self.steps_run + 1,
-                               spec=int(spec), **self._launch_counts()):
+                               spec=int(spec),
+                               **self._launch_counts()) as dspan:
                         if spec:
                             # speculative iteration: up to spec_k
                             # committed tokens per slot for one step's
@@ -2513,6 +2701,9 @@ class GenerationServer:
                             # the same commit loop
                             toks = eng.step()[:, None]
                             counts = np.ones(eng.max_slots, np.int32)
+                            # a model's own counts of the launch (the
+                            # experts' rows), read in the step's fetch
+                            dspan.set(**getattr(eng, "last_aux", {}))
                     if self._epoch != my_epoch:
                         return  # fenced mid-step (stall restart): the
                         # new loop owns the slots — do not commit or fail

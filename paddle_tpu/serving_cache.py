@@ -55,8 +55,9 @@ import numpy as np
 from .observability import flight as _flight
 from .observability import metrics as _om
 
-__all__ = ["PagedKVCache", "paged_attention", "write_kv_tokens",
-           "absmax_quantize", "use_kernel_default", "copy_block"]
+__all__ = ["PagedKVCache", "KindedKVCache", "paged_attention",
+           "write_kv_tokens", "absmax_quantize", "use_kernel_default",
+           "copy_block"]
 
 _M = _om.scope("serving")
 _G_blocks_free = _M.gauge(
@@ -69,6 +70,15 @@ _M_evictions = _M.counter(
     "block_evictions_total",
     "Paged KV blocks reclaimed from expired/failed/cancelled requests "
     "(normal completion frees blocks without counting here)")
+_G_kind_blocks = _M.gauge(
+    "kv_blocks_in_use",
+    "Paged KV blocks mapped to slots, by the kind of layer whose table "
+    "holds them (full / window): a block of a kind is one block in "
+    "each of that kind's layers")
+_M_window_freed = _M.counter(
+    "kv_window_blocks_freed_total",
+    "Blocks a window layer's table gave back because every position in "
+    "them had fallen behind the attention window of a live request")
 
 
 def _ceil_div(a: int, b: int) -> int:
@@ -123,12 +133,33 @@ class PagedKVCache:
     def __init__(self, max_slots: int, max_seq: int, block_size: int,
                  num_blocks: int,
                  prefix_cache: Optional[bool] = None,
-                 prefix_cache_blocks: Optional[int] = None):
+                 prefix_cache_blocks: Optional[int] = None,
+                 window: Optional[int] = None, window_slack: int = 0,
+                 kind: str = "full"):
         self.block_size = int(block_size)
         if self.block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.max_blocks_per_slot = _ceil_div(max_seq, self.block_size)
-        self.num_blocks = int(num_blocks)
+        # a WINDOW table (layers that attend the last `window` positions
+        # only): a slot holds the blocks its live rows can still see and
+        # no more — `advance` frees whole blocks behind the window and
+        # maps the ones ahead, so what a slot may hold at once is capped:
+        # the window, the rows one launch writes (`window_slack`: the
+        # prefill chunk) and one block of ragged ends
+        self.kind = str(kind)
+        self.window = None if window is None else int(window)
+        if self.window is not None and self.window < 1:
+            raise ValueError(f"window must be >= 1, got {window}")
+        self.hold_blocks = self.max_blocks_per_slot \
+            if self.window is None else min(
+                self.max_blocks_per_slot,
+                _ceil_div(self.window + max(int(window_slack), 1),
+                          self.block_size) + 1)
+        self._need: Dict[int, int] = {}   # slot -> logical blocks in all
+        self._lo: Dict[int, int] = {}     # slot -> first live logical block
+        # None: what every slot may hold at once (dense capacity parity)
+        self.num_blocks = int(max_slots) * self.hold_blocks \
+            if num_blocks is None else int(num_blocks)
         if self.num_blocks < 1:
             raise ValueError(f"num_blocks must be >= 1, got {num_blocks}")
         # logical block index -> physical block id; -1 = unmapped. The
@@ -144,6 +175,16 @@ class PagedKVCache:
         self.evictions = 0
         # -- prefix radix cache (FLAGS_serving_prefix_cache) ----------
         from .core.flags import flag_value
+        if self.window is not None:
+            # a shared prefix block would have to outlive the window of
+            # its first writer and be re-admitted behind another
+            # request's window: not built, so never silently wrong
+            if prefix_cache:
+                raise ValueError(
+                    "prefix sharing is not supported on a window "
+                    "layer's block table: its blocks are freed behind "
+                    "the window, a shared prefix must not be")
+            prefix_cache = False
         self.prefix_enabled = bool(
             flag_value("serving_prefix_cache") if prefix_cache is None
             else prefix_cache)
@@ -203,6 +244,7 @@ class PagedKVCache:
     def _sync_gauges(self) -> None:
         _G_blocks_free.set(self.available_blocks())
         _G_blocks_used.set(self.used_blocks())
+        _G_kind_blocks.set(self.used_blocks(), kind=self.kind)
 
     # -- prefix radix tree (lock held for every _-helper) -------------------
     def _incref(self, node: _PrefixNode) -> None:
@@ -295,6 +337,11 @@ class PagedKVCache:
         now = _ceil_div(max(prompt_tokens, 1), self.block_size)
         total = min(max(_ceil_div(total_tokens, self.block_size), now),
                     self.max_blocks_per_slot)
+        need = total
+        # a window table holds at most `hold_blocks` of them at once;
+        # the head of a long prompt is mapped now, `advance` moves on
+        total = min(total, self.hold_blocks)
+        now = min(now, total)
         with self._lock:
             if total > self.num_blocks:
                 raise ValueError(
@@ -351,6 +398,8 @@ class PagedKVCache:
                     self.prefix_tokens_reused += skip
                 self._reserved[slot] = reserved
                 self._reserved_total += reserved
+                self._need[slot] = need
+                self._lo[slot] = 0
                 self._sync_gauges()
                 avail = None
         if avail is not None:
@@ -506,6 +555,9 @@ class PagedKVCache:
             raise ValueError(
                 f"position {pos} is past the cache capacity "
                 f"({self.max_blocks_per_slot * self.block_size} tokens)")
+        if self.window is not None:
+            self.advance(slot, pos, pos)
+            return
         if self.block_tables[slot, bidx] >= 0:
             return
         with self._lock:
@@ -528,10 +580,80 @@ class PagedKVCache:
                        block_index=bidx,
                        available=self.available_blocks())
 
+    def advance(self, slot: int, first_pos: int, last_pos: int) -> int:
+        """Move ``slot``'s live range on to the rows ``[first_pos,
+        last_pos]`` that the next launch writes: map the blocks through
+        ``last_pos`` and, in a window table, first give back every
+        block that lies wholly behind ``first_pos - window + 1`` (no row
+        of this launch or a later one can see it). A freed block is
+        re-credited to the slot's reservation as far as the request
+        still has blocks ahead, so what a slot holds and may still draw
+        never passes ``hold_blocks`` and a draw here cannot fail. A
+        full table maps only (its prompt blocks were mapped at
+        admission). Returns the blocks freed."""
+        slot = int(slot)
+        hi = min(int(last_pos) // self.block_size,
+                 self.max_blocks_per_slot - 1)
+        if self.window is None:
+            for bidx in range(int(first_pos) // self.block_size, hi + 1):
+                if self.block_tables[slot, bidx] < 0:
+                    self.ensure_token(slot, bidx * self.block_size)
+            return 0
+        lo = max(int(first_pos) - self.window + 1, 0) // self.block_size
+        freed = mapped = 0
+        with self._lock:
+            owned = self._owned.get(slot)
+            if owned is None:
+                raise RuntimeError(f"slot {slot} holds no KV blocks")
+            for bidx in range(self._lo.get(slot, 0), min(lo, hi + 1)):
+                b = int(self.block_tables[slot, bidx])
+                if b >= 0:
+                    self.block_tables[slot, bidx] = -1
+                    owned.remove(b)
+                    self._free.append(b)
+                    freed += 1
+            lo = max(self._lo.get(slot, 0), min(lo, hi))
+            self._lo[slot] = lo
+            if freed:
+                hold = min(self.hold_blocks, self._need[slot] - lo)
+                credit = max(hold - len(owned), 0) - self._reserved[slot]
+                self._reserved[slot] += credit
+                self._reserved_total += credit
+            for bidx in range(lo, hi + 1):
+                if self.block_tables[slot, bidx] >= 0:
+                    continue
+                if self._reserved.get(slot, 0) <= 0:
+                    raise RuntimeError(
+                        f"slot {slot} has no KV reservation left mapping "
+                        f"block {bidx} of its window table (live from "
+                        f"{lo}, {len(owned)} held of {self.hold_blocks}): "
+                        f"the budget passed at admission was too small, "
+                        f"or a launch writes more rows than the table's "
+                        f"window_slack")
+                b = self._pop_block()
+                self._reserved[slot] -= 1
+                self._reserved_total -= 1
+                owned.append(b)
+                self.block_tables[slot, bidx] = b
+                mapped += 1
+            if freed or mapped:
+                self._sync_gauges()
+        if freed:
+            _M_window_freed.inc(freed)
+        if freed or mapped:
+            _flight.record("serving", "window_advance", slot=slot,
+                           freed=freed, mapped=mapped, first_block=lo,
+                           available=self.available_blocks())
+        return freed
+
     def reserve_through(self, slot: int, pos: int) -> None:
         """Materialize every block covering positions [0, pos] — the
         decode-window pre-extension (``decode_steps`` needs a block
         table that stays valid for the whole device-resident loop)."""
+        if self.window is not None:
+            raise NotImplementedError(
+                "a window table maps one launch ahead (advance); a "
+                "device-resident decode window is not built for it")
         last = min(int(pos) // self.block_size,
                    self.max_blocks_per_slot - 1)
         for bidx in range(last + 1):
@@ -547,6 +669,10 @@ class PagedKVCache:
         admission-time budget so the next window's pre-extension can
         draw the same blocks again). Returns the block count rolled
         back."""
+        if self.window is not None:
+            raise NotImplementedError(
+                "rolling back a window table is not built: a block "
+                "freed behind the window cannot be had again")
         slot, tokens = int(slot), int(tokens)
         keep = _ceil_div(tokens, self.block_size) if tokens > 0 else 0
         rolled = unshared = 0
@@ -616,6 +742,8 @@ class PagedKVCache:
             self._cow_pending.pop(slot, None)
             resv = self._reserved.pop(slot, 0)
             self._reserved_total -= resv
+            self._need.pop(slot, None)
+            self._lo.pop(slot, None)
             self._free.extend(blocks)
             self.block_tables[slot, :] = -1
             if evicted and blocks:
@@ -684,6 +812,129 @@ class PagedKVCache:
         return int(sum(int(p) for p, a in zip(pos, active) if a))
 
 
+class KindedKVCache:
+    """A block table and an allocator per KIND of layer, for a model
+    whose layers do not all keep the same history: ``full`` layers keep
+    every position, ``window`` layers the last ``W``. One
+    :class:`PagedKVCache` a kind (each with its own pool size; the
+    layers of a kind share its table, each with a pool of its own), and
+    the calls an engine makes go to every kind: admission reserves by
+    kind (the whole need in the full table, at most ``hold_blocks`` in
+    a window table) and takes all or nothing, ``advance`` frees behind
+    the windows, ``release`` returns every kind's blocks.
+
+    Prefix sharing is off here and asking for it is an error (see
+    ``PagedKVCache``'s window note); rolling back (speculation) is not
+    built for window tables."""
+
+    def __init__(self, max_slots: int, max_seq: int, block_size: int,
+                 kinds: Dict[str, dict],
+                 prefix_cache: Optional[bool] = None):
+        if prefix_cache:
+            raise ValueError(
+                "prefix sharing is not supported for a model with "
+                "window layers: a window layer frees its blocks behind "
+                "the window, a shared prefix must not be freed")
+        self.block_size = int(block_size)
+        self.kinds: Dict[str, PagedKVCache] = {
+            kind: PagedKVCache(
+                max_slots, max_seq, block_size, spec.get("num_blocks"),
+                prefix_cache=False, window=spec.get("window"),
+                window_slack=spec.get("window_slack", 0), kind=kind)
+            for kind, spec in kinds.items()}
+        self.max_blocks_per_slot = _ceil_div(max_seq, self.block_size)
+        self.prefix_enabled = False
+
+    @property
+    def block_tables(self) -> Dict[str, np.ndarray]:
+        return {k: c.block_tables for k, c in self.kinds.items()}
+
+    def _tightest(self) -> PagedKVCache:
+        return min(self.kinds.values(),
+                   key=lambda c: c.available_blocks() / c.num_blocks)
+
+    @property
+    def num_blocks(self) -> int:
+        """Of the kind nearest exhaustion (what admission pressure,
+        ``available_blocks() / num_blocks``, is read from)."""
+        return self._tightest().num_blocks
+
+    def available_blocks(self) -> int:
+        return self._tightest().available_blocks()
+
+    def used_blocks(self) -> int:
+        return sum(c.used_blocks() for c in self.kinds.values())
+
+    def occupied_slots(self) -> int:
+        return max(c.occupied_slots() for c in self.kinds.values())
+
+    def admit(self, slot: int, prompt_tokens: int, total_tokens: int,
+              token_ids=None) -> bool:
+        done = []
+        for c in self.kinds.values():
+            try:
+                ok = c.admit(slot, prompt_tokens, total_tokens)
+            except Exception:
+                for d in done:
+                    d.release(slot)
+                raise
+            if not ok:
+                for d in done:
+                    d.release(slot)
+                return False
+            done.append(c)
+        return True
+
+    def advance(self, slot: int, first_pos: int, last_pos: int) -> int:
+        return sum(c.advance(slot, first_pos, last_pos)
+                   for c in self.kinds.values())
+
+    def ensure_token(self, slot: int, pos: int) -> None:
+        for c in self.kinds.values():
+            c.ensure_token(slot, pos)
+
+    def release(self, slot: int, evicted: bool = False) -> int:
+        return sum(c.release(slot, evicted=evicted)
+                   for c in self.kinds.values())
+
+    def reserve_through(self, slot: int, pos: int) -> None:
+        for c in self.kinds.values():
+            c.reserve_through(slot, pos)
+
+    def truncate(self, slot: int, tokens: int) -> int:
+        return sum(c.truncate(slot, tokens) for c in self.kinds.values())
+
+    # prefix sharing is off: the engine's calls find nothing shared
+    def matched_tokens(self, slot: int) -> int:
+        return 0
+
+    def take_cow(self, slot: int):
+        return None
+
+    def cow_for_write(self, slot: int, pos: int):
+        return None
+
+    def commit_prefix(self, slot: int, token_ids, tokens_written: int) -> int:
+        return 0
+
+    def reset_prefix_cache(self) -> int:
+        return 0
+
+    def check_invariants(self) -> None:
+        for c in self.kinds.values():
+            c.check_invariants()
+
+    def stats(self) -> Dict[str, object]:
+        """The tightest kind's numbers under the one-table keys, every
+        kind's own under ``kinds``."""
+        out: Dict[str, object] = dict(self._tightest().stats())
+        out["kinds"] = {k: c.stats() for k, c in self.kinds.items()}
+        return out
+
+    def active_tokens(self, pos: np.ndarray, active: np.ndarray) -> int:
+        return next(iter(self.kinds.values())).active_tokens(pos, active)
+
+
 # ---------------------------------------------------------------------------
 # device side: quantized block writes + tiled streaming attention
 # ---------------------------------------------------------------------------
@@ -740,15 +991,27 @@ def use_kernel_default(head_dim: int) -> bool:
     return _pk.kernel_available(head_dim)
 
 
+def _kernel_row_tile(T: int, H: int, D: int) -> int:
+    """Rows a slot the Pallas kernel takes at once: all ``T`` where its
+    float32 accumulator fits ``_KERNEL_Q_VMEM_BUDGET``, else the largest
+    divisor of ``T`` that does (0 if none: the caller takes the walk)."""
+    fit = _KERNEL_Q_VMEM_BUDGET // (H * D * 4)
+    return next((t for t in range(min(T, fit), 0, -1) if T % t == 0), 0)
+
+
 def paged_attention(q, k_pool, v_pool, tables, positions, *,
                     block_size: int, n_rep: int, n_tiles=None,
-                    k_scale=None, v_scale=None, use_kernel=None):
+                    k_scale=None, v_scale=None, use_kernel=None,
+                    lower=None):
     """Block-table-gathered streaming attention for one layer.
 
     ``q [S, T, H, D]`` attends to the K/V history of its slot, stored
     as pool blocks ``[num_blocks, block_size, KVH, D]`` addressed
     through ``tables [S, max_blocks]`` (entry < 0 = unmapped). Row
-    ``(s, t)`` may attend every column ``c <= positions[s, t]``.
+    ``(s, t)`` may attend every column ``c <= positions[s, t]`` and,
+    with ``lower [S, T]`` (a window layer: ``positions - W + 1``), only
+    ``c >= lower[s, t]``; the walk then starts at the first block any
+    row still sees, so blocks freed behind the window are never read.
 
     The walk is an online-softmax loop over ``block_size`` tiles
     (``jax.lax.fori_loop``, so ``n_tiles`` — typically
@@ -775,14 +1038,17 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     """
     if use_kernel is None:
         use_kernel = use_kernel_default(q.shape[3])
+    row_tile = q.shape[1]
     if use_kernel and q.shape[1] * q.shape[2] * q.shape[3] * 4 \
             > _KERNEL_Q_VMEM_BUDGET:
         # the kernel's f32 accumulator scratch (and its q/out tiles)
-        # scale with T*H*D: decode (T=1), spec verify (T=k+1) and
-        # chunked prefill all fit easily, but the DENSE engine's
-        # un-chunked whole-prompt prefill can exceed per-core VMEM —
-        # those calls take the jnp walk, same numerics
-        use_kernel = False
+        # scale with T*H*D: decode (T=1), spec verify (T=k+1) and a
+        # 64-row chunk of 32 heads fit; a wider chunk (512 rows of 128
+        # heads) goes to the kernel a tile of rows at a time, each tile
+        # a slot of its own over the same table row. Only a row count
+        # with no divisor that fits takes the jnp walk, same numerics
+        row_tile = _kernel_row_tile(*q.shape[1:])
+        use_kernel = row_tile >= 8 or row_tile == q.shape[1]
     if use_kernel and block_size * k_pool.dtype.itemsize < 4:
         # the kernel copies one block at a time, and Mosaic copies no
         # slab thinner than a 32-bit sublane row (block_size 1 in
@@ -790,18 +1056,30 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
         use_kernel = False
     from .ops.pallas import count_path
     count_path("paged_attention", "pallas" if use_kernel else "jnp_walk")
+    S, T, H, D = q.shape
     if use_kernel:
         from .ops.pallas import paged_attention as _pk
-        return _pk.paged_attention_kernel(
-            q, k_pool, v_pool, tables, positions,
-            block_size=block_size, n_rep=n_rep, n_tiles=n_tiles,
-            k_scale=k_scale, v_scale=v_scale)
-    S, T, H, D = q.shape
+        nt = T // row_tile
+
+        def rows(a):
+            return None if a is None else a.reshape(
+                (S * nt, row_tile) + a.shape[2:])
+        out = _pk.paged_attention_kernel(
+            rows(q), k_pool, v_pool,
+            tables if nt == 1 else jnp.repeat(tables, nt, axis=0),
+            rows(positions), block_size=block_size, n_rep=n_rep,
+            n_tiles=n_tiles, k_scale=k_scale, v_scale=v_scale,
+            lower=rows(lower))
+        return out.reshape(S, T, H, D)
     K = k_pool.shape[2]
     R = int(n_rep)
     assert K * R == H, (K, R, H)
     if n_tiles is None:
         n_tiles = tables.shape[1]
+    first_tile = 0
+    if lower is not None:
+        lower = jnp.maximum(lower, 0)
+        first_tile = jnp.minimum(jnp.min(lower) // block_size, n_tiles)
     q5 = q.reshape(S, T, K, R, D)
     inv_sqrt_d = 1.0 / np.sqrt(D)
     cols0 = jnp.arange(block_size)
@@ -834,8 +1112,10 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
         # [S, T, bs] -> broadcast over (K, R); also masks unmapped
         # blocks (cols of tile i all exceed positions that never
         # reached it) and clamped phys-0 garbage for inactive slots
-        ok = (i * block_size + cols0)[None, None, :] \
-            <= positions[:, :, None]
+        cols = (i * block_size + cols0)[None, None, :]
+        ok = cols <= positions[:, :, None]
+        if lower is not None:
+            ok = ok & (cols >= lower[:, :, None])
         okb = ok[:, None, None, :, :]
         s = jnp.where(okb, s, -1e30)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
@@ -849,7 +1129,7 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
         acc_new = acc * corr[..., None] + pv
         return m_new, l_new, acc_new
 
-    m, l, acc = jax.lax.fori_loop(0, n_tiles, tile, (m0, l0, a0))
+    m, l, acc = jax.lax.fori_loop(first_tile, n_tiles, tile, (m0, l0, a0))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 3, 1, 2, 4).reshape(S, T, H, D).astype(
         q.dtype)

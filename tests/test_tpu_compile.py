@@ -1,4 +1,5 @@
-"""The paged-attention kernel compiled for the chip, without the chip.
+"""The serving kernels (paged attention, the held experts' rows matmul)
+compiled for the chip, without the chip.
 
 The Pallas interpreter (tests/test_serving_spec.py) holds the kernel's
 numbers; it cannot see what Mosaic refuses. Every refusal met while the
@@ -43,8 +44,18 @@ def one_chip():
 
 
 YI, MISTRAL = (32, 4, 128), (32, 8, 128)      # heads, KV heads, head_dim
-# id: (S, T, (H, KVH, D), block_size, num_blocks, max_blocks, pool, q, int8)
+CMDA = (128, 8, 128)      # Command A+: n_rep 16, twice the widest before it
+# id: (S, T, (H, KVH, D), block_size, num_blocks, max_blocks, pool, q, int8
+#      [, bounded]): bounded = a window layer's call, with each row's first
+# visible position. A 512-row chunk of 128 heads reaches the kernel as 8
+# slots of 64 rows (serving_cache.paged_attention splits it).
 SHAPES = {
+    "cmda_full_decode": (32, 1, CMDA, 16, 12288, 1024, "bfloat16", "bfloat16", False),
+    "cmda_window_decode": (32, 1, CMDA, 16, 9472, 1024, "bfloat16", "bfloat16", False, True),
+    "cmda_full_chunk_512": (8, 64, CMDA, 16, 12288, 1024, "bfloat16", "bfloat16", False),
+    "cmda_window_chunk_512": (8, 64, CMDA, 16, 9472, 1024, "bfloat16", "bfloat16", False, True),
+    "cmda_window_chunk_8": (1, 8, CMDA, 16, 9472, 1024, "bfloat16", "bfloat16", False, True),
+    "yi_decode_bounded": (32, 1, YI, 16, 4096, 128, "bfloat16", "bfloat16", False, True),
     "yi_decode_32_slots": (32, 1, YI, 16, 4096, 128, "bfloat16", "bfloat16", False),
     "yi_prefill_chunk_8": (1, 8, YI, 16, 4096, 128, "bfloat16", "bfloat16", False),
     "yi_prefill_chunk_64": (1, 64, YI, 16, 4096, 128, "bfloat16", "bfloat16", False),
@@ -61,20 +72,51 @@ SHAPES = {
 
 @pytest.mark.parametrize("name", sorted(SHAPES))
 def test_paged_attention_kernel_compiles_for_the_v5e(one_chip, name):
-    S, T, (H, K, D), bs, NB, MB, pool, qdt, quant = SHAPES[name]
+    S, T, (H, K, D), bs, NB, MB, pool, qdt, quant, *bounded = SHAPES[name]
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
 
     scale = sds((NB, bs, K), "float32") if quant else None
+    lower = sds((S, T), "int32") if bounded else None
     compiled = jax.jit(
-        lambda q, k, v, t, p, n, ks, vs: pk.paged_attention_kernel(
+        lambda q, k, v, t, p, n, ks, vs, lo: pk.paged_attention_kernel(
             q, k, v, t, p, block_size=bs, n_rep=H // K, n_tiles=n,
-            k_scale=ks, v_scale=vs)).lower(
+            k_scale=ks, v_scale=vs, lower=lo)).lower(
         sds((S, T, H, D), qdt), sds((NB, bs, K, D), pool),
         sds((NB, bs, K, D), pool), sds((S, MB), "int32"),
-        sds((S, T), "int32"), sds((), "int32"), scale, scale).compile()
+        sds((S, T), "int32"), sds((), "int32"), scale, scale,
+        lower).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # the trace, the benchmark's readers and chip_smoke.py find it by this
     assert "_paged_attention_call" in text
+
+
+# rows of the sorted buffer, row tile, columns: Command A+'s held experts
+# ([16, 4096, 8192] gate and up, [16, 4096, 4096] down) at a decode launch of
+# 32 rows (tile 16), a 512-row chunk (tile 64), the smallest chunk (8 rows)
+EXPERT_ROWS = {
+    "decode_gate_up": (512, 16, 8192), "decode_down": (512, 16, 4096),
+    "chunk_512_gate_up": (5120, 64, 8192), "chunk_512_down": (5120, 64, 4096),
+    "chunk_8_gate_up": (320, 16, 8192), "widest_tile": (6144, 128, 8192),
+}
+
+
+@pytest.mark.parametrize("name", sorted(EXPERT_ROWS))
+def test_expert_rows_matmul_compiles_for_the_v5e(one_chip, name):
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    m, block_t, n = EXPERT_ROWS[name]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda lhs, rhs, ids, live: gm.expert_rows_matmul(
+            lhs, rhs, ids, live, block_t, use_kernel=True)).lower(
+        sds((m, 4096), "bfloat16"), sds((16, 4096, n), "bfloat16"),
+        sds((m // block_t,), "int32"), sds((), "int32")).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the trace and the benchmark's readers find it by this
+    assert "_expert_rows_matmul_call" in text
