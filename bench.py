@@ -766,9 +766,9 @@ def bench_paged_attention_paths():
         NB = S * MB
         q = jnp.asarray(rng.standard_normal((S, T, H, D)),
                         jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((NB, bs, K, D)),
+        kp = jnp.asarray(rng.standard_normal((NB, bs, K * D)),
                          jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((NB, bs, K, D)),
+        vp = jnp.asarray(rng.standard_normal((NB, bs, K * D)),
                          jnp.float32)
         tables = jnp.asarray(
             rng.permutation(NB).reshape(S, MB).astype(np.int32))

@@ -368,8 +368,8 @@ def run_kernel_parity() -> None:
         S, H, K, D, bs, MB = 8, 32, 32, 128, 16, 64
     NB = S * MB
     rng = np.random.default_rng(0)
-    kp = jnp.asarray(rng.standard_normal((NB, bs, K, D)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((NB, bs, K, D)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal((NB, bs, K * D)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((NB, bs, K * D)), jnp.bfloat16)
     tables = rng.permutation(NB).reshape(S, MB).astype(np.int32)
     use_kernel = sc.use_kernel_default(D)
     check(use_kernel or REHEARSAL,

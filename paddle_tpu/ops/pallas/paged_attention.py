@@ -24,6 +24,12 @@ online softmax, and walks only what is live:
   // block_size``, and masks the ragged head of it. Without it nothing
   of this is in the program: a full-attention call compiles as before.
 
+A pool is ``[num_blocks, block_size, KVH*D]`` from its allocation on,
+the layout the copies above read, and reaches the kernel as it is
+stored: compiled for the v5e a bf16 ``[..., KVH, 128]`` array is tiled
+``T(4,128)(2,1)`` and the kernel's slab ``T(8,128)(2,1)``, so a
+four-dimensional pool was copied whole, K and V, a layer a launch.
+
 Contract (shared with the jnp walk, parity-pinned in
 tests/test_serving_spec.py):
 
@@ -142,8 +148,10 @@ def _kernel(tables_ref, nblk_ref, *refs, block_size, C, kvh, head_dim,
     and each slot's live block count (and, ``bounded``, its first live
     block). Tensor refs: q [1, K, T*R, D] and row positions [1, T*R, 1]
     (and, ``bounded``, the rows' first visible positions, same shape) in
-    VMEM | K and V pools [NB, bs, K*D] (and their scales [NB, bs, K])
-    left in HBM | out [1, K, T*R, D]. Scratch: the two-slot group
+    VMEM | K and V pools [NB, bs, K*D], the layout they are allocated
+    in (one block is one contiguous slab, so one copy brings all KV
+    heads), and their scales [NB, bs, K], left in HBM | out
+    [1, K, T*R, D]. Scratch: the two-slot group
     buffers [2, C, bs, .] the DMAs land in, their semaphores [stream,
     slot], and the float32 online-softmax state m/l [K, T*R, 1], acc
     [K, T*R, D]."""
@@ -269,7 +277,7 @@ def _paged_attention_call(q, k_pool, v_pool, tables, positions,
                           n_rep, interpret):
     S, T, H, D = q.shape
     bounded = lower is not None
-    NB, _, K, _ = k_pool.shape
+    K = H // n_rep
     MB = tables.shape[1]
     R, TR = n_rep, T * n_rep
     dequant = k_scale is not None
@@ -301,9 +309,9 @@ def _paged_attention_call(q, k_pool, v_pool, tables, positions,
         in_specs.append(pos_spec)
         args.append(jnp.repeat(lower, R, axis=1)[..., None])
     n_rows = len(args)
+    # the pools go in as they are stored: no relayout of a pool a launch
     in_specs += [hbm, hbm]
-    args += [k_pool.reshape(NB, block_size, K * D),
-             v_pool.reshape(NB, block_size, K * D)]
+    args += [k_pool, v_pool]
     scratch = [pltpu.VMEM((2, C, block_size, K * D), k_pool.dtype),
                pltpu.VMEM((2, C, block_size, K * D), v_pool.dtype)]
     if dequant:
@@ -344,7 +352,7 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, positions, *,
                            k_scale=None, v_scale=None, lower=None,
                            interpret: bool = False):
     """Flat-signature drop-in for ``serving_cache.paged_attention``
-    (q [S, T, H, D], pools [num_blocks, bs, KVH, D], tables
+    (q [S, T, H, D], pools [num_blocks, bs, KVH*D], tables
     [S, max_blocks], positions [S, T]); ``n_tiles`` may be traced —
     it caps every slot's own block count. ``lower [S, T]`` is each
     row's first visible position (a window layer's ``pos - W + 1``).

@@ -18,14 +18,19 @@ program families:
 Two cache layouts ship:
 
 - **Dense** (:class:`LlamaDecodeEngine`): per-layer arrays
-  [slots, max_seq, KVH, D] (a stacked [L, ...] form measured
+  [slots, max_seq, KVH*D] (a stacked [L, ...] form measured
   ~11 ms/step of slice/stack copies), donated through the decode step
   so the update is in-place in HBM. Simple, but HBM scales with
   *capacity* (slots x max_seq) whether slots are full or idle.
 - **Paged** (:class:`PagedLlamaDecodeEngine`, the production/server
   default): a shared per-layer block pool [num_blocks, block_size,
-  KVH, D] plus per-slot block tables (``serving_cache.PagedKVCache``),
-  so HBM scales with *active tokens*; prompts prefill in CHUNKS
+  KVH*D] plus per-slot block tables (``serving_cache.PagedKVCache``),
+  so HBM scales with *active tokens*. The KV heads lie side by side in
+  the minor dimension from allocation to the kernel call: the v5e
+  tiles a bf16 [..., KVH, 128] array T(4,128)(2,1) and the paged
+  kernel's [block_size, KVH*D] slab T(8,128)(2,1), so a pool kept
+  four-dimensional was copied whole, K and V, a layer a launch.
+  Prompts prefill in CHUNKS
   through their own bucketed executable interleaved with decode steps
   (a long prompt never stalls the in-flight batch), and the decode
   attention is a tiled streaming walk of each slot's block list
@@ -514,7 +519,9 @@ class LlamaDecodeEngine:
         # XLA materializes as whole-cache copies (~11 ms/step measured
         # at 6 layers x 8 slots x 1024); per-layer donated leaves
         # update in place
-        self.k_cache = [jnp.zeros((S, self.max_seq, kvh, self.head_dim),
+        # the KV heads flat in the minor dimension, as the paged pools
+        # are: _attend's view of a slot's rows as blocks is then free
+        self.k_cache = [jnp.zeros((S, self.max_seq, kvh * self.head_dim),
                                   self.dtype) for _ in range(L)]
         self.v_cache = [jnp.zeros_like(self.k_cache[0])
                         for _ in range(L)]
@@ -586,7 +593,7 @@ class LlamaDecodeEngine:
             axis=-1).astype(x.dtype)
 
     def _attend(self, q, k_all, v_all, positions):
-        """q [S,T,H,D] vs caches [S,max_seq,KVH,D]; row (s,t) may
+        """q [S,T,H,D] vs caches [S,max_seq,KVH*D]; row (s,t) may
         attend every column c <= positions[s,t]. Routed through the
         ONE ``serving_cache.paged_attention`` seam by viewing the
         dense per-slot rows as an identity-mapped block pool (a free
@@ -601,8 +608,8 @@ class LlamaDecodeEngine:
         S, M = k_all.shape[0], k_all.shape[1]
         ts = self._attend_tile
         nb = M // ts
-        k_pool = k_all.reshape((S * nb, ts) + k_all.shape[2:])
-        v_pool = v_all.reshape((S * nb, ts) + v_all.shape[2:])
+        k_pool = k_all.reshape(S * nb, ts, -1)
+        v_pool = v_all.reshape(S * nb, ts, -1)
         tables = jnp.arange(S * nb, dtype=jnp.int32).reshape(S, nb)
         # use_kernel pinned to the __init__-time decision so the
         # compiled programs bake exactly what _count_pa_path reports
@@ -635,8 +642,8 @@ class LlamaDecodeEngine:
         q = self._rope(q, positions)
         k = self._rope(k, positions)
         sl = jnp.arange(S)[:, None].repeat(T, 1)      # [S, T] slot ids
-        kc_l = kc_l.at[sl, write_cols].set(k)
-        vc_l = vc_l.at[sl, write_cols].set(v)
+        kc_l = kc_l.at[sl, write_cols].set(k.reshape(S, T, -1))
+        vc_l = vc_l.at[sl, write_cols].set(v.reshape(S, T, -1))
         att = self._attend(q, kc_l, vc_l, positions)
         h = res + self._mm(att.reshape(S, T, H), lp["o_proj"])
         res = h
@@ -839,7 +846,7 @@ class LlamaDecodeEngine:
         artifact a serving process can run without this class (ref: the
         reference predictor's save/load of an analyzed program). The
         exported signature matches the live engine's cache layout:
-        dense per-layer [slots, max_seq, KVH, D] arrays here; the paged
+        dense per-layer [slots, max_seq, KVH*D] arrays here; the paged
         engine exports its block-pool signature (pools + block tables +
         active mask) instead."""
         avals = jax.tree.map(
@@ -854,10 +861,15 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
     """Paged-KV decode engine: the dense engine's math (weights,
     projections, rope, int8 matmuls) over a **block-pool cache**.
 
-    Layout: one shared pool per layer ``[num_blocks, block_size, KVH,
-    D]`` (``serving_cache.PagedKVCache``) addressed through per-slot
+    Layout: one shared pool per layer ``[num_blocks, block_size,
+    KVH*D]`` (``serving_cache.PagedKVCache``) addressed through per-slot
     block tables, so KV HBM scales with ACTIVE tokens instead of
-    slots x max_seq. Admission reserves a request's worst-case block
+    slots x max_seq. A pool is allocated, written, copied and read in
+    that one layout, the one the paged kernel's block copies read (a
+    block is one contiguous ``[block_size, KVH*D]`` slab, tiled
+    ``T(8,128)(2,1)`` on the v5e; a bf16 ``[..., KVH, 128]`` pool is
+    tiled ``T(4,128)(2,1)`` and had to be copied whole before every
+    attention call). Admission reserves a request's worst-case block
     count (prompt + generation budget), prompt blocks are mapped
     immediately, and decode extends one block at a time at step
     boundaries — extension can therefore never fail mid-stream.
@@ -925,14 +937,17 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                    "bfloat16": jnp.bfloat16}.get(self.kv_quant,
                                                  self.dtype)
         bs = self.block_size
-        shapes = [(self.num_blocks[sp["kind"]] if self._kinded
-                   else self.num_blocks, bs, sp["kv_heads"], sp["head_dim"])
-                  for sp in self.cache_spec]
-        kv = {"k": [jnp.zeros(sh, pool_dt) for sh in shapes],
-              "v": [jnp.zeros(sh, pool_dt) for sh in shapes]}
+        # (blocks, KV heads, head width) a layer, each by its kind
+        geo = [(self.num_blocks[sp["kind"]] if self._kinded
+                else self.num_blocks, sp["kv_heads"], sp["head_dim"])
+               for sp in self.cache_spec]
+        # K and V with the heads flat: what the kernel's block copy reads
+        kv = {name: [jnp.zeros((nb, bs, kvh * d), pool_dt)
+                     for nb, kvh, d in geo] for name in ("k", "v")}
         if self.kv_quant == "int8":
-            kv["ksc"] = [jnp.zeros(sh[:3], jnp.float32) for sh in shapes]
-            kv["vsc"] = [jnp.zeros(sh[:3], jnp.float32) for sh in shapes]
+            for name in ("ksc", "vsc"):     # a scale a (token, head)
+                kv[name] = [jnp.zeros((nb, bs, kvh), jnp.float32)
+                            for nb, kvh, _ in geo]
         return kv
 
     def _init_cache(self) -> None:
@@ -1022,9 +1037,10 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
     # -- device side --------------------------------------------------------
     def _write_kv(self, kvl, k, v, positions, tables, wmask):
         """Scatter rope'd K/V rows [S, T, KVH, D] into their (physical
-        block, offset) cells; rows with ``wmask`` False or an unmapped
-        table entry are dropped (OOB index), so prefill padding and
-        inactive slots never touch a real block."""
+        block, offset) cells, each a row of KVH*D in the pool; rows
+        with ``wmask`` False or an unmapped table entry are dropped
+        (OOB index), so prefill padding and inactive slots never touch
+        a real block."""
         S, T = positions.shape
         bidx = jnp.minimum(positions // self.block_size,
                            self._kv.max_blocks_per_slot - 1)
@@ -1036,17 +1052,17 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         vf = v.reshape((S * T,) + v.shape[2:])
         out = dict(kvl)
         if self.kv_quant == "int8":
-            kq, ks = self._sc.absmax_quantize(kf)
-            vq, vs = self._sc.absmax_quantize(vf)
-            out["k"] = self._sc.write_kv_tokens(kvl["k"], phys, off, kq)
-            out["v"] = self._sc.write_kv_tokens(kvl["v"], phys, off, vq)
+            # a scale a (token, head), taken before the heads are flat
+            kf, ks = self._sc.absmax_quantize(kf)
+            vf, vs = self._sc.absmax_quantize(vf)
             out["ksc"] = self._sc.write_kv_tokens(kvl["ksc"], phys,
                                                   off, ks)
             out["vsc"] = self._sc.write_kv_tokens(kvl["vsc"], phys,
                                                   off, vs)
-        else:
-            out["k"] = self._sc.write_kv_tokens(kvl["k"], phys, off, kf)
-            out["v"] = self._sc.write_kv_tokens(kvl["v"], phys, off, vf)
+        out["k"] = self._sc.write_kv_tokens(
+            kvl["k"], phys, off, kf.reshape(S * T, -1))
+        out["v"] = self._sc.write_kv_tokens(
+            kvl["v"], phys, off, vf.reshape(S * T, -1))
         return out
 
     def _cow_impl(self, params, kvs, src, dst):

@@ -5,7 +5,9 @@ The dense serving cache (`serving.LlamaDecodeEngine`) burns HBM
 proportional to *capacity*: every slot owns `max_seq` K/V rows per
 layer whether it holds a 4-token prompt or a full context. This module
 replaces those rows with a **shared per-layer block pool**
-``[num_blocks, block_size, KVH, D]`` plus per-slot **block tables**
+``[num_blocks, block_size, KVH*D]`` (the KV heads side by side in the
+minor dimension: the layout the Pallas kernel's block copies read, so
+no launch relayouts a pool) plus per-slot **block tables**
 mapping logical block index -> physical block, so HBM scales with
 *active tokens* and a pool sized for N dense slots admits far more
 short requests (the vLLM design; here grounded in the
@@ -123,6 +125,12 @@ class PagedKVCache:
     for the prompt are mapped immediately, the rest stay *reserved*
     and are materialized one at a time by ``ensure_token`` as decode
     crosses block boundaries. ``release`` returns both.
+
+    A physical block id indexes the engine's device pools, one K and
+    one V a layer, each ``[num_blocks, block_size, KVH*D]``: a block is
+    one contiguous ``[block_size, KVH*D]`` slab, which is what the
+    kernel copies (``T(8,128)(2,1)`` on the v5e, where a ``[..., KVH,
+    128]`` bf16 array would be tiled ``T(4,128)(2,1)`` and need a copy).
 
     Thread safety: mutations are guarded by an instrumented lock
     (``analysis.locks.make_lock``) — the server loop is the only
@@ -942,6 +950,7 @@ class KindedKVCache:
 def absmax_quantize(x, bits: int = 8):
     """Symmetric per-(token, head) absmax int8 of K/V rows
     ``[N, KVH, D]`` -> ``(codes int8 [N, KVH, D], scale f32 [N, KVH])``
+    (the caller flattens the codes to the pool's ``[N, KVH*D]`` rows)
     — the ``quantization.quantize.quant_absmax`` step computation
     (dynamic absmax over the head dim, qmax = 2^(bits-1) - 1), kept
     raw-code-valued here because the pool STORES the codes and the
@@ -1006,8 +1015,14 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     """Block-table-gathered streaming attention for one layer.
 
     ``q [S, T, H, D]`` attends to the K/V history of its slot, stored
-    as pool blocks ``[num_blocks, block_size, KVH, D]`` addressed
-    through ``tables [S, max_blocks]`` (entry < 0 = unmapped). Row
+    as pool blocks ``[num_blocks, block_size, KVH*D]`` (``KVH = H //
+    n_rep``; the one layout this seam takes) addressed through
+    ``tables [S, max_blocks]`` (entry < 0 = unmapped). The heads are
+    flat because that is what the kernel's block copy reads: compiled
+    for the v5e a bf16 ``[..., KVH, 128]`` array is tiled
+    ``T(4,128)(2,1)`` and the kernel's ``[bs, KVH*D]`` slab
+    ``T(8,128)(2,1)``, so a four-dimensional pool cost a copy of the
+    whole pool, K and V, a layer a launch. Row
     ``(s, t)`` may attend every column ``c <= positions[s, t]`` and,
     with ``lower [S, T]`` (a window layer: ``positions - W + 1``), only
     ``c >= lower[s, t]``; the walk then starts at the first block any
@@ -1036,6 +1051,11 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     against (tests/test_serving_spec.py runs the kernel through the
     Pallas interpreter on CPU and asserts same-numerics).
     """
+    S, T, H, D = q.shape
+    if k_pool.shape[1:] != (block_size, H // n_rep * D):
+        raise ValueError(
+            f"a KV pool is [num_blocks, block_size, KVH*D] = [., "
+            f"{block_size}, {H // n_rep * D}] here, got {k_pool.shape}")
     if use_kernel is None:
         use_kernel = use_kernel_default(q.shape[3])
     row_tile = q.shape[1]
@@ -1056,7 +1076,6 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
         use_kernel = False
     from .ops.pallas import count_path
     count_path("paged_attention", "pallas" if use_kernel else "jnp_walk")
-    S, T, H, D = q.shape
     if use_kernel:
         from .ops.pallas import paged_attention as _pk
         nt = T // row_tile
@@ -1071,9 +1090,8 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
             n_tiles=n_tiles, k_scale=k_scale, v_scale=v_scale,
             lower=rows(lower))
         return out.reshape(S, T, H, D)
-    K = k_pool.shape[2]
     R = int(n_rep)
-    assert K * R == H, (K, R, H)
+    K = H // R
     if n_tiles is None:
         n_tiles = tables.shape[1]
     first_tile = 0
@@ -1090,8 +1108,9 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     def tile(i, carry):
         m, l, acc = carry
         phys = jnp.maximum(tables[:, i], 0)            # [S]
-        k_t = k_pool[phys]                             # [S, bs, K, D]
-        v_t = v_pool[phys]
+        # the heads are split on the gathered tile, never on a pool
+        k_t = k_pool[phys].reshape(S, block_size, K, D)
+        v_t = v_pool[phys].reshape(S, block_size, K, D)
         if k_scale is not None:
             k_t = (k_t.astype(jnp.float32)
                    * k_scale[phys][..., None]).astype(q.dtype)

@@ -323,8 +323,8 @@ def test_the_kernels_lower_bound_matches_the_jnp_walk(T, window, dtype):
     rng = np.random.default_rng(T * 100 + window)
     S, K, Rp, D, bs, NB, MB = 3, 2, 4, 16, 4, 48, 12
     q = jnp.asarray(rng.normal(size=(S, T, K * Rp, D)), dtype)
-    kp = jnp.asarray(rng.normal(size=(NB, bs, K, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(NB, bs, K, D)), dtype)
+    kp = jnp.asarray(rng.normal(size=(NB, bs, K * D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(NB, bs, K * D)), dtype)
     tables = rng.permutation(NB)[:S * MB].reshape(S, MB).astype(np.int32)
     last = np.asarray([40, 9, 27])
     pos = jnp.asarray(last[:, None] + np.arange(T)[None, :], jnp.int32)
@@ -361,7 +361,7 @@ def test_a_wide_chunk_reaches_the_kernel_a_tile_of_rows_at_a_time():
     rng = np.random.default_rng(5)
     S, T, K, Rp, D, bs, NB, MB = 1, 16, 2, 2, 16, 4, 32, 8
     q = jnp.asarray(rng.normal(size=(S, T, K * Rp, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(NB, bs, K, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(NB, bs, K * D)), jnp.float32)
     tables = jnp.asarray(rng.permutation(NB)[:MB][None], jnp.int32)
     pos = jnp.asarray(8 + np.arange(T)[None], jnp.int32)
     walk = sc.paged_attention(q, kp, kp, tables, pos, block_size=bs, n_rep=Rp,

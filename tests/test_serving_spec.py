@@ -266,9 +266,9 @@ class TestPagedAttentionKernelSeam:
         rng = np.random.default_rng(seed)
         NB = S * MB + 2
         q = jnp.asarray(rng.standard_normal((S, T, H, D)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((NB, bs, K, D)),
+        kp = jnp.asarray(rng.standard_normal((NB, bs, K * D)),
                          jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((NB, bs, K, D)),
+        vp = jnp.asarray(rng.standard_normal((NB, bs, K * D)),
                          jnp.float32)
         tables = rng.permutation(NB)[:S * MB].reshape(S, MB)
         tables = jnp.asarray(tables.astype(np.int32))
@@ -282,8 +282,8 @@ class TestPagedAttentionKernelSeam:
             vq, vs = absmax_quantize(vp.reshape(NB * bs, K, D))
             kw.update(k_scale=ks.reshape(NB, bs, K),
                       v_scale=vs.reshape(NB, bs, K))
-            kp = kq.reshape(NB, bs, K, D)
-            vp = vq.reshape(NB, bs, K, D)
+            kp = kq.reshape(NB, bs, K * D)
+            vp = vq.reshape(NB, bs, K * D)
         return q, kp, vp, tables, pos, kw
 
     def test_kernel_matches_jnp_walk_on_every_geometry(self):
@@ -301,6 +301,73 @@ class TestPagedAttentionKernelSeam:
                 np.testing.assert_allclose(
                     np.asarray(ref), np.asarray(got), rtol=1e-6,
                     atol=1e-6, err_msg=f"geometry {geo} quant={quant}")
+
+    # id: (S, T, H, KVH, D, block_size, max_blocks, pool dtype)
+    DENSE = {
+        "decode_gqa": (3, 1, 4, 2, 8, 8, 4, "float32"),
+        "verify_window_gqa": (2, 5, 8, 2, 16, 4, 6, "float32"),
+        "one_kv_head": (2, 3, 4, 1, 8, 8, 3, "float32"),
+        "no_shared_heads": (2, 1, 4, 4, 16, 4, 5, "float32"),
+        "yi_heads_bf16": (2, 1, 32, 4, 128, 16, 3, "bfloat16"),
+        "int8_codes": (2, 2, 4, 2, 8, 8, 4, "int8"),
+    }
+
+    @pytest.mark.parametrize("name", sorted(DENSE))
+    def test_walk_on_a_flat_pool_matches_dense_softmax_attention(self,
+                                                                 name):
+        """The oracle itself, held to something a pool's layout cannot
+        fool: K and V are drawn ``[S, L, KVH, D]``, one history a slot,
+        written a token at a time into a flat ``[NB, bs, KVH*D]`` pool
+        through a shuffled table by the engine's own scatter, and the
+        walk has to give what a plain softmax attention over the
+        ``[S, L, KVH, D]`` arrays gives, query head ``h`` against KV
+        head ``h // n_rep``."""
+        import jax.numpy as jnp
+        from paddle_tpu.serving_cache import (absmax_quantize,
+                                              paged_attention,
+                                              write_kv_tokens)
+        S, T, H, K, D, bs, MB, dtype = self.DENSE[name]
+        rng = np.random.default_rng(sorted(self.DENSE).index(name))
+        quant = dtype == "int8"
+        qdt = jnp.float32 if quant else jnp.dtype(dtype)
+        L, NB, R = bs * MB, S * MB + 3, H // K
+        q = jnp.asarray(rng.standard_normal((S, T, H, D)), qdt)
+        k = jnp.asarray(rng.standard_normal((S, L, K, D)), qdt)
+        v = jnp.asarray(rng.standard_normal((S, L, K, D)), qdt)
+        tables = rng.permutation(NB)[:S * MB].reshape(S, MB).astype(
+            np.int32)
+        phys = jnp.asarray(np.repeat(tables, bs, axis=1).reshape(-1))
+        off = jnp.asarray(np.tile(np.arange(L) % bs, S))
+        kw = dict(block_size=bs, n_rep=R)
+        kf, vf = k.reshape(S * L, K, D), v.reshape(S * L, K, D)
+        if quant:
+            (kf, ks), (vf, vs) = absmax_quantize(kf), absmax_quantize(vf)
+            # what the pool holds is the codes times their scales
+            k = (kf.astype(qdt) * ks[..., None]).reshape(k.shape)
+            v = (vf.astype(qdt) * vs[..., None]).reshape(v.shape)
+            zeros = jnp.zeros((NB, bs, K), jnp.float32)
+            kw.update(k_scale=write_kv_tokens(zeros, phys, off, ks),
+                      v_scale=write_kv_tokens(zeros, phys, off, vs))
+        # never-written blocks hold what a recycled block may hold
+        garbage = jnp.full((NB, bs, K * D), 99, jnp.dtype(dtype))
+        kp = write_kv_tokens(garbage, phys, off, kf.reshape(S * L, -1))
+        vp = write_kv_tokens(garbage, phys, off, vf.reshape(S * L, -1))
+        last = rng.integers(T - 1, L, (S,))
+        last[0] = L - 1
+        pos = (last[:, None] - (T - 1) + np.arange(T)[None, :]).astype(
+            np.int32)
+        got = np.asarray(paged_attention(
+            q, kp, vp, jnp.asarray(tables), jnp.asarray(pos),
+            use_kernel=False, **kw), np.float64)
+        q64, k64, v64 = (np.asarray(a, np.float64) for a in (q, k, v))
+        want = np.zeros_like(got)
+        for s_, t, h in np.ndindex(S, T, H):
+            n = pos[s_, t] + 1
+            sco = k64[s_, :n, h // R] @ q64[s_, t, h] / np.sqrt(D)
+            p = np.exp(sco - sco.max())
+            want[s_, t, h] = (p / p.sum()) @ v64[s_, :n, h // R]
+        tol = 2e-2 if dtype == "bfloat16" else 1e-5
+        np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 
     # id: (S, T, H, KVH, D, block_size, first positions, pool dtype,
     #      extras). The table holds one group of blocks and four more
@@ -373,8 +440,8 @@ class TestPagedAttentionKernelSeam:
         assert 0 <= pos.min() and pos.max() < bs * MB
         NB = S * MB + 3
         q = jnp.asarray(rng.standard_normal((S, T, H, D)), dt)
-        kp = jnp.asarray(rng.standard_normal((NB, bs, K, D)), dt)
-        vp = jnp.asarray(rng.standard_normal((NB, bs, K, D)), dt)
+        kp = jnp.asarray(rng.standard_normal((NB, bs, K * D)), dt)
+        vp = jnp.asarray(rng.standard_normal((NB, bs, K * D)), dt)
         tables = rng.permutation(NB)[:S * MB].reshape(S, MB).astype(
             np.int32)
         if extra.get("share"):
@@ -391,7 +458,7 @@ class TestPagedAttentionKernelSeam:
             vq, vs = absmax_quantize(vp.reshape(NB * bs, K, D))
             kw.update(k_scale=ks.reshape(NB, bs, K),
                       v_scale=vs.reshape(NB, bs, K))
-            kp, vp = kq.reshape(NB, bs, K, D), vq.reshape(NB, bs, K, D)
+            kp, vp = kq.reshape(kp.shape), vq.reshape(vp.shape)
         tables, pos = jnp.asarray(tables), jnp.asarray(pos)
         ref = paged_attention(q, kp, vp, tables, pos, use_kernel=False,
                               **kw)
@@ -442,9 +509,9 @@ class TestPagedAttentionKernelSeam:
         S, T, H, K, D, bs, MB = 2, 1, 4, 2, 8, 8, 4
         NB = S * MB + 2
         q = jnp.asarray(rng.standard_normal((S, T, H, D)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((NB, bs, K, D)),
+        kp = jnp.asarray(rng.standard_normal((NB, bs, K * D)),
                          jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((NB, bs, K, D)),
+        vp = jnp.asarray(rng.standard_normal((NB, bs, K * D)),
                          jnp.float32)
         # block 0 is nobody's block: tables draw from [1, NB), the
         # last logical tile of each slot is unmapped (-1 -> clamps to
@@ -495,13 +562,29 @@ class TestPagedAttentionKernelSeam:
         before = walk.value(kernel="paged_attention", path="jnp_walk")
         S, T, H, K, D, bs, MB = 1, 1, 2, 1, 16, 8, 2
         q = jnp.ones((S, T, H, D), jnp.float32)
-        pool = jnp.ones((MB, bs, K, D), jnp.float32)
+        pool = jnp.ones((MB, bs, K * D), jnp.float32)
         out = sc.paged_attention(
             q, pool, pool, jnp.arange(MB, dtype=jnp.int32)[None],
             jnp.full((S, T), 5, jnp.int32), block_size=bs, n_rep=H // K)
         np.testing.assert_allclose(np.asarray(out), 1.0, rtol=1e-6)
         assert walk.value(kernel="paged_attention",
                           path="jnp_walk") == before + 1
+
+    def test_seam_takes_one_pool_layout(self):
+        """Flat pools only: a pool with its heads apart would cost the
+        kernel a copy of the whole pool a launch, so the seam refuses
+        it on both paths."""
+        import jax.numpy as jnp
+        from paddle_tpu import serving_cache as sc
+        S, T, H, K, D, bs, MB = 1, 1, 4, 2, 8, 8, 2
+        q = jnp.ones((S, T, H, D), jnp.float32)
+        pool = jnp.ones((MB, bs, K, D), jnp.float32)
+        for use_kernel in (False, True):
+            with pytest.raises(ValueError, match="KVH\\*D"):
+                sc.paged_attention(
+                    q, pool, pool, jnp.arange(MB, dtype=jnp.int32)[None],
+                    jnp.full((S, T), 5, jnp.int32), block_size=bs,
+                    n_rep=H // K, use_kernel=use_kernel)
 
 
 class TestJaxprPins:
@@ -611,8 +694,8 @@ class TestJaxprPins:
             lambda q, k, v, t, p, n: pk.paged_attention_kernel(
                 q, k, v, t, p, block_size=bs, n_rep=H // K, n_tiles=n))(
             sds((S, T, H, D), jnp.bfloat16),
-            sds((NB, bs, K, D), jnp.bfloat16),
-            sds((NB, bs, K, D), jnp.bfloat16),
+            sds((NB, bs, K * D), jnp.bfloat16),
+            sds((NB, bs, K * D), jnp.bfloat16),
             sds((S, MB), jnp.int32), sds((S, T), jnp.int32),
             sds((), jnp.int32))
         calls = []

@@ -6,22 +6,31 @@ numbers; it cannot see what Mosaic refuses. Every refusal met while the
 kernel was written showed only here: a bf16 compare on the v5e's VPU, an
 ambient float32 matmul precision on bf16 operands, a DMA of a slab whose
 minor dimension is not whole 128-lane rows (the int8 scales), scoped VMEM at
-wide query tiles. So the serving shapes are compiled against a described
-v5e (`jax.experimental.topologies`), about a second each. A compile is not a
-run: numbers and times come from the chip (PERF.md).
+wide query tiles. Nor can it see a layout: a pool kept `[NB, bs, KVH, D]` is
+tiled `T(4,128)(2,1)` on the v5e, the kernel's `[NB, bs, KVH*D]` operand
+`T(8,128)(2,1)`, and the reshape between them was a copy of the whole pool, K
+and V, a layer a launch (42% of a decode cell's device time, PERF.md). So the
+serving shapes are compiled against a described v5e
+(`jax.experimental.topologies`), about a second each. A compile is not a run:
+numbers and times come from the chip (PERF.md).
 
 The topology is described inside a fixture, never at import: only one
 process may load libtpu, and every xdist worker imports every test file.
 Keep such tests in this one file.
 """
+import math
 import os
+import re
+import types
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from paddle_tpu import serving_cache as sc
 from paddle_tpu.ops.pallas import paged_attention as pk
+from paddle_tpu.serving import PagedLlamaDecodeEngine
 
 
 @pytest.fixture(scope="module")
@@ -83,14 +92,80 @@ def test_paged_attention_kernel_compiles_for_the_v5e(one_chip, name):
         lambda q, k, v, t, p, n, ks, vs, lo: pk.paged_attention_kernel(
             q, k, v, t, p, block_size=bs, n_rep=H // K, n_tiles=n,
             k_scale=ks, v_scale=vs, lower=lo)).lower(
-        sds((S, T, H, D), qdt), sds((NB, bs, K, D), pool),
-        sds((NB, bs, K, D), pool), sds((S, MB), "int32"),
+        sds((S, T, H, D), qdt), sds((NB, bs, K * D), pool),
+        sds((NB, bs, K * D), pool), sds((S, MB), "int32"),
         sds((S, T), "int32"), sds((), "int32"), scale, scale,
         lower).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # the trace, the benchmark's readers and chip_smoke.py find it by this
     assert "_paged_attention_call" in text
+
+
+# id: (S, T, (H, KVH, D), num_blocks, max_blocks, pool dtype[, bounded]), at
+# block_size 16: a launch's K/V write and its attention over one layer's pool
+WRITE_THEN_ATTEND = {
+    "yi_decode": (32, 1, YI, 4096, 128, "bfloat16"),
+    "yi_chunk_64": (1, 64, YI, 4096, 128, "bfloat16"),
+    "yi_float32_pool_decode": (32, 1, YI, 4096, 128, "float32"),
+    "yi_int8_pool_decode": (32, 1, YI, 4096, 128, "int8"),
+    "cmda_full_decode": (32, 1, CMDA, 12288, 1024, "bfloat16"),
+    "cmda_window_decode": (32, 1, CMDA, 9472, 1024, "bfloat16", True),
+    "cmda_window_chunk_512": (1, 512, CMDA, 9472, 1024, "bfloat16", True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WRITE_THEN_ATTEND))
+def test_no_launch_copies_a_pool(one_chip, name):
+    """The engine's K/V write, then the seam's kernel, with the pools
+    donated: the scatter writes in place, the kernel reads what the scatter
+    wrote, and nothing else in the program is as large as a pool."""
+    S, T, (H, K, D), NB, MB, pool, *bounded = WRITE_THEN_ATTEND[name]
+    bs, quant = 16, pool == "int8"
+    qdt = "float32" if pool == "float32" else "bfloat16"
+    eng = types.SimpleNamespace(
+        block_size=bs, kv_quant="int8" if quant else None, _sc=sc,
+        _kv=types.SimpleNamespace(max_blocks_per_slot=MB))
+
+    def launch(kvl, q, k, v, tables, pos, n):
+        kvl = PagedLlamaDecodeEngine._write_kv(
+            eng, kvl, k, v, pos, tables, jnp.ones(pos.shape, bool))
+        att = sc.paged_attention(
+            q, kvl["k"], kvl["v"], tables, pos, block_size=bs,
+            n_rep=H // K, n_tiles=n, k_scale=kvl.get("ksc"),
+            v_scale=kvl.get("vsc"), use_kernel=True,
+            lower=pos - 4095 if bounded else None)
+        return att, kvl
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    kvl = {"k": sds((NB, bs, K * D), pool), "v": sds((NB, bs, K * D), pool)}
+    if quant:
+        kvl.update(ksc=sds((NB, bs, K), "float32"),
+                   vsc=sds((NB, bs, K), "float32"))
+    compiled = jax.jit(launch, donate_argnums=(0,)).lower(
+        kvl, sds((S, T, H, D), qdt), sds((S, T, K, D), qdt),
+        sds((S, T, K, D), qdt), sds((S, MB), "int32"), sds((S, T), "int32"),
+        sds((), "int32")).compile()
+    text = compiled.as_text()
+    assert "_paged_attention_call" in text
+    # every instruction whose result is pool-shaped, by its opcode: the
+    # parameters, the scatters (inside their fusions) and the two fusions
+    # that hold them; a reshape, copy, transpose or bitcast-convert is the
+    # relayout this test is here to catch
+    made = re.findall(
+        r"= \(?\w+\[%d,%d,%d\]\S* ([\w-]+)\(" % (NB, bs, K * D), text)
+    assert set(made) <= {"parameter", "scatter", "fusion"}, sorted(set(made))
+    assert made.count("fusion") == 2, made
+    mem = compiled.memory_analysis()
+    # donated and written in place, at no more bytes than the values take
+    # (an int8 block of 16 rows is two of its 8-row tiles: nothing is padded)
+    assert mem.alias_size_in_bytes == sum(
+        math.prod(a.shape) * a.dtype.itemsize for a in kvl.values())
+    # and no temporary as large as one group of blocks, let alone a pool
+    group = pk._GROUP_TOKENS * K * D * jnp.dtype(pool).itemsize
+    assert mem.temp_size_in_bytes < group, (mem.temp_size_in_bytes, group)
 
 
 # rows of the sorted buffer, row tile, columns: Command A+'s held experts
