@@ -4,12 +4,12 @@ Default: lint the package and print the report (exit 1 on error-severity
 findings — the CI contract tests/test_lint_clean.py mirrors in-process).
 
 Options:
-  --self-check    seed one bug per analyzer, assert each rule fires
-                  (the bench --dispatch-only smoke); exit 1 on failure
+  --self-check    seed one bug per analyzer, assert each rule fires;
+                  exit 1 on failure
   --rules         print the rule table (ids, analyzers, severities)
   --capture-plan  static capture plan over the repo's own step
-                  functions (hapi train/eval step, serving decode step,
-                  bench step) — the whole-step-capture work list; exit
+                  functions (hapi train/eval step, serving decode
+                  step) — the whole-step-capture work list; exit
                   1 on unaccounted breaks or error-severity findings
   --json          emit the report/plan as JSON instead of text
 """

@@ -172,7 +172,7 @@ CAPTURE_ALLOWLIST = [
      "extension, prefill staging, speculative accept/rollback — "
      "committing the verified prefix and truncating rejected draft "
      "block writes) advances BETWEEN captured programs by design: "
-     "the jitted dense/paged _decode_impl, the paged _prefill_impl "
+     "the jitted _decode_impl, the _prefill_impl "
      "chunks and the spec propose/verify pair are the capture "
      "regions, the server loop is the boundary that replays them"),
     ("PTC003", "paddle_tpu/serving.py*",
@@ -182,11 +182,6 @@ CAPTURE_ALLOWLIST = [
      "decode_steps batches it to one fetch per window and a "
      "speculative step fetches ONCE for up to spec_k committed "
      "tokens (the verify outputs drive accept/rollback)"),
-    ("PTC003", "bench.py*",
-     "deliberate device barriers: a value cannot arrive before the "
-     "work that produces it has finished — warmup fetches bound the "
-     "compile, the final fetch closes the timed region; the timed "
-     "loop itself stays fetch-free"),
     ("PTC001", "paddle_tpu/amp/grad_scaler.py*",
      "the legacy override path ONLY: an optimizer with a custom "
      "step() (the LBFGS pattern) must run as written, so the found "

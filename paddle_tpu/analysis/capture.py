@@ -670,10 +670,6 @@ REPO_STEPS: List[Tuple[str, str, Tuple[str, ...]]] = [
      ("inputs", "labels")),
     ("paddle_tpu/hapi/model.py", "Model.eval_batch",
      ("inputs", "labels")),
-    ("paddle_tpu/serving.py", "LlamaDecodeEngine._decode_impl",
-     ("params", "k_cache", "v_cache", "last_ids", "pos")),
-    ("paddle_tpu/serving.py", "LlamaDecodeEngine.step", ()),
-    ("paddle_tpu/serving.py", "LlamaDecodeEngine.decode_steps", ()),
     ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine._decode_impl",
      ("params", "kv", "last_ids", "pos", "tables", "act")),
     ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine._prefill_impl",
@@ -699,7 +695,8 @@ REPO_STEPS: List[Tuple[str, str, Tuple[str, ...]]] = [
      ("params", "kv", "last_ids", "draft_tok", "pos", "tables",
       "act")),
     ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine.spec_step", ()),
-    ("paddle_tpu/serving.py", "LlamaDecodeEngine.swap_weights", ()),
+    ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine.swap_weights",
+     ()),
     ("paddle_tpu/serving.py",
      "GenerationServer._apply_pending_swap", ()),
     ("paddle_tpu/serving.py",
@@ -723,7 +720,6 @@ REPO_STEPS: List[Tuple[str, str, Tuple[str, ...]]] = [
     ("paddle_tpu/distributed/dist_train.py", "_DistCapturedStep.step",
      ("inputs", "labels")),
     ("paddle_tpu/amp/grad_scaler.py", "GradScaler.step", ()),
-    ("bench.py", "bench_llama", ()),
 ]
 
 

@@ -3,10 +3,10 @@
 ``report()`` composes a capture audit (when given a callable), a full
 source-lint pass and, when a lock auditor is active, its summary into
 one :class:`AnalysisReport` with a single ``diagnostics`` list and a
-text/dict rendering. ``self_check()`` is the smoke contract the bench
-``--dispatch-only`` path runs: one seeded bug per analyzer, each of
-which must be detected by its rule id — proving the analysis plane
-itself works before anyone trusts a clean report.
+text/dict rendering. ``self_check()`` is the smoke contract
+(``python -m paddle_tpu.analysis --self-check``): one seeded bug per
+analyzer, each of which must be detected by its rule id — proving the
+analysis plane itself works before anyone trusts a clean report.
 """
 from __future__ import annotations
 
@@ -106,8 +106,8 @@ def self_check(verbose: bool = False) -> Dict[str, Any]:
     audit, capture (one break per PTC rule), shapes (a wrong spec
     fails the golden run), flight (a synthetic crash leaves a dump
     containing the seeded event) and locks. Returns {"ok": bool,
-    "checks": {name: bool}, "detail": str}. Cheap enough for the bench
-    ``--dispatch-only`` path (~a second, CPU)."""
+    "checks": {name: bool}, "detail": str}. About a second on the
+    CPU."""
     checks: Dict[str, bool] = {}
     details: List[str] = []
 

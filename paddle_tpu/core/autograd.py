@@ -233,8 +233,7 @@ _op_gate_cache: Dict[str, list] = {}
 
 # -- telemetry (paddle_tpu.observability) ------------------------------------
 # dispatch.ops_total is the one REAL hot-path instrument (a counter inc
-# per dispatch, kill-switched by FLAGS_metrics — the metrics_overhead
-# bench measures exactly this). Per-op attribution rides free: the
+# per dispatch, kill-switched by FLAGS_metrics). Per-op attribution rides free: the
 # collector below reads the dispatch counts _op_gate already keeps, so
 # ops_dispatched_total{op=...} costs the hot loop nothing.
 _M_ops = _om.counter(
@@ -269,9 +268,8 @@ def _op_gate(name: str, n_args: int) -> bool:
     """Returns has_vjp for the op; validates arity on first dispatch and
     counts dispatches (introspection via op_registry.dispatch_counts)."""
     if _M_flag.value:
-        # inline unlabeled-counter bump (see Counter._v): the measured
-        # per-dispatch telemetry cost, enforced ≤5% by bench.py's
-        # metrics_overhead line
+        # inline unlabeled-counter bump (see Counter._v): the whole
+        # per-dispatch telemetry cost
         _M_ops._v += 1
     hit = _op_gate_cache.get(name)
     if hit is not None:

@@ -155,8 +155,7 @@ define_flag("fusion_flush_origin", False,
 define_flag("metrics", True,
             "Process-wide telemetry registry (paddle_tpu.observability): "
             "counters/gauges/histograms woven through dispatch, fusion, "
-            "collectives, checkpointing and serving. Default ON — the "
-            "metrics_overhead bench enforces <=5% dispatch overhead. "
+            "collectives, checkpointing and serving. Default ON. "
             "FLAGS_metrics=0 is the kill switch: every instrument "
             "mutation becomes one cached flag read + return")
 define_flag("serving_block_size", 16,
@@ -193,12 +192,6 @@ define_flag("serving_spec_draft_layers", 0,
             "shares the target's embedding/head/first-N-layer weights "
             "at zero extra weight HBM. 0 (default) = half the target's "
             "layers (min 1)")
-define_flag("paged_attention_kernel", True,
-            "Use the Pallas block-table paged-attention TPU kernel "
-            "behind the serving_cache.paged_attention seam when the "
-            "backend supports it; 0 forces the pure-jnp tiled walk "
-            "(the CPU/tier-1 numerics oracle) everywhere. "
-            "decode/verify/prefill all route through the one seam")
 define_flag("serving_admission_policy", "static",
             "Admission policy a GenerationServer builds when none is "
             "passed: 'static' keeps the FLAGS_serving_shed_queue rule "

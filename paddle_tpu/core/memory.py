@@ -23,7 +23,7 @@ layers three sources:
 
 jit-internal temporaries never appear in (2)/(3) — they are XLA's, and
 are reported per-executable by :func:`program_memory_analysis` over
-``Compiled.memory_analysis()`` (bench emits them as peak_hbm_bytes).
+``Compiled.memory_analysis()``.
 """
 from __future__ import annotations
 

@@ -138,8 +138,7 @@ class Engine:
         decision metric for the memory-aware recompute pass (ref: the
         reference prices recompute candidates with its static memory
         cost model, not compiled binaries). The compiled
-        ``memory_analysis()`` remains the deployment truth (bench
-        peak_hbm_bytes); XLA CPU's schedule-agnostic temp figure cannot
+        ``memory_analysis()`` remains the deployment truth; XLA CPU's schedule-agnostic temp figure cannot
         see remat savings, the model can.
 
         Shape basis is GLOBAL: jaxpr avals carry unpartitioned logical

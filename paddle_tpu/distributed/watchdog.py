@@ -61,7 +61,7 @@ class Watchdog:
         loss = wd.run(lambda: float(step(x, y)))     # raises on hang
 
     The callable must block until device completion (a host value
-    transfer, the way bench.py closes its timed regions)."""
+    transfer)."""
 
     def __init__(self, timeout: float = 600.0,
                  on_timeout: Optional[Callable[[], None]] = None,

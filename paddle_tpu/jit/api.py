@@ -307,7 +307,7 @@ class TrainStep:
     def compile_stats(self, *batch):
         """Compile the step for these batch shapes without running it and
         return XLA's per-device memory analysis (same contract as
-        DistTrainStep.compile_stats; bench emits it as peak_hbm_bytes)."""
+        DistTrainStep.compile_stats)."""
         ins, lbls = self._split(batch)
         return self._step.compile_stats(ins, lbls)
 

@@ -1668,7 +1668,7 @@ class CapturedStep:
     def compile_stats(self, inputs, labels=()):
         """Compile the train step for these batch shapes without running
         it and return XLA's per-device memory analysis (TrainStep's
-        compile_stats contract; bench emits it as peak_hbm_bytes)."""
+        compile_stats contract)."""
         arrays = self._arrays(list(inputs) + list(labels))
         jitted = self._build("train", len(inputs))
         gathered = self._gather(train=True)

@@ -14,10 +14,11 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.tensor import Tensor
 from ..core.autograd import apply_op
@@ -344,6 +345,11 @@ class LlamaForCausalLM(Layer):
             logits = self._logits(h)
         return logits if caches is None else (logits, new_caches)
 
+    def serve_model(self):
+        """What the paged serving engine asks of a model (``serving.py``,
+        the seam between engine and model)."""
+        return LlamaServe(self.config)
+
     def generate(self, input_ids, max_new_tokens=32):
         """Greedy decode with per-layer KV caches (inference parity check,
         not the serving path)."""
@@ -394,6 +400,176 @@ class LlamaPretrainingCriterion(Layer):
             # the model's collective chain.
             return fused_softmax_ce_mean(lg, shifted, ignore_index=-100)
         return apply_op(f, logits, labels, op_name="causal_lm_loss")
+
+
+# ---------------------------------------------------------------------------
+# The serving seam: the decoder layer on arrays, over the engine's paged
+# cache (``serving.PagedLlamaDecodeEngine`` asks ``serve_model()`` for it).
+# ---------------------------------------------------------------------------
+
+def _quantize_w(w_t):
+    """Per-output-channel symmetric int8 of a TRANSPOSED [out, in]
+    weight (ref: quantize.py PTQ convert)."""
+    w_t = np.asarray(w_t, np.float32)
+    step = np.maximum(np.abs(w_t).max(axis=1), 1e-8) / 127.0
+    q = np.clip(np.round(w_t / step[:, None]), -127, 127).astype(np.int8)
+    return jnp.asarray(q), jnp.asarray(step.astype(np.float32))
+
+
+# Served weights are stored TRANSPOSED ([out, in]) and contracted against
+# their LAST dim: with the natural [in, out] orientation XLA's chosen
+# executable layout disagreed with the call-input layout and re-transposed
+# the weights every step, a per-call copy no warm-up can amortize because
+# jit inputs cannot be layout-pinned across calls.
+def _mm(h, w):
+    """h @ w (w stored transposed); int8 path = dynamic per-tensor
+    act quant + s8*s8->s32 with per-channel scale epilogue
+    (quantize._int8_linear_impl math, calibration-free because
+    decode activations are visible)."""
+    if isinstance(w, tuple):
+        w_q, w_step = w
+        step = jnp.maximum(jnp.max(jnp.abs(h.astype(jnp.float32))),
+                           1e-8) / 127.0
+        qh = jnp.clip(jnp.round(h.astype(jnp.float32) / step),
+                      -127, 127).astype(jnp.int8)
+        acc = jax.lax.dot_general(
+            qh, w_q, (((qh.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32)
+        return (acc.astype(jnp.float32) * (w_step * step)).astype(
+            h.dtype)
+    return jax.lax.dot_general(
+        h, w, (((h.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(h.dtype)
+
+
+def _rms(h, w, eps):
+    h32 = h.astype(jnp.float32)
+    var = jnp.mean(jnp.square(h32), axis=-1, keepdims=True)
+    return (h32 * jax.lax.rsqrt(var + eps)).astype(h.dtype) * w
+
+
+def _rope_at(x, positions, theta):
+    """x [S, T, Hd, D] rotated at per-slot absolute positions
+    (positions [S, T])."""
+    d2 = x.shape[-1] // 2
+    inv = 1.0 / (theta ** (jnp.arange(0, d2, dtype=jnp.float32) / d2))
+    freqs = positions.astype(jnp.float32)[..., None] * inv  # [S,T,d2]
+    cos = jnp.cos(freqs)[:, :, None, :]
+    sin = jnp.sin(freqs)[:, :, None, :]
+    x1, x2 = x[..., :d2], x[..., d2:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+        axis=-1).astype(x.dtype)
+
+
+class LlamaServe:
+    """The model's side of the serving seam: its cache spec (for each
+    layer a kind, ``full`` or ``window`` of ``W`` positions, and KV heads
+    x head_dim), its parameters as the engine's pytree, and a step a
+    layer ``(h, the layer's pools, positions, the kind's block table) ->
+    (h, pools, counts)``. From the engine it takes cache services only:
+    ``_write_kv``, ``_sc.paged_attention``, ``block_size``, ``n_rep``,
+    ``_pa_kernel``, and ``dtype`` / ``n_layers`` / ``int8`` to lay out
+    its parameters."""
+
+    n_aux = 0            # small integers a launch hands back with its token
+    aux_names = ()
+    supports_int8 = True
+    supports_speculation = True
+
+    def __init__(self, cfg: LlamaConfig):
+        self.cfg = cfg
+        self.head_dim = cfg.hidden_size // cfg.num_attention_heads
+
+    def cache_spec(self, n_layers: int) -> list:
+        cfg = self.cfg
+        return [{"kind": "full", "window": None,
+                 "kv_heads": cfg.num_key_value_heads,
+                 "head_dim": self.head_dim,
+                 "q_heads": cfg.num_attention_heads}] * n_layers
+
+    def build_params(self, eng, sd) -> Dict[str, object]:
+        """Device param pytree from a name -> array/Tensor state dict:
+        dtype cast, TRANSPOSED projections (see ``_mm``), optional int8
+        quantization, layer truncation."""
+        cfg, dt = self.cfg, eng.dtype
+
+        def get(name):
+            try:
+                v = sd[name]
+            except KeyError:
+                raise ValueError(
+                    f"weight state dict is missing {name!r} — not a "
+                    f"checkpoint of this model") from None
+            if hasattr(v, "_data"):
+                v = v._data
+            return jnp.asarray(v, dt)
+
+        p: Dict[str, object] = {"emb": get("llama.embed_tokens.weight"),
+                                "norm": get("llama.norm.weight")}
+        if cfg.tie_word_embeddings:
+            p["head"] = p["emb"]      # [V, H] is already the
+        else:                         # transposed head
+            p["head"] = get("lm_head.weight").T
+        layers = []
+        for i in range(eng.n_layers):
+            pre = f"llama.layers.{i}."
+            lp = {"in_ln": get(pre + "input_layernorm.weight"),
+                  "post_ln": get(pre
+                                 + "post_attention_layernorm"
+                                   ".weight")}
+            for nm in ("q_proj", "k_proj", "v_proj", "o_proj"):
+                lp[nm] = get(pre + "self_attn." + nm + ".weight").T
+            for nm in ("gate_proj", "up_proj", "down_proj"):
+                lp[nm] = get(pre + "mlp." + nm + ".weight").T
+            if eng.int8:
+                for nm in ("q_proj", "k_proj", "v_proj", "o_proj",
+                           "gate_proj", "up_proj", "down_proj"):
+                    lp[nm] = _quantize_w(lp[nm])
+            layers.append(lp)
+        p["layers"] = layers
+        if eng.int8:
+            p["head"] = _quantize_w(p["head"])
+        return p
+
+    def embed(self, eng, params, ids):
+        return jnp.take(params["emb"], ids, axis=0).astype(eng.dtype)
+
+    def layer(self, eng, li, lp, h, kvl, positions, tables, n_tiles, wmask):
+        """One decoder layer over [S, T, H] with block-pool K/V writes
+        and the tiled streaming attention."""
+        cfg = self.cfg
+        S, T, H = h.shape
+        kvh = cfg.num_key_value_heads
+        res = h
+        x = _rms(h, lp["in_ln"], cfg.rms_norm_eps)
+        q = _mm(x, lp["q_proj"]).reshape(
+            S, T, cfg.num_attention_heads, self.head_dim)
+        k = _mm(x, lp["k_proj"]).reshape(S, T, kvh, self.head_dim)
+        v = _mm(x, lp["v_proj"]).reshape(S, T, kvh, self.head_dim)
+        q = _rope_at(q, positions, cfg.rope_theta)
+        k = _rope_at(k, positions, cfg.rope_theta)
+        with jax.named_scope("paged.kv_write"):
+            kvl = eng._write_kv(kvl, k, v, positions, tables, wmask)
+        with jax.named_scope("paged.attn"):
+            att = eng._sc.paged_attention(
+                q, kvl["k"], kvl["v"], tables, positions,
+                block_size=eng.block_size, n_rep=eng.n_rep,
+                n_tiles=n_tiles, k_scale=kvl.get("ksc"),
+                v_scale=kvl.get("vsc"), use_kernel=eng._pa_kernel)
+        h = res + _mm(att.reshape(S, T, H), lp["o_proj"])
+        with jax.named_scope("paged.mlp"):
+            res = h
+            x = _rms(h, lp["post_ln"], cfg.rms_norm_eps)
+            ff = _mm(jax.nn.silu(
+                _mm(x, lp["gate_proj"]).astype(jnp.float32)).astype(
+                    x.dtype) * _mm(x, lp["up_proj"]),
+                lp["down_proj"])
+            return res + ff, kvl, None
+
+    def head(self, eng, params, h):
+        return _mm(_rms(h, params["norm"], self.cfg.rms_norm_eps),
+                   params["head"])
 
 
 # ---------------------------------------------------------------------------

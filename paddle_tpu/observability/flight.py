@@ -57,9 +57,7 @@ degraded): a replica SIGKILL and its recovery read as one trace.
 
 Recording is on by default (``FLAGS_flight_recorder``) because an
 append costs the same class of work as a ``Counter`` bump — one cached
-flag read, one clock read, one tuple, one GIL-atomic ``deque.append``
-— enforced by bench.py's ``flight_recorder_overhead`` line (≤5% of a
-cached eager dispatch, same bar as ``metrics_overhead``).
+flag read, one clock read, one tuple, one GIL-atomic ``deque.append``.
 
 Crash forensics: :func:`dump` freezes the ring as a JSONL file (header
 line + one event per line) and best-effort merges the host-tracer

@@ -8,9 +8,7 @@ they all migrate onto:
 
 - **Instruments** are lock-cheap and kill-switchable: every mutation
   first checks ``FLAGS_metrics`` (one cached attribute read) and
-  returns immediately when metrics are off — the always-on claim is
-  enforced by bench.py's ``metrics_overhead`` line (≤5% dispatch
-  overhead), not asserted.
+  returns immediately when metrics are off.
 - **Labels** ride as kwargs (``counter.inc(op="add")``); label values
   keep their Python type internally (the fusion chain-length view needs
   int keys back) and stringify only at exposition time.

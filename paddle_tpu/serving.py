@@ -1,67 +1,33 @@
-"""Generation serving: fixed-slot continuous batching over a compiled
-single-token decode step.
+"""Generation serving: fixed-slot continuous batching over compiled,
+static-shaped step programs, with one decode engine.
 
-The reference's inference engine is a production deliverable whose LLM
-path runs fused multi-transformer decode kernels behind the predictor
-(ref: paddle/fluid/inference/api/analysis_predictor.h +
-phi/kernels/fusion/gpu/fused_multi_transformer_op.cu). The TPU-native
-equivalent keeps everything STATIC-SHAPED so XLA compiles exactly two
-program families:
+``PagedLlamaDecodeEngine`` keeps the slots, a shared block pool a layer
+``[num_blocks, block_size, KVH*D]`` behind per-slot block tables
+(``serving_cache``: ``PagedKVCache``, or ``KindedKVCache`` with a table a
+kind of layer for a model with window layers), donation, warm-up bundles,
+weight swaps and speculation. It holds no model's math.
 
-- ``prefill[bucket]``: prompt forward (padded to a pow-2 bucket)
-  writing K/V into the slot's cache;
-- ``decode``: ONE step advancing ALL slots together — q of shape
-  [slots, 1] against the per-slot K/V history with per-slot position
-  masks. Iteration-level (continuous) batching falls out: requests
-  join/leave at step boundaries, the compiled program never changes.
+**The seam.** ``model.serve_model()`` hands the engine a cache spec (a
+kind, KV heads and head width a layer), ``build_params`` (its weights as
+the engine's pytree), ``embed``, ``head`` and one layer's step ``(h, the
+layer's pools, positions, the kind's block table) -> (h, pools, counts)``.
+The engine offers that step ``_write_kv`` (rope'd K/V rows into their
+blocks), ``_sc.paged_attention`` (``_pa_kernel``: the Pallas kernel on a
+TPU at head widths of whole 128-lane rows, the counted jnp walk elsewhere),
+``block_size``, ``n_rep``, and ``dtype`` / ``n_layers`` / ``int8`` for its
+parameters' layout. ``models/llama.py`` and ``models/cohere2_moe.py`` use it.
 
-Two cache layouts ship:
+**Two program families**, through ``jit.sot.capture_jit``: ``serving.decode``
+(``jit_serving_decode`` in a device trace), one token for every slot at its
+own position, and ``serving.prefill_b<bucket>`` (``jit_serving_prefill_b8``
+...), one prompt chunk of one slot padded to a power-of-two bucket up to
+the chunk length. Speculation adds ``serving.spec_draft`` /
+``serving.spec_verify``, prefix sharing ``serving.prefix_cow``.
 
-- **Dense** (:class:`LlamaDecodeEngine`): per-layer arrays
-  [slots, max_seq, KVH*D] (a stacked [L, ...] form measured
-  ~11 ms/step of slice/stack copies), donated through the decode step
-  so the update is in-place in HBM. Simple, but HBM scales with
-  *capacity* (slots x max_seq) whether slots are full or idle.
-- **Paged** (:class:`PagedLlamaDecodeEngine`, the production/server
-  default): a shared per-layer block pool [num_blocks, block_size,
-  KVH*D] plus per-slot block tables (``serving_cache.PagedKVCache``),
-  so HBM scales with *active tokens*. The KV heads lie side by side in
-  the minor dimension from allocation to the kernel call: the v5e
-  tiles a bf16 [..., KVH, 128] array T(4,128)(2,1) and the paged
-  kernel's [block_size, KVH*D] slab T(8,128)(2,1), so a pool kept
-  four-dimensional was copied whole, K and V, a layer a launch.
-  Prompts prefill in CHUNKS
-  through their own bucketed executable interleaved with decode steps
-  (a long prompt never stalls the in-flight batch), and the decode
-  attention is a tiled streaming walk of each slot's block list
-  (``serving_cache.paged_attention``) that never materializes a dense
-  [S, max_seq] view. Optional bf16/int8 block storage
-  (``kv_quant=``) reuses the quantize.py absmax math.
-
-``int8=True`` runs every projection as a REAL s8 x s8 -> s32 MXU matmul
-(dynamic per-tensor activation quant, per-channel weight scales — the
-same math as quantization.Int8Linear) with bf16 caches/activations.
-
-Every engine's attention routes through the ONE
-``serving_cache.paged_attention`` seam (the dense cache is viewed as
-an identity-mapped block pool), behind which
-``FLAGS_paged_attention_kernel`` selects the Pallas block-table TPU
-kernel or the pure-jnp tile walk (the CPU/tier-1 numerics oracle).
-The paged engine additionally supports **speculative decoding**
-(``attach_draft``): a cheap draft — typically ``make_draft``'s
-truncated-layer weight-sharing view — proposes
-``FLAGS_serving_spec_tokens`` tokens per step, the target verifies
-the whole window in one batched call, accepted prefixes commit and
-rejected suffixes roll their block writes back through the admission
-reservation (``PagedKVCache.truncate``); greedy output stays
-BIT-equal to the non-speculative stream.
-
-Decode is memory-bound (every step streams the full weight set), so the
-bench grades tokens/s against the weight-streaming roofline:
-slots / (weight_bytes / HBM_BW) — with the cache-traffic term sized
-O(slots x max_seq) for the dense engine and O(active tokens) for the
-paged one (``llama_decode_paged_tokens_per_sec``).
-"""
+**Chunking** is the engine's (``begin_request`` reserves blocks,
+``prefill_chunk`` runs at most ``FLAGS_serving_prefill_chunk`` tokens a call);
+**the loop** is ``GenerationServer._loop``: admit, one prompt chunk, one
+decode step for all active slots, commit, sweep."""
 from __future__ import annotations
 
 import itertools
@@ -79,8 +45,7 @@ from .observability import metrics as _om
 from .profiler import RecordEvent as _span
 from .utils import fault_injection as _fi
 
-__all__ = ["LlamaDecodeEngine", "PagedLlamaDecodeEngine",
-           "GenerationServer"]
+__all__ = ["PagedLlamaDecodeEngine", "GenerationServer"]
 
 # process registry instruments (one set across all servers; the
 # per-instance stats() dict stays the legacy view)
@@ -183,69 +148,72 @@ _REQ_SEQ = itertools.count(1)
 _I32 = jax.ShapeDtypeStruct((), np.int32)
 
 
-def _quantize_w(w_t):
-    """Per-output-channel symmetric int8 of a TRANSPOSED [out, in]
-    weight (ref: quantize.py PTQ convert)."""
-    w_t = np.asarray(w_t, np.float32)
-    step = np.maximum(np.abs(w_t).max(axis=1), 1e-8) / 127.0
-    q = np.clip(np.round(w_t / step[:, None]), -127, 127).astype(np.int8)
-    return jnp.asarray(q), jnp.asarray(step.astype(np.float32))
+class PagedLlamaDecodeEngine:
+    """Paged-KV decode engine for a model that hands over the serving
+    seam (``model.serve_model()``, module docstring).
 
+    Layout: one shared pool per layer ``[num_blocks, block_size,
+    KVH*D]`` (``serving_cache.PagedKVCache``) addressed through per-slot
+    block tables, so KV HBM scales with ACTIVE tokens instead of
+    slots x max_seq. A pool is allocated, written, copied and read in
+    that one layout, the one the paged kernel's block copies read (a
+    block is one contiguous ``[block_size, KVH*D]`` slab, tiled
+    ``T(8,128)(2,1)`` on the v5e; a bf16 ``[..., KVH, 128]`` pool would
+    be tiled ``T(4,128)(2,1)`` and copied whole before every attention
+    call). Admission reserves a request's worst-case block
+    count (prompt + generation budget), prompt blocks are mapped
+    immediately, and decode extends one block at a time at step
+    boundaries — extension can therefore never fail mid-stream.
 
-class _LlamaServe:
-    """The model's side of the seam between engine and model, for the
-    Llama code: the first user of it. A model hands the engine (through
-    ``model.serve_model()``; a model without one is a Llama) its cache
-    spec (for each layer a kind, ``full`` or ``window`` of ``W``
-    positions, and KV heads x head_dim), its parameters as the engine's
-    pytree, and a step a layer ``(h, the layer's pools, positions, the
-    kind's block table) -> (h, pools, counts)``. The engine keeps slots,
-    tables, chunking, donation, warm-up and the loop. Llama's layer math
-    stays on the engine (``_block_paged``; the dense engine shares it)."""
+    Prefill is CHUNKED: ``begin_request`` allocates, then
+    ``prefill_chunk`` runs at most ``FLAGS_serving_prefill_chunk``
+    prompt tokens through a bucketed executable per call, writing K/V
+    straight into the slot's blocks; the GenerationServer loop
+    interleaves one chunk with each decode step so a long prompt
+    stalls the in-flight batch by at most one chunk forward.
 
-    n_aux = 0            # small integers a launch hands back with its token
-    aux_names = ()
-    supports_int8 = True
-    supports_speculation = True
+    The decode step (``_decode_impl``, registered through
+    ``capture_jit`` with the pool pytree donated) walks each slot's
+    block list with the tiled streaming attention
+    (``serving_cache.paged_attention``) — no ``[S, max_seq]`` score or
+    cache view is ever materialized.
 
-    def __init__(self, cfg):
-        self.cfg = cfg
-
-    def cache_spec(self, n_layers: int) -> list:
-        cfg = self.cfg
-        return [{"kind": "full", "window": None,
-                 "kv_heads": cfg.num_key_value_heads,
-                 "head_dim": cfg.hidden_size // cfg.num_attention_heads,
-                 "q_heads": cfg.num_attention_heads}] * n_layers
-
-    def build_params(self, eng, sd):
-        return eng._build_llama_params(sd)
-
-    def embed(self, eng, params, ids):
-        return jnp.take(params["emb"], ids, axis=0).astype(eng.dtype)
-
-    def layer(self, eng, li, lp, h, kvl, positions, tables, n_tiles, wmask):
-        h, kvl = eng._block_paged(lp, h, kvl, positions, tables, n_tiles,
-                                  wmask)
-        return h, kvl, None
-
-    def head(self, eng, params, h):
-        return eng._mm(eng._rms(h, params["norm"]), params["head"])
-
-
-class LlamaDecodeEngine:
-    """Compiled decode engine for a LlamaForCausalLM.
-
-    Host-side state per slot: position, remaining budget, output ids.
-    Device-side: params (frozen), K/V caches (donated each step).
+    ``kv_quant``: None stores blocks in the model dtype, "bfloat16"
+    halves f32 pools, "int8" stores absmax codes + per-(token, head)
+    scales (quantize.py math) dequantized per gathered tile.
     """
+
+    # process-registry prefix metrics are target-engine only; an
+    # attached draft mirrors every admission (attach_draft flips this)
+    _prefix_metrics = True
 
     def __init__(self, model, max_slots: int = 4, max_seq: int = 256,
                  int8: bool = False, eos_id: Optional[int] = None,
+                 block_size: Optional[int] = None,
+                 num_blocks: Optional[int] = None,
+                 kv_quant: Optional[str] = None,
+                 prefill_chunk: Optional[int] = None,
                  num_layers: Optional[int] = None,
-                 share_params: Optional[Dict[str, object]] = None):
+                 share_params: Optional[Dict[str, object]] = None,
+                 prefix_cache: Optional[bool] = None):
+        from .core.flags import flag_value
+        self.block_size = int(block_size or
+                              flag_value("serving_block_size"))
+        mbs = -(-int(max_seq) // self.block_size)
+        auto = int(max_slots) * mbs  # every slot at max_seq at once
+        # one pool size, or one a kind of layer ({"full": n, "window": n})
+        # for a model whose cache spec has window layers
+        self.num_blocks = dict(num_blocks) if isinstance(num_blocks, dict) \
+            else int(num_blocks or flag_value("serving_num_blocks") or auto)
+        self._prefix_cache = prefix_cache
+        if kv_quant not in (None, "bfloat16", "int8"):
+            raise ValueError(
+                f"kv_quant must be None, 'bfloat16' or 'int8', got "
+                f"{kv_quant!r}")
+        self.kv_quant = kv_quant
+        self.prefill_chunk_len = int(
+            prefill_chunk or flag_value("serving_prefill_chunk"))
         cfg = model.config
-        self.cfg = cfg
         self.max_slots = int(max_slots)
         self.max_seq = int(max_seq)
         self.eos_id = eos_id
@@ -260,13 +228,12 @@ class LlamaDecodeEngine:
                 f"num_layers must be in [1, {cfg.num_hidden_layers}], "
                 f"got {num_layers}")
         # the seam: what this model asks of a cache and how its layer steps
-        self._m = model.serve_model() if hasattr(model, "serve_model") \
-            else _LlamaServe(cfg)
-        if not getattr(self, "paged", False) \
-                and not isinstance(self._m, _LlamaServe):
-            raise NotImplementedError(
-                "the dense engine runs the Llama block only; serve "
-                f"{type(model).__name__} from PagedLlamaDecodeEngine")
+        if not hasattr(model, "serve_model"):
+            raise TypeError(
+                f"{type(model).__name__} has no serve_model(): the engine "
+                f"serves a model only through the seam it hands over "
+                f"(cache_spec, build_params, embed, layer, head)")
+        self._m = model.serve_model()
         if self.int8 and not self._m.supports_int8:
             raise NotImplementedError(
                 f"int8 projections are not built for {type(model).__name__}")
@@ -305,15 +272,10 @@ class LlamaDecodeEngine:
 
         from . import serving_cache as _sc
         self._sc = _sc
-        # every engine's attention rides the ONE paged_attention seam
-        # (the dense cache is viewed as an identity-mapped block pool);
-        # the implementation behind it — Pallas kernel vs jnp walk —
-        # is decided here ONCE so the per-step path counters report
-        # what the compiled programs actually baked in
+        # the implementation behind the paged_attention seam — Pallas
+        # kernel vs jnp walk — is decided here ONCE so the per-step path
+        # counters report what the compiled programs actually baked in
         self._pa_kernel = _sc.use_kernel_default(self.head_dim)
-        self._attend_tile = next(
-            ts for ts in (128, 64, 32, 16, 8, 4, 2, 1)
-            if self.max_seq % ts == 0)
         self._draft: Optional["PagedLlamaDecodeEngine"] = None
         self._spec_k = 0
         # adaptive-admission brownout knobs, applied by the server at
@@ -325,7 +287,61 @@ class LlamaDecodeEngine:
         self._chunk_cap: Optional[int] = None
         from .jit.sot import capture_jit as _capture_jit
         self._capture_jit = _capture_jit
-        self._init_cache()
+
+        kinds = sorted({sp["kind"] for sp in self.cache_spec})
+        # a model with window layers gets a table and an allocator a
+        # kind; every other model the one table it always had
+        self._kinded = kinds != ["full"]
+        if self._kinded:
+            if self.kv_quant == "int8":
+                raise NotImplementedError(
+                    "an int8 KV pool is not built for window layers")
+            given = self.num_blocks if isinstance(self.num_blocks, dict) \
+                else {}
+            # a kind with no size given holds what every slot may hold
+            # at once: max_seq in a full table, window + chunk + a block
+            # in a window table
+            self._kv = _sc.KindedKVCache(
+                self.max_slots, self.max_seq, self.block_size,
+                {k: {"num_blocks": given.get(k),
+                     "window": self.window if k == "window" else None,
+                     "window_slack": self.prefill_chunk_len}
+                 for k in kinds}, prefix_cache=self._prefix_cache)
+            self.num_blocks = {k: c.num_blocks
+                               for k, c in self._kv.kinds.items()}
+        else:
+            if isinstance(self.num_blocks, dict):
+                self.num_blocks = int(self.num_blocks["full"])
+            self._kv = _sc.PagedKVCache(
+                max_slots=self.max_slots, max_seq=self.max_seq,
+                block_size=self.block_size, num_blocks=self.num_blocks,
+                prefix_cache=self._prefix_cache)
+        self.kvs = self._alloc_pools()
+        # counts a launch hands back (a model's `aux_names`) that no
+        # fetch has read yet: a prompt chunk that is not its prompt's
+        # last is never fetched, the next fetch reads them
+        self._aux_pending: List[object] = []
+        self.last_aux: Dict[str, int] = {}
+        # the pool pytree is donated each step/chunk: K/V writes land
+        # in place in HBM. The jitted step is registered as a CAPTURED
+        # step program (jit.sot.capture_jit): its clean capture plan is
+        # checked in (tests/test_capture_plan.py), every call counts into
+        # sot.captured_steps_total and the first compile lands in the
+        # flight journal — identical execution to a bare jax.jit
+        self._decode = self._capture_jit(self._decode_impl,
+                                         donate_argnums=(1,),
+                                         name="serving.decode",
+                                         warm={"program": "decode",
+                                               **self._warm_geo()})
+        self._decode_collect = None
+        self._prefills: Dict[int, object] = {}
+        self._prefill_state: Dict[int, dict] = {}
+        self.last_chunk: Dict[str, int] = {}
+        # prefix-sharing state: the boundary copy-on-write program is
+        # built lazily (first block-aligned hit), per-request hit
+        # accounting feeds the server's req["prefix_hit_tokens"]
+        self._cow = None
+        self.prefix_hit_tokens: Dict[int, int] = {}
 
     def _build_params(self, sd) -> Dict[str, object]:
         """Device param pytree from a name -> array/Tensor state dict,
@@ -333,51 +349,6 @@ class LlamaDecodeEngine:
         swapped-in tree is layout-identical to a boot-time one and the
         compiled step programs are reused as-is."""
         return self._m.build_params(self, sd)
-
-    def _build_llama_params(self, sd) -> Dict[str, object]:
-        """Llama's tree: the same prep ``__init__`` does — dtype cast,
-        TRANSPOSED projections, optional int8 quantization, layer
-        truncation."""
-        cfg, dt = self.cfg, self.dtype
-
-        def get(name):
-            try:
-                v = sd[name]
-            except KeyError:
-                raise ValueError(
-                    f"weight state dict is missing {name!r} — not a "
-                    f"checkpoint of this model") from None
-            if hasattr(v, "_data"):
-                v = v._data
-            return jnp.asarray(v, dt)
-
-        p: Dict[str, object] = {"emb": get("llama.embed_tokens.weight"),
-                                "norm": get("llama.norm.weight")}
-        # projections stored transposed ([out, in]) — see _mm
-        if cfg.tie_word_embeddings:
-            p["head"] = p["emb"]      # [V, H] is already the
-        else:                         # transposed head
-            p["head"] = get("lm_head.weight").T
-        layers = []
-        for i in range(self.n_layers):
-            pre = f"llama.layers.{i}."
-            lp = {"in_ln": get(pre + "input_layernorm.weight"),
-                  "post_ln": get(pre
-                                 + "post_attention_layernorm"
-                                   ".weight")}
-            for nm in ("q_proj", "k_proj", "v_proj", "o_proj"):
-                lp[nm] = get(pre + "self_attn." + nm + ".weight").T
-            for nm in ("gate_proj", "up_proj", "down_proj"):
-                lp[nm] = get(pre + "mlp." + nm + ".weight").T
-            if self.int8:
-                for nm in ("q_proj", "k_proj", "v_proj", "o_proj",
-                           "gate_proj", "up_proj", "down_proj"):
-                    lp[nm] = _quantize_w(lp[nm])
-            layers.append(lp)
-        p["layers"] = layers
-        if self.int8:
-            p["head"] = _quantize_w(p["head"])
-        return p
 
     @staticmethod
     def _leaf_specs(p) -> Dict[str, object]:
@@ -452,8 +423,10 @@ class LlamaDecodeEngine:
         (counted ``warmup.failures_total{reason=stale}``) instead of
         silently replaying programs the persistent cache has no
         artifacts for."""
-        return {"layout": "dense", "slots": self.max_slots,
-                "max_seq": self.max_seq}
+        return {"layout": "paged", "slots": self.max_slots,
+                "max_seq": self.max_seq, "block_size": self.block_size,
+                "num_blocks": self.num_blocks,
+                "chunk": self.prefill_chunk_len}
 
     def _bundle_stale(self, meta, keys=None) -> List[str]:
         """Geometry keys on which a warm-bundle entry disagrees with
@@ -469,464 +442,6 @@ class LlamaDecodeEngine:
             geo = {k: geo[k] for k in keys if k in geo}
         return sorted(k for k, v in geo.items()
                       if k in meta and meta[k] != v)
-
-    def reset_state(self) -> None:
-        """Discard ALL slot and cache state — the crash-recovery seam:
-        after a decode-loop crash the donated cache buffers may be
-        mid-donation (deleted), so fresh zero pools replace them and
-        the host bookkeeping (pos/active/last_ids) resets. The
-        compiled step programs are KEPT — they are pure functions of
-        their arguments, so recovery costs zero recompiles."""
-        self.pos[:] = 0
-        self.active[:] = False
-        self.last_ids[:] = 0
-        self._alloc_cache()
-
-    def _prewarm_entry(self, entry):
-        """AOT-rebuild one recorded serving program (a warm-bundle
-        entry) over this engine's live geometry via
-        ``lower().compile()`` — with the persistent executable cache
-        enabled this is a disk read, not a fresh XLA compile. Returns
-        False for entries this engine cannot replay (unknown program,
-        spec programs without a draft attached) and the string
-        ``"stale"`` for entries whose recorded geometry disagrees with
-        the live config (replaying those would compile FRESH programs
-        at boot while claiming warmth)."""
-        meta = entry.get("meta") or {}
-        if meta.get("program") != "decode":
-            return False
-        if self._bundle_stale(meta):
-            return "stale"
-        S = self.max_slots
-        # helper args are NumPy-backed (device_put, not a compiled
-        # fill program): pre-warm must never compile anything the
-        # bundle's writer didn't
-        self._decode._jitted.lower(
-            self.params, self.k_cache, self.v_cache,
-            jnp.asarray(np.zeros((S, 1), np.int32)),
-            jnp.asarray(np.zeros(S, np.int32))).compile()
-        _flight.record("warmup", "serving_program", program="decode")
-        return True
-
-    def _alloc_cache(self) -> None:
-        """(Re)allocate the dense per-layer cache arrays — fresh zeros
-        at boot AND at crash recovery (``reset_state``)."""
-        cfg = self.cfg
-        S, L = self.max_slots, self.n_layers
-        kvh = cfg.num_key_value_heads
-        # per-LAYER cache arrays (not one stacked [L, ...] array): the
-        # stacked form costs a slice per layer + a stack per step that
-        # XLA materializes as whole-cache copies (~11 ms/step measured
-        # at 6 layers x 8 slots x 1024); per-layer donated leaves
-        # update in place
-        # the KV heads flat in the minor dimension, as the paged pools
-        # are: _attend's view of a slot's rows as blocks is then free
-        self.k_cache = [jnp.zeros((S, self.max_seq, kvh * self.head_dim),
-                                  self.dtype) for _ in range(L)]
-        self.v_cache = [jnp.zeros_like(self.k_cache[0])
-                        for _ in range(L)]
-
-    def _init_cache(self) -> None:
-        """Build the DENSE cache layout + its compiled step programs
-        (PagedLlamaDecodeEngine overrides with the block pool)."""
-        self._alloc_cache()
-        # caches are donated: each decode step updates them in place in
-        # HBM instead of allocating a second [L,S,max_seq,...] copy.
-        # The jitted step is registered as a CAPTURED step program
-        # (jit.sot.capture_jit): its clean capture plan is checked in
-        # (tests/test_capture_plan.py), so every call counts into
-        # sot.captured_steps_total and the first compile lands in the
-        # flight journal — identical execution to a bare jax.jit
-        self._decode = self._capture_jit(self._decode_impl,
-                                         donate_argnums=(1, 2),
-                                         name="serving.decode",
-                                         warm={"program": "decode",
-                                               **self._warm_geo()})
-        self._decode_collect = None
-        self._prefills: Dict[int, object] = {}
-
-    # -- math ---------------------------------------------------------------
-    # Weights are stored TRANSPOSED ([out, in]) and contracted against
-    # their LAST dim: with the natural [in, out] orientation XLA's
-    # chosen executable layout disagreed with the call-input layout and
-    # re-transposed ~1 GB of weights EVERY step (~3.6 ms/step measured)
-    # — a per-call copy no warm-up can amortize because jit inputs
-    # cannot be layout-pinned across calls.
-    def _mm(self, h, w):
-        """h @ w (w stored transposed); int8 path = dynamic per-tensor
-        act quant + s8*s8->s32 with per-channel scale epilogue
-        (quantize._int8_linear_impl math, calibration-free because
-        decode activations are visible)."""
-        if isinstance(w, tuple):
-            w_q, w_step = w
-            step = jnp.maximum(jnp.max(jnp.abs(h.astype(jnp.float32))),
-                               1e-8) / 127.0
-            qh = jnp.clip(jnp.round(h.astype(jnp.float32) / step),
-                          -127, 127).astype(jnp.int8)
-            acc = jax.lax.dot_general(
-                qh, w_q, (((qh.ndim - 1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32)
-            return (acc.astype(jnp.float32) * (w_step * step)).astype(
-                h.dtype)
-        return jax.lax.dot_general(
-            h, w, (((h.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(h.dtype)
-
-    def _rms(self, h, w):
-        h32 = h.astype(jnp.float32)
-        var = jnp.mean(jnp.square(h32), axis=-1, keepdims=True)
-        return (h32 * jax.lax.rsqrt(var + self.cfg.rms_norm_eps)).astype(
-            h.dtype) * w
-
-    def _rope(self, x, positions):
-        """x [S, T, Hd, D] rotated at per-slot absolute positions
-        (positions [S, T])."""
-        d2 = self.head_dim // 2
-        inv = 1.0 / (self.cfg.rope_theta ** (
-            jnp.arange(0, d2, dtype=jnp.float32) / d2))
-        freqs = positions.astype(jnp.float32)[..., None] * inv  # [S,T,d2]
-        cos = jnp.cos(freqs)[:, :, None, :]
-        sin = jnp.sin(freqs)[:, :, None, :]
-        x1, x2 = x[..., :d2], x[..., d2:]
-        return jnp.concatenate(
-            [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-            axis=-1).astype(x.dtype)
-
-    def _attend(self, q, k_all, v_all, positions):
-        """q [S,T,H,D] vs caches [S,max_seq,KVH*D]; row (s,t) may
-        attend every column c <= positions[s,t]. Routed through the
-        ONE ``serving_cache.paged_attention`` seam by viewing the
-        dense per-slot rows as an identity-mapped block pool (a free
-        leading-dim reshape), so no engine — dense or paged — ever
-        materializes a ``[*, max_seq]`` score row (the two historical
-        ``jax.nn.softmax(scores)`` sites lived here), GQA stays a
-        grouped contraction against the UNEXPANDED caches, and the
-        Pallas kernel accelerates the dense engine too. The jnp walk
-        streams every max_seq column (all tiles) of the
-        capacity-sized dense cache; the kernel stops each slot at its
-        own length (the columns past it were masked: same numbers)."""
-        S, M = k_all.shape[0], k_all.shape[1]
-        ts = self._attend_tile
-        nb = M // ts
-        k_pool = k_all.reshape(S * nb, ts, -1)
-        v_pool = v_all.reshape(S * nb, ts, -1)
-        tables = jnp.arange(S * nb, dtype=jnp.int32).reshape(S, nb)
-        # use_kernel pinned to the __init__-time decision so the
-        # compiled programs bake exactly what _count_pa_path reports
-        # (a flag flip after construction changes neither)
-        return self._sc.paged_attention(
-            q, k_pool, v_pool, tables, positions, block_size=ts,
-            n_rep=self.n_rep, use_kernel=self._pa_kernel)
-
-    def walk_group_tokens(self, T: int = 1) -> int:
-        """Tokens the paged-attention kernel fetches and computes on a
-        loop step at this engine's shapes (``T`` rows a slot): a slot
-        at ``pos`` walks ``pos + 1`` rounded up to it."""
-        from .ops.pallas.paged_attention import group_tokens
-        ts = self._attend_tile
-        return group_tokens(
-            ts, self.cfg.num_key_value_heads * self.head_dim, self.dtype,
-            T, self.n_rep, self.max_seq // ts)
-
-    def _block(self, lp, h, kc_l, vc_l, positions, write_cols):
-        """One decoder layer over [S, T, H] with fixed-cache K/V
-        writes at write_cols [S, T]."""
-        S, T, H = h.shape
-        kvh = self.cfg.num_key_value_heads
-        res = h
-        x = self._rms(h, lp["in_ln"])
-        q = self._mm(x, lp["q_proj"]).reshape(
-            S, T, self.cfg.num_attention_heads, self.head_dim)
-        k = self._mm(x, lp["k_proj"]).reshape(S, T, kvh, self.head_dim)
-        v = self._mm(x, lp["v_proj"]).reshape(S, T, kvh, self.head_dim)
-        q = self._rope(q, positions)
-        k = self._rope(k, positions)
-        sl = jnp.arange(S)[:, None].repeat(T, 1)      # [S, T] slot ids
-        kc_l = kc_l.at[sl, write_cols].set(k.reshape(S, T, -1))
-        vc_l = vc_l.at[sl, write_cols].set(v.reshape(S, T, -1))
-        att = self._attend(q, kc_l, vc_l, positions)
-        h = res + self._mm(att.reshape(S, T, H), lp["o_proj"])
-        res = h
-        x = self._rms(h, lp["post_ln"])
-        ff = self._mm(jax.nn.silu(
-            self._mm(x, lp["gate_proj"]).astype(jnp.float32)).astype(
-                x.dtype) * self._mm(x, lp["up_proj"]),
-            lp["down_proj"])
-        return res + ff, kc_l, vc_l
-
-    def _forward(self, params, k_cache, v_cache, ids, positions):
-        """Shared prefill/decode body: ids [S, T] -> logits [S, T, V];
-        caches are per-layer lists (donated leaves, in-place)."""
-        h = jnp.take(params["emb"], ids, axis=0).astype(self.dtype)
-        new_k, new_v = [], []
-        for li, lp in enumerate(params["layers"]):
-            h, kc_l, vc_l = self._block(
-                lp, h, k_cache[li], v_cache[li], positions, positions)
-            new_k.append(kc_l)
-            new_v.append(vc_l)
-        h = self._rms(h, params["norm"])
-        logits = self._mm(h, params["head"])
-        # barrier: without it XLA fuses the [H, V] head matmul into the
-        # consumer argmax as a VPU reduce-loop fusion (measured 2.8 ms
-        # vs ~0.3 ms for the same contraction on the MXU)
-        logits = jax.lax.optimization_barrier(logits)
-        return (logits, new_k, new_v)
-
-    def _decode_impl(self, params, k_cache, v_cache, last_ids, pos):
-        """One token for every slot: ids [S,1], pos [S] = cache index
-        to write (== tokens so far)."""
-        positions = pos[:, None]                        # [S, 1]
-        logits, k_cache, v_cache = self._forward(
-            params, k_cache, v_cache, last_ids, positions)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return nxt, k_cache, v_cache
-
-    def _prefill_impl(self, params, k_cache, v_cache, ids, slot,
-                      true_len):
-        """Prompt forward for ONE slot: ids [1, B] (bucket-padded),
-        writes cache rows [0, B), returns argmax at the last real
-        token, narrowed to the one slot by slicing. Rows past
-        true_len are bucket padding: their outputs are never read and
-        their cache rows are overwritten by later decode writes
-        before any position mask can attend them, so the causal
-        positions mask alone is sufficient."""
-        B = ids.shape[1]
-        positions = jnp.arange(B)[None, :]              # [1, B]
-        kc = [jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=0)
-              for c in k_cache]
-        vc = [jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=0)
-              for c in v_cache]
-        logits, kc, vc = self._forward(params, kc, vc, ids, positions)
-        k_cache = [jax.lax.dynamic_update_slice_in_dim(c, u, slot, axis=0)
-                   for c, u in zip(k_cache, kc)]
-        v_cache = [jax.lax.dynamic_update_slice_in_dim(c, u, slot, axis=0)
-                   for c, u in zip(v_cache, vc)]
-        first = jnp.argmax(logits[0, true_len - 1, :]).astype(jnp.int32)
-        return first, k_cache, v_cache
-
-    # -- host orchestration -------------------------------------------------
-    def _bucket(self, n: int) -> int:
-        b = 8
-        while b < n:
-            b *= 2
-        return min(b, self.max_seq)
-
-    def _count_pa_path(self, n: int = 1) -> None:
-        """Per-step accounting of which implementation the
-        paged_attention seam ran — Pallas kernel vs jnp walk, decided
-        once at program-build time (``_pa_kernel``), so the counters
-        report what the compiled steps actually baked in."""
-        (_M_pa_kernel if self._pa_kernel else _M_pa_fallback).inc(n)
-
-    def prefill(self, slot: int, prompt_ids: np.ndarray) -> int:
-        """Load a prompt into ``slot``; returns the first generated
-        token (greedy)."""
-        prompt_ids = np.asarray(prompt_ids, np.int32).reshape(-1)
-        n = int(prompt_ids.shape[0])
-        if not 0 < n <= self.max_seq - 1:
-            raise ValueError(
-                f"prompt length {n} not in [1, {self.max_seq - 1}]")
-        b = self._bucket(n)
-        if b not in self._prefills:
-            self._prefills[b] = jax.jit(self._prefill_impl,
-                                        donate_argnums=(1, 2))
-        padded = np.zeros((1, b), np.int32)
-        padded[0, :n] = prompt_ids
-        first, self.k_cache, self.v_cache = self._prefills[b](
-            self.params, self.k_cache, self.v_cache, jnp.asarray(padded),
-            jnp.int32(slot), jnp.int32(n))
-        first = int(first)
-        self.pos[slot] = n
-        self.active[slot] = True
-        self.last_ids[slot, 0] = first
-        return first
-
-    def step(self) -> np.ndarray:
-        """One decode iteration for ALL slots; returns next token per
-        slot (garbage for inactive slots — callers consult .active)."""
-        with _span("serving.decode.prepare"):
-            ids = jnp.asarray(self.last_ids)
-            pos = jnp.asarray(self.pos)
-        with _span("serving.decode.enqueue"):
-            nxt, self.k_cache, self.v_cache = self._decode(
-                self.params, self.k_cache, self.v_cache, ids, pos)
-        self._count_pa_path()
-        with _span("serving.decode.fetch"):
-            nxt = np.asarray(nxt)
-        for s in range(self.max_slots):
-            if self.active[s]:
-                self.pos[s] += 1
-                self.last_ids[s, 0] = nxt[s]
-        return nxt
-
-    def _decode_collect_impl(self, params, k_cache, v_cache, last_ids,
-                             pos, buf, i):
-        """Decode step + on-device token collection (buf [S, n] donated;
-        column i written in-place)."""
-        nxt, k_cache, v_cache = self._decode_impl(
-            params, k_cache, v_cache, last_ids, pos)
-        buf = jax.lax.dynamic_update_slice(buf, nxt[:, None],
-                                           (jnp.int32(0), i))
-        return nxt, k_cache, v_cache, buf
-
-    def decode_steps(self, n: int) -> np.ndarray:
-        """``n`` chained decode iterations with DEVICE-resident token
-        feedback — dispatches pipeline asynchronously and ONE host
-        fetch closes the window. Every slot must be active; returns
-        [S, n] generated tokens.
-
-        Alternatives measured at 8 slots x 1024 ctx on a v5e before
-        PR 1 (not re-measured on today's code), all SLOWER than this
-        per-step form (989 tok/s): lax.scan-fused loop 319
-        (cache carries copy inside the while body), 8x unrolled chunks
-        672 (intermediate cache generations copy), AOT layout-AUTO
-        executables 331 (per-call relayout + AOT dispatch overhead),
-        [S,KVH,M,D] / flattened-3D cache layouts 957 / 638. The
-        residual above the weights+cache roofline is two boundary
-        layout conversions of the caches per step that XLA emits
-        regardless of shape arrangement."""
-        if not self.active.all():
-            raise ValueError(
-                "decode_steps advances EVERY slot; use step() when some "
-                "slots are free (the continuous-batching server path)")
-        if int(self.pos.max()) + n > self.max_seq - 1:
-            raise ValueError(
-                f"decode_steps({n}) would write past the {self.max_seq}"
-                f"-token cache (max pos {int(self.pos.max())}); out-of-"
-                f"bounds K/V writes are silently dropped by XLA and the "
-                f"position mask would then attend unwritten rows")
-        if self._decode_collect is None:
-            self._decode_collect = self._capture_jit(
-                self._decode_collect_impl, donate_argnums=(1, 2, 5),
-                name="serving.decode_window")
-        ids = jnp.asarray(self.last_ids)
-        pos = jnp.asarray(self.pos)
-        # tokens accumulate in ONE donated device buffer: holding a
-        # per-step list of output arrays measured 2x slower (every live
-        # buffer adds handle bookkeeping to later dispatches)
-        buf = jnp.zeros((self.max_slots, n), jnp.int32)
-        for i in range(n):
-            nxt, self.k_cache, self.v_cache, buf = self._decode_collect(
-                self.params, self.k_cache, self.v_cache, ids, pos, buf,
-                jnp.int32(i))
-            ids = nxt[:, None]
-            pos = pos + 1
-        self._count_pa_path(n)
-        toks = np.asarray(buf)                      # the one fetch
-        self.pos += n
-        self.last_ids = toks[:, -1:].astype(np.int32).copy()
-        return toks
-
-    def release(self, slot: int, evicted: bool = False) -> None:
-        """Free ``slot`` for the next admission. ``evicted`` marks a
-        reclaim (deadline expiry / failure) — meaningful on the paged
-        engine, where it feeds ``serving.block_evictions_total``;
-        the dense engine's rows are slot-owned either way."""
-        self.active[slot] = False
-        self.pos[slot] = 0
-
-    def generate(self, prompt_ids, max_new_tokens: int = 32,
-                 slot: int = 0) -> List[int]:
-        """Single-request convenience path (tests / warm-up): prefill
-        into ``slot``'s cache region — dense [max_seq] rows here,
-        freshly allocated pool blocks on the paged engine — then greedy
-        single-token steps until eos/budget/capacity."""
-        out = [self.prefill(slot, prompt_ids)]
-        for _ in range(max_new_tokens - 1):
-            if self.eos_id is not None and out[-1] == self.eos_id:
-                break
-            if self.pos[slot] >= self.max_seq - 1:
-                break
-            out.append(int(self.step()[slot]))
-        self.release(slot)
-        return out
-
-    def export_decode(self):
-        """AOT-serialize the decode step via jax.export — the StableHLO
-        artifact a serving process can run without this class (ref: the
-        reference predictor's save/load of an analyzed program). The
-        exported signature matches the live engine's cache layout:
-        dense per-layer [slots, max_seq, KVH*D] arrays here; the paged
-        engine exports its block-pool signature (pools + block tables +
-        active mask) instead."""
-        avals = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            (self.params, self.k_cache, self.v_cache,
-             jnp.asarray(self.last_ids), jnp.asarray(self.pos)))
-        exported = jax.export.export(jax.jit(self._decode_impl))(*avals)
-        return exported.serialize()
-
-
-class PagedLlamaDecodeEngine(LlamaDecodeEngine):
-    """Paged-KV decode engine: the dense engine's math (weights,
-    projections, rope, int8 matmuls) over a **block-pool cache**.
-
-    Layout: one shared pool per layer ``[num_blocks, block_size,
-    KVH*D]`` (``serving_cache.PagedKVCache``) addressed through per-slot
-    block tables, so KV HBM scales with ACTIVE tokens instead of
-    slots x max_seq. A pool is allocated, written, copied and read in
-    that one layout, the one the paged kernel's block copies read (a
-    block is one contiguous ``[block_size, KVH*D]`` slab, tiled
-    ``T(8,128)(2,1)`` on the v5e; a bf16 ``[..., KVH, 128]`` pool is
-    tiled ``T(4,128)(2,1)`` and had to be copied whole before every
-    attention call). Admission reserves a request's worst-case block
-    count (prompt + generation budget), prompt blocks are mapped
-    immediately, and decode extends one block at a time at step
-    boundaries — extension can therefore never fail mid-stream.
-
-    Prefill is CHUNKED: ``begin_request`` allocates, then
-    ``prefill_chunk`` runs at most ``FLAGS_serving_prefill_chunk``
-    prompt tokens through a bucketed executable per call, writing K/V
-    straight into the slot's blocks; the GenerationServer loop
-    interleaves one chunk with each decode step so a long prompt
-    stalls the in-flight batch by at most one chunk forward.
-
-    The decode step (``_decode_impl``, registered through
-    ``capture_jit`` with the pool pytree donated) walks each slot's
-    block list with the tiled streaming attention
-    (``serving_cache.paged_attention``) — no dense ``[S, max_seq]``
-    score or cache view is ever materialized.
-
-    ``kv_quant``: None stores blocks in the model dtype, "bfloat16"
-    halves f32 pools, "int8" stores absmax codes + per-(token, head)
-    scales (quantize.py math) dequantized per gathered tile.
-    """
-
-    paged = True
-    # process-registry prefix metrics are target-engine only; an
-    # attached draft mirrors every admission (attach_draft flips this)
-    _prefix_metrics = True
-
-    def __init__(self, model, max_slots: int = 4, max_seq: int = 256,
-                 int8: bool = False, eos_id: Optional[int] = None,
-                 block_size: Optional[int] = None,
-                 num_blocks: Optional[int] = None,
-                 kv_quant: Optional[str] = None,
-                 prefill_chunk: Optional[int] = None,
-                 num_layers: Optional[int] = None,
-                 share_params: Optional[Dict[str, object]] = None,
-                 prefix_cache: Optional[bool] = None):
-        from .core.flags import flag_value
-        self.block_size = int(block_size or
-                              flag_value("serving_block_size"))
-        mbs = -(-int(max_seq) // self.block_size)
-        auto = int(max_slots) * mbs  # dense capacity parity
-        # one pool size, or one a kind of layer ({"full": n, "window": n})
-        # for a model whose cache spec has window layers
-        self.num_blocks = dict(num_blocks) if isinstance(num_blocks, dict) \
-            else int(num_blocks or flag_value("serving_num_blocks") or auto)
-        self._prefix_cache = prefix_cache
-        if kv_quant not in (None, "bfloat16", "int8"):
-            raise ValueError(
-                f"kv_quant must be None, 'bfloat16' or 'int8', got "
-                f"{kv_quant!r}")
-        self.kv_quant = kv_quant
-        self.prefill_chunk_len = int(
-            prefill_chunk or flag_value("serving_prefill_chunk"))
-        super().__init__(model, max_slots=max_slots, max_seq=max_seq,
-                         int8=int8, eos_id=eos_id,
-                         num_layers=num_layers,
-                         share_params=share_params)
 
     def _alloc_pools(self) -> Dict[str, list]:
         """Fresh zeroed block pools (per-layer K/V + optional int8
@@ -950,74 +465,15 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                             for nb, kvh, _ in geo]
         return kv
 
-    def _init_cache(self) -> None:
-        from . import serving_cache as _sc
-        self._sc = _sc
-        kinds = sorted({sp["kind"] for sp in self.cache_spec})
-        # a model with window layers gets a table and an allocator a
-        # kind; every other model the one table it always had
-        self._kinded = kinds != ["full"]
-        if self._kinded:
-            if self.kv_quant == "int8":
-                raise NotImplementedError(
-                    "an int8 KV pool is not built for window layers")
-            given = self.num_blocks if isinstance(self.num_blocks, dict) \
-                else {}
-            # a kind with no size given holds what every slot may hold
-            # at once: max_seq in a full table, window + chunk + a block
-            # in a window table
-            self._kv = _sc.KindedKVCache(
-                self.max_slots, self.max_seq, self.block_size,
-                {k: {"num_blocks": given.get(k),
-                     "window": self.window if k == "window" else None,
-                     "window_slack": self.prefill_chunk_len}
-                 for k in kinds}, prefix_cache=self._prefix_cache)
-            self.num_blocks = {k: c.num_blocks
-                               for k, c in self._kv.kinds.items()}
-        else:
-            if isinstance(self.num_blocks, dict):
-                self.num_blocks = int(self.num_blocks["full"])
-            self._kv = _sc.PagedKVCache(
-                max_slots=self.max_slots, max_seq=self.max_seq,
-                block_size=self.block_size, num_blocks=self.num_blocks,
-                prefix_cache=self._prefix_cache)
-        self.kvs = self._alloc_pools()
-        # counts a launch hands back (a model's `aux_names`) that no
-        # fetch has read yet: a prompt chunk that is not its prompt's
-        # last is never fetched, the next fetch reads them
-        self._aux_pending: List[object] = []
-        self.last_aux: Dict[str, int] = {}
-        # the pool pytree is donated each step/chunk: K/V writes land
-        # in place in HBM, and capture_jit keeps the paged step inside
-        # captured-step accounting exactly like the dense one
-        self._decode = self._capture_jit(self._decode_impl,
-                                         donate_argnums=(1,),
-                                         name="serving.decode",
-                                         warm={"program": "decode",
-                                               **self._warm_geo()})
-        self._decode_collect = None
-        self._prefills: Dict[int, object] = {}
-        self._prefill_state: Dict[int, dict] = {}
-        self.last_chunk: Dict[str, int] = {}
-        # prefix-sharing state: the boundary copy-on-write program is
-        # built lazily (first block-aligned hit), per-request hit
-        # accounting feeds the server's req["prefix_hit_tokens"]
-        self._cow = None
-        self.prefix_hit_tokens: Dict[int, int] = {}
-
-    def _warm_geo(self) -> Dict[str, object]:
-        return {"layout": "paged", "slots": self.max_slots,
-                "max_seq": self.max_seq, "block_size": self.block_size,
-                "num_blocks": self.num_blocks,
-                "chunk": self.prefill_chunk_len}
-
     def reset_state(self) -> None:
-        """Crash-recovery reset over the block pool: every owned slot
-        is released as a counted EVICTION (its request is being
-        re-admitted or quarantined by the supervisor), staged prefills
-        are dropped, and the donated pool pytree is rebuilt as fresh
-        zeros. Compiled programs are kept — zero recompiles. An
-        attached draft resets in the same call (mirrored slots)."""
+        """Discard ALL slot and cache state — the crash-recovery seam:
+        after a decode-loop crash the donated pool buffers may be
+        mid-donation (deleted). Every owned slot is released as a
+        counted EVICTION (its request is being re-admitted or
+        quarantined by the supervisor), staged prefills are dropped, and
+        the donated pool pytree is rebuilt as fresh zeros. Compiled
+        programs are kept — zero recompiles. An attached draft resets
+        in the same call (mirrored slots)."""
         for s in range(self.max_slots):
             self._kv.release(s, evicted=True)
         # the pool pytree is about to be rebuilt as ZEROS: every
@@ -1076,44 +532,15 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                 for name, pools in kvs.items()}
 
     def walk_group_tokens(self, T: int = 1) -> int:
+        """Tokens the paged-attention kernel fetches and computes on a
+        loop step at this engine's shapes (``T`` rows a slot): a slot
+        at ``pos`` walks ``pos + 1`` rounded up to it."""
         from .ops.pallas.paged_attention import group_tokens
         return group_tokens(
             self.block_size,
             self.cache_spec[0]["kv_heads"] * self.head_dim,
             self.kvs["k"][0].dtype, T, self.n_rep,
             self._kv.max_blocks_per_slot, self.kv_quant == "int8")
-
-    def _block_paged(self, lp, h, kvl, positions, tables, n_tiles,
-                     wmask):
-        """One decoder layer over [S, T, H] with block-pool K/V writes
-        and the tiled streaming attention."""
-        S, T, H = h.shape
-        kvh = self.cfg.num_key_value_heads
-        res = h
-        x = self._rms(h, lp["in_ln"])
-        q = self._mm(x, lp["q_proj"]).reshape(
-            S, T, self.cfg.num_attention_heads, self.head_dim)
-        k = self._mm(x, lp["k_proj"]).reshape(S, T, kvh, self.head_dim)
-        v = self._mm(x, lp["v_proj"]).reshape(S, T, kvh, self.head_dim)
-        q = self._rope(q, positions)
-        k = self._rope(k, positions)
-        with jax.named_scope("paged.kv_write"):
-            kvl = self._write_kv(kvl, k, v, positions, tables, wmask)
-        with jax.named_scope("paged.attn"):
-            att = self._sc.paged_attention(
-                q, kvl["k"], kvl["v"], tables, positions,
-                block_size=self.block_size, n_rep=self.n_rep,
-                n_tiles=n_tiles, k_scale=kvl.get("ksc"),
-                v_scale=kvl.get("vsc"), use_kernel=self._pa_kernel)
-        h = res + self._mm(att.reshape(S, T, H), lp["o_proj"])
-        with jax.named_scope("paged.mlp"):
-            res = h
-            x = self._rms(h, lp["post_ln"])
-            ff = self._mm(jax.nn.silu(
-                self._mm(x, lp["gate_proj"]).astype(jnp.float32)).astype(
-                    x.dtype) * self._mm(x, lp["up_proj"]),
-                lp["down_proj"])
-            return res + ff, kvl
 
     def _forward_paged(self, params, kv, ids, positions, tables,
                        n_tiles, wmask):
@@ -1135,7 +562,9 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                 out_kv[key].append(kvl[key])
         with jax.named_scope("paged.head"):
             logits = self._m.head(self, params, h)
-            # same MXU-vs-fused-argmax barrier as the dense engine
+            # barrier: without it XLA fuses the [H, V] head matmul into
+            # the consumer argmax as a VPU reduce-loop fusion instead of
+            # running the contraction on the MXU
             logits = jax.lax.optimization_barrier(logits)
         return logits, out_kv, aux
 
@@ -1233,6 +662,19 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         return t, n_acc, kv
 
     # -- host orchestration -------------------------------------------------
+    def _bucket(self, n: int) -> int:
+        b = 8
+        while b < n:
+            b *= 2
+        return min(b, self.max_seq)
+
+    def _count_pa_path(self, n: int = 1) -> None:
+        """Per-step accounting of which implementation the
+        paged_attention seam ran — Pallas kernel vs jnp walk, decided
+        once at program-build time (``_pa_kernel``), so the counters
+        report what the compiled steps actually baked in."""
+        (_M_pa_kernel if self._pa_kernel else _M_pa_fallback).inc(n)
+
     def make_draft(self, model,
                    num_layers: Optional[int] = None
                    ) -> "PagedLlamaDecodeEngine":
@@ -1266,7 +708,7 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
                      spec_tokens: Optional[int] = None
                      ) -> "PagedLlamaDecodeEngine":
         """Enable speculative decoding: ``draft`` (a make_draft view
-        or ANY second paged engine over the same geometry) proposes
+        or ANY second engine over the same geometry) proposes
         ``spec_tokens`` (default ``FLAGS_serving_spec_tokens``) tokens
         per step; this target verifies the window in one batched
         call. Admission reserves ``spec_tokens`` extra budget per
@@ -1531,11 +973,11 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
 
     def prefill(self, slot: int, prompt_ids,
                 budget: Optional[int] = None) -> int:
-        """One-shot prefill (dense-API compat: tests / direct use):
-        admits with ``budget`` generation tokens reserved (default:
-        the worst case, max_seq - len(prompt)) and runs every chunk
-        back to back. The server path uses begin_request +
-        prefill_chunk instead to interleave with decode."""
+        """One-shot prefill (tests / direct use): admits with
+        ``budget`` generation tokens reserved (default: the worst case,
+        max_seq - len(prompt)) and runs every chunk back to back. The
+        server path uses begin_request + prefill_chunk instead to
+        interleave with decode."""
         prompt_ids = np.asarray(prompt_ids, np.int32).reshape(-1)
         n = int(prompt_ids.shape[0])
         if budget is None:
@@ -1694,10 +1136,11 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
 
     def decode_steps(self, n: int) -> np.ndarray:
         """``n`` chained decode iterations with DEVICE-resident token
-        feedback (one host fetch closes the window) — the dense
-        engine's contract over the block pool. Blocks for the whole
-        window are mapped up front so the device-side table stays
-        valid without host round-trips."""
+        feedback: dispatches pipeline asynchronously and ONE host fetch
+        closes the window. Every slot must be active; returns [S, n]
+        generated tokens. Blocks for the whole window are mapped up
+        front so the device-side table stays valid without host
+        round-trips."""
         if self._kinded or self._m.n_aux:
             raise NotImplementedError(
                 "a device-resident decode window is not built for a "
@@ -1738,7 +1181,8 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
 
     def generate(self, prompt_ids, max_new_tokens: int = 32,
                  slot: int = 0) -> List[int]:
-        """Single-request convenience path over the block pool: the
+        """Single-request convenience path (tests / warm-up): prefill,
+        then greedy single-token steps until eos/budget/capacity. The
         admission reservation is sized to ``max_new_tokens`` so a
         short request holds only its own blocks."""
         out = [self.prefill(slot, prompt_ids, budget=max_new_tokens)]
@@ -1765,7 +1209,8 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
             self._draft.release(slot, evicted=evicted)
 
     def _prewarm_entry(self, entry):
-        """Paged warm-bundle replay: decode, prefill (per recorded
+        """AOT-rebuild one recorded serving program (a warm-bundle
+        entry) over this engine's live geometry: decode, prefill (per recorded
         bucket) and — with a draft attached — the speculative
         propose/verify pair, each rebuilt AOT over the live block-pool
         geometry (``lower().compile()`` = a persistent-cache disk
@@ -1845,10 +1290,11 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
         return True
 
     def export_decode(self):
-        """AOT-serialize the PAGED decode step via jax.export: the
+        """AOT-serialize the decode step via jax.export — the StableHLO
+        artifact a serving process can run without this class (ref: the
+        reference predictor's save/load of an analyzed program). The
         signature carries the block pools, per-slot block tables and
-        the active mask, so a serving process can run the streaming
-        decode step without this class."""
+        the active mask."""
         avals = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             (self.params, self.kvs, jnp.asarray(self.last_ids),
@@ -1859,16 +1305,15 @@ class PagedLlamaDecodeEngine(LlamaDecodeEngine):
 
 
 class GenerationServer:
-    """Iteration-level continuous batching around a LlamaDecodeEngine:
-    requests are admitted into free slots at step boundaries, every
-    step advances all active requests together, finished requests free
-    their slot for the next admission — no request waits for another
-    to finish (ref role: the multi-stream request loop of the
-    reference's serving predictor).
+    """Iteration-level continuous batching around a
+    :class:`PagedLlamaDecodeEngine`: requests are admitted into free
+    slots at step boundaries, every step advances all active requests
+    together, finished requests free their slot for the next admission
+    — no request waits for another to finish (ref role: the
+    multi-stream request loop of the reference's serving predictor).
 
-    With a :class:`PagedLlamaDecodeEngine` the loop additionally
-    splits prefill from decode: admission allocates + reserves KV
-    blocks (pool exhaustion defers the request — it WAITS for blocks,
+    The loop splits prefill from decode: admission allocates + reserves
+    KV blocks (pool exhaustion defers the request — it WAITS for blocks,
     it never crashes the loop), and each iteration advances at most
     ONE prompt chunk before the decode step, so a long prompt admitted
     mid-stream costs already-decoding requests one chunk forward per
@@ -1897,12 +1342,11 @@ class GenerationServer:
 
     _STOP = object()  # queue sentinel: wake the loop for shutdown
 
-    def __init__(self, engine: LlamaDecodeEngine, policy=None):
+    def __init__(self, engine: PagedLlamaDecodeEngine, policy=None):
         self.engine = engine
-        self._paged = bool(getattr(engine, "paged", False))
         self._q: "_queue.Queue" = _queue.Queue()
         self._slots: Dict[int, dict] = {}
-        # paged engines split admission from activation: a slot in
+        # admission is split from activation: a slot in
         # _prefilling holds blocks and runs one prompt chunk per loop
         # iteration; _waiting holds admitted-order requests deferred
         # because the block pool couldn't cover their reservation yet
@@ -1971,12 +1415,12 @@ class GenerationServer:
     def _fenced(self) -> bool:
         """True on a ZOMBIE loop thread: one whose stamped epoch (set
         at its loop entry) no longer matches the server's. Mutation
-        paths the loop calls into (_admit_one/_admit_paged/
-        _run_prefill) check this before touching request dicts or the
-        slot tables, so a stalled thread that wakes mid-recovery
-        cannot double-commit tokens or register stale slots beside
-        the replacement loop. Non-loop threads (tests driving admit
-        helpers directly) carry no stamp and are never fenced."""
+        paths the loop calls into (_admit_paged/_run_prefill) check
+        this before touching request dicts or the slot tables, so a
+        stalled thread that wakes mid-recovery cannot double-commit
+        tokens or register stale slots beside the replacement loop.
+        Non-loop threads (tests driving admit helpers directly) carry
+        no stamp and are never fenced."""
         my = getattr(threading.current_thread(),
                      "_serving_loop_epoch", None)
         return my is not None and my != self._epoch
@@ -2153,7 +1597,7 @@ class GenerationServer:
         (verified by the ``framework.checkpoint`` reader), or a
         ``CheckpointManager`` (its newest good checkpoint). Weight
         prep (disk I/O + the full host->device build,
-        :meth:`~LlamaDecodeEngine.prepare_swap`) happens on THIS
+        :meth:`~PagedLlamaDecodeEngine.prepare_swap`) happens on THIS
         thread; the loop thread only validates + pointer-installs at
         its next step boundary. Same shapes/dtypes ⇒ zero recompiles;
         any mismatch raises here with the old weights intact (counted
@@ -2219,7 +1663,7 @@ class GenerationServer:
         before."""
         from .core.flags import flag_value
         bound = int(flag_value("serving_shed_queue"))
-        if not self._paged or bound <= 0:
+        if bound <= 0:
             return False
         return (self._waiting != []
                 and self._q.qsize() + len(self._waiting) > bound
@@ -2263,55 +1707,6 @@ class GenerationServer:
             # be censored and queue_seconds would stay low
             _M_queue_s.observe(dt)
 
-    def _admit_one(self, req, slot) -> None:
-        eng = self.engine
-        if req is self._STOP or req["done"].is_set():
-            return  # sentinel, or already failed while queued
-        if self._expired(req):
-            self.deadline_expired += 1
-            _M_expired.inc()
-            self._fail(req, TimeoutError(
-                "request deadline expired while queued"))
-            return
-        # stamp admission BEFORE prefill: queue_seconds is the pure
-        # submit->admission wait and decode_seconds covers prefill +
-        # decode (slow prefill must not masquerade as queueing — the
-        # load-shedding signal would point at admission when the real
-        # cost is the model). t_queue0 rebases the origin for
-        # crash-recovered requests: their pre-crash DECODE time is
-        # not admission starvation
-        req["t_admit"] = time.monotonic()
-        _M_queue_s.observe(req["t_admit"] - req.get("t_queue0",
-                                                    req["t0"]))
-        try:
-            first = eng.prefill(slot, req["prompt"])
-        except Exception as e:  # noqa: BLE001 — surfaced per request
-            if self._fenced():
-                return  # zombie: the request was already re-admitted
-            self._fail(req, e)
-            return
-        if self._fenced():
-            return  # zombie woke from a wedged prefill: the new loop
-            # owns this request — committing here would duplicate its
-            # stream and register a stale slot
-        req["out"].append(first)
-        self._slots[slot] = req
-        self.admitted += 1
-        _M_admitted.inc()
-        _flight.record("serving", "admitted",
-                       trace_id=req.get("trace_id"), slot=slot)
-        self._finish_if_done(slot, req)
-
-    def _release_slot(self, slot, evicted: bool = False) -> None:
-        """Free an engine slot on a failure/expiry path. Only paged
-        engines take the eviction marker (it feeds
-        serving.block_evictions_total); duck-typed dense engines keep
-        the bare release(slot) contract."""
-        if self._paged:
-            self.engine.release(slot, evicted=evicted)
-        else:
-            self.engine.release(slot)
-
     def _free_slots(self):
         eng = self.engine
         return [s for s in range(eng.max_slots)
@@ -2347,13 +1742,19 @@ class GenerationServer:
         if not ok:
             return "defer"
         req["t_admit"] = time.monotonic()
-        # t_queue0 = recovery rebase (see _admit_one)
+        # stamp admission BEFORE prefill: queue_seconds is the pure
+        # submit->admission wait and decode_seconds covers prefill +
+        # decode (slow prefill must not masquerade as queueing — the
+        # load-shedding signal would point at admission when the real
+        # cost is the model). t_queue0 rebases the origin for
+        # crash-recovered requests: their pre-crash DECODE time is
+        # not admission starvation
         _M_queue_s.observe(req["t_admit"] - req.get("t_queue0",
                                                     req["t0"]))
         # per-request prefix accounting: tokens this admission served
         # from shared radix blocks (0 = cold prompt), readable off the
         # finished request next to its tokens/latency (getattr:
-        # duck-typed fake engines keep the bare paged contract)
+        # duck-typed fake engines keep the bare contract)
         req["prefix_hit_tokens"] = getattr(
             eng, "prefix_hit_tokens", {}).get(slot, 0)
         self._prefilling[slot] = req
@@ -2365,31 +1766,6 @@ class GenerationServer:
         return "admitted"
 
     def _admit(self):
-        if not self._paged:
-            free = self._free_slots()
-            # supervisor-recovered requests land in _waiting (dense
-            # engines never defer on blocks, so this list is otherwise
-            # empty): admit them ahead of the queue, oldest first
-            while free and self._waiting:
-                req = self._waiting.pop(0)
-                if req["done"].is_set():
-                    continue
-                self._admit_one(req, free[0])
-                if req["done"].is_set() and req["error"] is not None:
-                    continue  # rejected before prefill: slot still free
-                free.pop(0)
-            while free:
-                try:
-                    req = self._q.get_nowait()
-                except _queue.Empty:
-                    return
-                if req is self._STOP or req["done"].is_set():
-                    continue  # sentinel, or failed while queued
-                self._admit_one(req, free[0])
-                if req["done"].is_set() and req["error"] is not None:
-                    continue  # rejected before prefill: slot still free
-                free.pop(0)
-            return
         if self._cancel_waiting:
             # shutdown(drain=False) signalled: cancel block-deferred
             # requests HERE, on the loop thread — failing them from
@@ -2451,7 +1827,7 @@ class GenerationServer:
                     if self._fenced():
                         return  # zombie: recovery owns the request now
                     del self._prefilling[slot]
-                    self._release_slot(slot, evicted=True)
+                    self.engine.release(slot, evicted=True)
                     self._fail(req, e)
                     return
                 if self._fenced():
@@ -2459,7 +1835,7 @@ class GenerationServer:
                     # nothing — the new loop re-admitted this request
                 # the turn this request got: which prompt tokens, in
                 # which bucket (getattr: duck-typed fake engines keep
-                # the bare paged contract)
+                # the bare contract)
                 chunk = dict(getattr(self.engine, "last_chunk", {}))
                 if first is not None:
                     # a model's own counts (the experts' rows), read in
@@ -2497,7 +1873,7 @@ class GenerationServer:
     def _expire_active(self):
         """Step-boundary deadline sweep over active, prefilling and
         block-waiting requests: an expired request is failed with
-        TimeoutError and its slot/blocks freed (paged blocks count as
+        TimeoutError and its slot/blocks freed (its blocks count as
         EVICTIONS — serving.block_evictions_total); tokens already
         produced stay in ``req['out']``."""
         for slot in list(self._slots):
@@ -2505,7 +1881,7 @@ class GenerationServer:
             if self._expired(req):
                 self.deadline_expired += 1
                 _M_expired.inc()
-                self._release_slot(slot, evicted=True)
+                self.engine.release(slot, evicted=True)
                 del self._slots[slot]
                 self._fail(req, TimeoutError(
                     f"request deadline expired after "
@@ -2515,7 +1891,7 @@ class GenerationServer:
             if self._expired(req):
                 self.deadline_expired += 1
                 _M_expired.inc()
-                self._release_slot(slot, evicted=True)
+                self.engine.release(slot, evicted=True)
                 del self._prefilling[slot]
                 self._fail(req, TimeoutError(
                     "request deadline expired during prefill"))
@@ -2587,8 +1963,9 @@ class GenerationServer:
         done.set()
 
     def _admit_spanned(self, admit, *args) -> None:
-        """Run one of the two admission paths as a `serving.admit` span
-        that says how many requests it admitted."""
+        """Run an admission pass (`_admit`, or `_admit_parked` from the
+        idle loop) as a `serving.admit` span that says how many requests
+        it admitted."""
         with _span("serving.admit") as span:
             before = self.admitted
             admit(*args)
@@ -2596,10 +1973,7 @@ class GenerationServer:
 
     def _admit_parked(self, req) -> None:
         """Admit the request the idle loop was parked for, directly."""
-        slot = self._free_slots()[0]
-        if not self._paged:
-            self._admit_one(req, slot)
-        elif self._admit_paged(req, slot) == "defer":
+        if self._admit_paged(req, self._free_slots()[0]) == "defer":
             self._waiting.append(req)
 
     def _launch_counts(self) -> Dict[str, int]:
@@ -2661,7 +2035,7 @@ class GenerationServer:
                         with _span("serving.swap"):
                             self._apply_pending_swap()
                     self._admit_spanned(self._admit)
-                    if self._paged and self._prefilling:
+                    if self._prefilling:
                         self._run_prefill()
                     if not self._slots:
                         if self._prefilling or self._waiting:
@@ -2700,7 +2074,7 @@ class GenerationServer:
                     # carries every in-flight request's lifecycle trail
                     _fi.fire("serving.decode")
                     eng = self.engine
-                    spec = bool(self._paged and eng.spec_ready())
+                    spec = bool(eng.spec_ready())
                     with _span("serving.decode", step=self.steps_run + 1,
                                spec=int(spec),
                                **self._launch_counts()) as dspan:
@@ -2756,11 +2130,11 @@ class GenerationServer:
                                error=type(e).__name__)
                 for slot, req in list(self._slots.items()):
                     self._fail(req, e)
-                    self._release_slot(slot, evicted=True)
+                    self.engine.release(slot, evicted=True)
                 self._slots.clear()
                 for slot, req in list(self._prefilling.items()):
                     self._fail(req, e)
-                    self._release_slot(slot, evicted=True)
+                    self.engine.release(slot, evicted=True)
                 self._prefilling.clear()
                 self._set_gauges()
         self._set_gauges()
@@ -2847,7 +2221,6 @@ class GenerationServer:
                "prefilling": len(self._prefilling),
                "waiting_for_blocks": len(self._waiting),
                "draining": int(self._stopping.is_set()),
-               "drained": int(self._drained.is_set())}
-        if self._paged:
-            out["kv_pool"] = self.engine._kv.stats()
+               "drained": int(self._drained.is_set()),
+               "kv_pool": self.engine._kv.stats()}
         return out

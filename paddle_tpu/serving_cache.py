@@ -1,16 +1,15 @@
 """Paged KV cache for generation serving: block pool, block tables,
 and the tiled block-table-gathered streaming attention step.
 
-The dense serving cache (`serving.LlamaDecodeEngine`) burns HBM
-proportional to *capacity*: every slot owns `max_seq` K/V rows per
-layer whether it holds a 4-token prompt or a full context. This module
-replaces those rows with a **shared per-layer block pool**
+A cache of `max_seq` K/V rows a slot a layer burns HBM proportional to
+*capacity*, whether a slot holds a 4-token prompt or a full context.
+This module keeps a **shared per-layer block pool**
 ``[num_blocks, block_size, KVH*D]`` (the KV heads side by side in the
 minor dimension: the layout the Pallas kernel's block copies read, so
 no launch relayouts a pool) plus per-slot **block tables**
 mapping logical block index -> physical block, so HBM scales with
-*active tokens* and a pool sized for N dense slots admits far more
-short requests (the vLLM design; here grounded in the
+*active tokens* and a pool sized for N full-length slots admits far
+more short requests (the vLLM design; here grounded in the
 FlashAttention-2/CUTLASS memory-streaming tiling of PAPERS.md).
 
 Three pieces live here, deliberately factored apart:
@@ -987,15 +986,12 @@ _KERNEL_Q_VMEM_BUDGET = 4 * 1024 * 1024
 
 
 def use_kernel_default(head_dim: int) -> bool:
-    """The seam's path decision: the Pallas block-table kernel when
-    ``FLAGS_paged_attention_kernel`` is on AND the backend and the head
-    width support it (``ops.pallas.paged_attention.kernel_available``);
-    the pure-jnp tiled walk (the numerics oracle) otherwise. One
-    function so engines can count the live path per step without
-    re-deriving the policy."""
-    from .core.flags import flag_value
-    if not flag_value("paged_attention_kernel"):
-        return False
+    """The seam's path decision, from what it can observe: the Pallas
+    block-table kernel where the backend and the head width support it
+    (``ops.pallas.paged_attention.kernel_available``: a TPU, heads of
+    whole 128-lane rows); the pure-jnp tiled walk (the numerics oracle)
+    otherwise. One function so engines can count the live path per step
+    without re-deriving the policy."""
     from .ops.pallas import paged_attention as _pk
     return _pk.kernel_available(head_dim)
 
@@ -1038,14 +1034,14 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     memory is one tile, which is what lets a Pallas TPU kernel replace
     this function behind the same signature.
 
-    GQA runs against the UNEXPANDED pools (grouped contraction, the
-    dense engine's trick): ``n_rep = H // KVH`` query heads share each
+    GQA runs against the UNEXPANDED pools (grouped contraction):
+    ``n_rep = H // KVH`` query heads share each
     KV head. ``k_scale/v_scale [num_blocks, block_size, KVH]`` switch
     the gather to int8-dequant mode (absmax codes in the pools).
 
     ``use_kernel`` selects the implementation behind this ONE seam:
-    None (default) follows ``FLAGS_paged_attention_kernel`` + backend
-    and head-width availability, True forces the Pallas TPU kernel
+    None (default) follows backend and head-width availability
+    (``use_kernel_default``), True forces the Pallas TPU kernel
     (``ops.pallas.paged_attention``), False forces the jnp walk below
     — which stays the numerics ORACLE the kernel is parity-pinned
     against (tests/test_serving_spec.py runs the kernel through the
@@ -1121,9 +1117,7 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
         # NaN/inf). Masked columns must contribute EXACTLY zero, but
         # 0 * NaN = NaN in the PV contraction below — sanitize the
         # gathered tile so one request's garbage can never leak into
-        # another request sharing the pool (the dense engine's
-        # stale rows are at worst slot-local; the pool's must be
-        # inert everywhere)
+        # another request sharing the pool
         k_t = jnp.nan_to_num(k_t)
         v_t = jnp.nan_to_num(v_t)
         s = jnp.einsum("stkrd,sbkd->skrtb", q5, k_t,

@@ -38,7 +38,7 @@ boundary):
   poison fleet-wide rather than allowed to crash-loop the fleet.
 * **Resurrection**: the dead replica is relaunched via its ``spawn``
   callable (the same executable cache + warm bundle ⇒ 0 fresh XLA
-  compiles, bench-pinned) under a bounded full-jittered exponential
+  compiles) under a bounded full-jittered exponential
   backoff; ``max_restarts`` failures degrade the fleet to the
   survivors — the router itself never crashes.
 
@@ -187,13 +187,9 @@ def health_snapshot(server) -> dict:
     sup = getattr(server, "_supervisor", None)
     gave_up = bool(getattr(sup, "gave_up", False))
     level = int(getattr(server.policy, "level", 0))
-    paged = bool(getattr(server, "_paged", False))
-    if paged:
-        kv = server.engine._kv
-        blocks_free, blocks_total = int(kv.available_blocks()), \
-            int(kv.num_blocks)
-    else:
-        blocks_free = blocks_total = -1  # dense engine: no pool gauge
+    kv = server.engine._kv
+    blocks_free, blocks_total = int(kv.available_blocks()), \
+        int(kv.num_blocks)
     backlog = int(server._q.qsize() + len(server._waiting))
     draining = bool(server._stopping.is_set())
     ok = loop_alive and not gave_up and not draining and level < 3

@@ -214,9 +214,8 @@ class AdaptiveAdmissionPolicy:
         pressure level at most ONE step, and install the brownout
         knobs on the engine. Runs on the decode-loop thread."""
         now = time.monotonic()
-        paged = getattr(server, "_paged", False)
-        total = server.engine._kv.num_blocks if paged else 0
-        avail = server.engine._kv.available_blocks() if paged else total
+        total = server.engine._kv.num_blocks
+        avail = server.engine._kv.available_blocks()
         backlog = server._q.qsize() + len(server._waiting)
         self._ewma_avail = self._mix(self._ewma_avail, avail)
         self._ewma_backlog = self._mix(self._ewma_backlog, backlog)
@@ -246,7 +245,7 @@ class AdaptiveAdmissionPolicy:
                               server.tokens_delivered)
         self._steps_seen += 1
 
-        starved = (paged and total > 0
+        starved = (total > 0
                    and self._ewma_avail <= self.starve_frac * total)
         if starved and self._ewma_backlog > self._bound():
             target = 3
@@ -297,11 +296,10 @@ class AdaptiveAdmissionPolicy:
         normal (journaled)."""
         if self.level == 0:
             return
-        paged = getattr(server, "_paged", False)
-        total = server.engine._kv.num_blocks if paged else 0
-        avail = server.engine._kv.available_blocks() if paged else 0
+        total = server.engine._kv.num_blocks
+        avail = server.engine._kv.available_blocks()
         backlog = server._q.qsize() + len(server._waiting)
-        if backlog == 0 and (not paged or total == 0
+        if backlog == 0 and (total == 0
                              or avail > self.starve_frac * total):
             self._ewma_backlog = 0.0
             self._ewma_avail = float(avail)

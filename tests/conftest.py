@@ -23,3 +23,25 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(scope="module")
+def greedy_ref(model):
+    """The serving tests' token reference, over the requesting module's
+    own ``model`` fixture: ``(prompt, n_new) -> LlamaForCausalLM.generate``'s
+    greedy stream (eager forward passes over concatenated K/V: no engine,
+    no block pool, no compiled step), memoized by prompt. A greedy stream
+    is prefix-closed, so a shorter request is cut from a longer one."""
+    import paddle_tpu as paddle
+    streams = {}
+
+    def ref(prompt, n_new):
+        key = tuple(int(t) for t in prompt)
+        if len(streams.get(key, ())) < n_new:
+            ids = paddle.to_tensor(np.asarray(key, np.int32)[None, :])
+            full = model.generate(ids, max_new_tokens=int(n_new))
+            streams[key] = [int(t) for t in
+                            np.asarray(full.numpy())[0, len(key):]]
+        return streams[key][:int(n_new)]
+
+    return ref

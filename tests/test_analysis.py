@@ -481,7 +481,7 @@ class TestSubsystemLockOrder:
         from paddle_tpu.framework.checkpoint import CheckpointManager
         from paddle_tpu.serving import GenerationServer
         from paddle_tpu.distributed.elastic import ElasticManager
-        import tests.test_observability as tob
+        from tests.test_flight import FakeEngine
 
         with alocks.instrument(long_hold_s=30.0) as aud:
             # async checkpoint: concurrent writer + reader
@@ -505,7 +505,7 @@ class TestSubsystemLockOrder:
             mgr.close()
 
             # serving: submit/drain under load
-            srv = GenerationServer(tob.FakeEngine(slots=2))
+            srv = GenerationServer(FakeEngine(slots=2))
             reqs = [srv.submit([1, 2, 3], max_new_tokens=4)
                     for _ in range(5)]
             assert srv.shutdown(drain=True, timeout=30)

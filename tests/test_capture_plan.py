@@ -614,15 +614,12 @@ class TestLlamaPlanConsistency:
 class TestRepoStepFixtures:
     def test_serving_decode_impl_is_clean(self):
         """The jitted decode/prefill bodies are the capture regions:
-        zero findings, even unallowlisted — for the dense engine AND
-        the paged one (block-table walk, streaming attention, pool
-        scatter all stay functional)."""
+        zero findings, even unallowlisted (block-table walk, streaming
+        attention, pool scatter all stay functional)."""
         import os
         from paddle_tpu.analysis.lint import REPO_ROOT
         path = os.path.join(REPO_ROOT, "paddle_tpu", "serving.py")
         for qual, params in [
-            ("LlamaDecodeEngine._decode_impl",
-             ("params", "k_cache", "v_cache", "last_ids", "pos")),
             ("PagedLlamaDecodeEngine._decode_impl",
              ("params", "kv", "last_ids", "pos", "tables", "act")),
             ("PagedLlamaDecodeEngine._prefill_impl",
@@ -639,7 +636,7 @@ class TestRepoStepFixtures:
 
     def test_serving_decode_step_clean_plan_fixture(self):
         """Checked-in expectation for the decode step/window/prefill
-        loops (dense AND paged): the ONLY raw findings are the known
+        loops: the ONLY raw findings are the known
         slot/block bookkeeping mutations (PTC002) and the designed
         per-step/window/first-token fetch (PTC003, hoisted to the
         tail) — all allowlisted, so the effective plan is clean.
@@ -648,8 +645,6 @@ class TestRepoStepFixtures:
         from paddle_tpu.analysis.lint import REPO_ROOT
         path = os.path.join(REPO_ROOT, "paddle_tpu", "serving.py")
         expected = {
-            "LlamaDecodeEngine.step": {"PTC002": 2, "PTC003": 1},
-            "LlamaDecodeEngine.decode_steps": {"PTC002": 1, "PTC003": 1},
             "PagedLlamaDecodeEngine.step": {"PTC002": 2, "PTC003": 1},
             "PagedLlamaDecodeEngine.decode_steps":
                 {"PTC002": 1, "PTC003": 1},

@@ -17,8 +17,7 @@ from paddle_tpu.models import Cohere2MoeConfig, Cohere2MoeForCausalLM
 from paddle_tpu.models import cohere2_moe as cm
 from paddle_tpu.ops.pallas import grouped_matmul as gm
 from paddle_tpu.ops.pallas import paged_attention as pk
-from paddle_tpu.serving import (GenerationServer, LlamaDecodeEngine,
-                                PagedLlamaDecodeEngine)
+from paddle_tpu.serving import GenerationServer, PagedLlamaDecodeEngine
 
 from benchmark.lib import reference_cohere2_moe as R
 from benchmark.lib import weights_cohere2_moe as W
@@ -454,8 +453,6 @@ def test_prefix_sharing_speculation_and_int8_are_refused_for_this_model():
     with pytest.raises(NotImplementedError, match="int8"):
         PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64, block_size=4,
                                prefill_chunk=8, kv_quant="int8")
-    with pytest.raises(NotImplementedError, match="dense engine"):
-        LlamaDecodeEngine(model, max_slots=2, max_seq=64)
     eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64, block_size=4,
                                  prefill_chunk=8)
     assert eng._kv.prefix_enabled is False           # off, never silently wrong
@@ -471,11 +468,11 @@ def test_prefix_sharing_speculation_and_int8_are_refused_for_this_model():
 
 def test_llama_is_the_first_user_of_the_seam_with_its_programs_names():
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.serving import _LlamaServe
+    from paddle_tpu.models.llama import LlamaServe
     paddle.seed(0)
     eng = PagedLlamaDecodeEngine(LlamaForCausalLM(LlamaConfig.tiny()),
                                  max_slots=2, max_seq=64)
-    assert isinstance(eng._m, _LlamaServe) and not eng._kinded
+    assert isinstance(eng._m, LlamaServe) and not eng._kinded
     assert eng.window is None and isinstance(eng._kv, sc.PagedKVCache)
     assert [sp["kind"] for sp in eng.cache_spec] == ["full", "full"]
     assert "window_tokens" not in eng._chunk_counts(0, 8, 8)

@@ -15,6 +15,7 @@ import paddle_tpu as paddle
 from paddle_tpu import observability as obs
 from paddle_tpu.observability import flight
 from paddle_tpu.serving import GenerationServer
+from paddle_tpu.serving_cache import PagedKVCache
 from paddle_tpu.utils import fault_injection as fi
 
 
@@ -44,8 +45,10 @@ def quiet_thread_hook():
 
 
 class FakeEngine:
-    """Duck-typed decode engine (test_observability.py pattern): enough
-    surface for GenerationServer's host orchestration, no jax."""
+    """Duck-typed decode engine: just enough surface for
+    GenerationServer's host orchestration (begin_request /
+    prefill_chunk / step / release over a real, pure-host
+    PagedKVCache), no jax."""
 
     def __init__(self, slots=2, step_sleep=0.0):
         self.max_slots = slots
@@ -54,9 +57,22 @@ class FakeEngine:
         self.step_sleep = step_sleep
         self.pos = np.zeros(slots, np.int32)
         self.active = np.zeros(slots, bool)
+        self._kv = PagedKVCache(max_slots=slots, max_seq=self.max_seq,
+                                block_size=8, num_blocks=8 * slots)
+        self._staged = {}
 
-    def prefill(self, slot, ids):
-        self.pos[slot] = len(ids)
+    def spec_ready(self):
+        return False
+
+    def begin_request(self, slot, ids, budget):
+        total = min(len(ids) + max(int(budget), 1), self.max_seq)
+        if not self._kv.admit(slot, len(ids), total):
+            return False
+        self._staged[slot] = len(ids)
+        return True
+
+    def prefill_chunk(self, slot):
+        self.pos[slot] = self._staged.pop(slot)
         self.active[slot] = True
         return 7
 
@@ -66,13 +82,16 @@ class FakeEngine:
         out = np.zeros(self.max_slots, np.int64)
         for s in range(self.max_slots):
             if self.active[s]:
+                self._kv.ensure_token(s, int(self.pos[s]))
                 self.pos[s] += 1
                 out[s] = 100 + s
         return out
 
-    def release(self, slot):
+    def release(self, slot, evicted=False):
         self.active[slot] = False
         self.pos[slot] = 0
+        self._staged.pop(slot, None)
+        self._kv.release(slot, evicted=evicted)
 
 
 # ---------------------------------------------------------------------------
@@ -360,9 +379,10 @@ class TestServingLifecycle:
             trail = srv.trace(req)  # req dict and trace_id both work
             assert trail == srv.trace(req["trace_id"])
             names = [e["name"] for e in trail]
-            assert names[:3] == ["submit", "queued", "admitted"]
+            assert names[:5] == ["submit", "queued", "admitted",
+                                 "prefill_chunk", "prefilled"]
             assert names[-1] == "finished"
-            assert names[3:-1] == ["decode"] * (len(names) - 4)
+            assert names[5:-1] == ["decode"] * (len(names) - 6)
             assert trail[-1]["attrs"]["tokens"] == 3
             # decode steps carry a monotone token count
             toks = [e["attrs"]["tokens"] for e in trail

@@ -385,21 +385,6 @@ class TestBundleFaults:
         out3 = warmup.prewarm(bundle, engine=chunky)
         assert out3["programs"] >= 2 and out3["failures"] == 0
 
-    def test_dense_vs_paged_layout_is_stale(self):
-        """A paged replica's bundle into a dense engine (or vice
-        versa) is a LAYOUT mismatch, not warmth."""
-        from paddle_tpu.serving import LlamaDecodeEngine
-        warmup.clear_recorded()
-        mA = _model_a()
-        dense = LlamaDecodeEngine(mA, max_slots=2, max_seq=128)
-        dense.generate([1, 2], max_new_tokens=3)
-        bundle = warmup.load_bundle(warmup.export_bundle())
-        paged = PagedLlamaDecodeEngine(mA, **GEO)
-        before = self._reason_count("stale")
-        out = warmup.prewarm(bundle, engine=paged)
-        assert out["programs"] == 0
-        assert self._reason_count("stale") > before
-
 
 # ---------------------------------------------------------------------------
 # cache-dir GC by last-hit age

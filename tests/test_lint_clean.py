@@ -40,7 +40,7 @@ def test_suppressions_are_justified():
 
 def test_repo_step_functions_capture_clean():
     """The static capture pass over the package's own step functions
-    (hapi train/eval batch, serving decode step, the bench step): a new
+    (hapi train/eval batch, serving decode step): a new
     unallowlisted PTC diagnostic — a fresh graph break landing in a
     step path — fails CI here, exactly like a lint violation."""
     from paddle_tpu.analysis.capture import scan_repo_steps

@@ -419,35 +419,6 @@ class TestStepTimer:
 # cross-subsystem integration: one snapshot carries everything
 # ---------------------------------------------------------------------------
 
-class FakeEngine:
-    """Duck-typed decode engine: just enough surface for
-    GenerationServer's host orchestration (no jax compiles)."""
-
-    def __init__(self, slots=2):
-        self.max_slots = slots
-        self.max_seq = 64
-        self.eos_id = None
-        self.pos = np.zeros(slots, np.int32)
-        self.active = np.zeros(slots, bool)
-
-    def prefill(self, slot, ids):
-        self.pos[slot] = len(ids)
-        self.active[slot] = True
-        return 7
-
-    def step(self):
-        out = np.zeros(self.max_slots, np.int64)
-        for s in range(self.max_slots):
-            if self.active[s]:
-                self.pos[s] += 1
-                out[s] = 100 + s
-        return out
-
-    def release(self, slot):
-        self.active[slot] = False
-        self.pos[slot] = 0
-
-
 class TestIntegration:
     def test_dispatch_metrics_move(self):
         snap0 = obs.snapshot()["dispatch"]
@@ -487,6 +458,7 @@ class TestIntegration:
 
     def test_serving_metrics_and_endpoint(self):
         from paddle_tpu.serving import GenerationServer
+        from tests.test_flight import FakeEngine  # jax-free, duck-typed
         before = obs.snapshot()["serving"]
         srv = GenerationServer(FakeEngine())
         try:

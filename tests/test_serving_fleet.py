@@ -44,7 +44,7 @@ from paddle_tpu.serving_fleet import (FleetRouter, FleetSaturated,
                                       launch_replica)
 from paddle_tpu.utils import fault_injection as fi
 
-from test_serving_supervisor import CFG, FakeCausalEngine, FakePagedEngine
+from test_serving_supervisor import CFG, FakeCausalEngine
 
 FLEET_TERMINAL = {"finished", "failed", "shed"}
 
@@ -271,9 +271,9 @@ class TestPlacement:
         starved_dispatched) for 6 short requests."""
         servers, replicas, handles = [], [], []
         for i in range(3):
-            eng = FakePagedEngine(slots=2, max_seq=64, block_size=8,
-                                  num_blocks=(6 if i == 0 else 32),
-                                  step_sleep=0.01)
+            eng = FakeCausalEngine(slots=2, max_seq=64, block_size=8,
+                                   num_blocks=(6 if i == 0 else 32),
+                                   step_sleep=0.01)
             srv, rs, h = _mk_replica(i, eng, policy=StubLevelPolicy(0))
             servers.append(srv)
             replicas.append(rs)
@@ -372,15 +372,15 @@ class TestHealthz:
         try:
             snap = health_snapshot(srv)
             assert snap["ok"] and snap["loop_alive"]
-            assert snap["blocks_total"] == -1  # dense: no pool gauge
-            paged = GenerationServer(
-                FakePagedEngine(slots=2, max_seq=64, num_blocks=8))
+            assert snap["blocks_total"] == snap["blocks_free"] == 16
+            small = GenerationServer(
+                FakeCausalEngine(slots=2, max_seq=64, num_blocks=8))
             try:
-                psnap = health_snapshot(paged)
+                psnap = health_snapshot(small)
                 assert psnap["blocks_total"] == 8
                 assert psnap["blocks_free"] == 8
             finally:
-                paged.shutdown(drain=False, timeout=5)
+                small.shutdown(drain=False, timeout=5)
         finally:
             srv.shutdown(drain=False, timeout=5)
 

@@ -1,7 +1,10 @@
 """Generation serving: the compiled fixed-slot decode engine and the
-continuous-batching server (VERDICT r4 #4: "serving == generation").
+continuous-batching server (VERDICT r4 #4: "serving == generation"),
+at the engine's default block and chunk sizes.
 Oracle = LlamaForCausalLM.generate (the parity KV-cache path); the
-engine's static-cache decode must produce the same greedy tokens.
+engine's paged decode must produce the same greedy tokens. Slot
+independence, slot reuse and the AOT export at this geometry are cases
+of test_serving_paged.py's tests of the same names.
 ref role: analysis_predictor.h + fused_multi_transformer_op.cu."""
 import threading
 
@@ -10,7 +13,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.serving import GenerationServer, LlamaDecodeEngine
+from paddle_tpu.serving import GenerationServer, PagedLlamaDecodeEngine
 
 CFG = dict(vocab_size=64, hidden_size=32, intermediate_size=64,
            num_hidden_layers=2, num_attention_heads=4,
@@ -31,58 +34,20 @@ def _oracle(model, prompt, n_new):
 
 class TestDecodeEngine:
     def test_single_request_matches_generate_oracle(self, model):
-        eng = LlamaDecodeEngine(model, max_slots=2, max_seq=64)
+        eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64)
         prompt = [5, 9, 11, 3]
         got = eng.generate(prompt, max_new_tokens=8)
         assert got == _oracle(model, prompt, 8)
 
-    def test_slots_are_independent(self, model):
-        """Two interleaved requests in different slots produce exactly
-        their single-request sequences (no cache cross-talk)."""
-        eng = LlamaDecodeEngine(model, max_slots=2, max_seq=64)
-        p0, p1 = [1, 2, 3], [40, 41, 42, 43, 44]
-        o0 = [eng.prefill(0, p0)]
-        o1 = [eng.prefill(1, p1)]
-        for _ in range(5):
-            nxt = eng.step()
-            o0.append(int(nxt[0]))
-            o1.append(int(nxt[1]))
-        assert o0 == _oracle(model, p0, 6)
-        assert o1 == _oracle(model, p1, 6)
-
-    def test_slot_reuse_after_release(self, model):
-        eng = LlamaDecodeEngine(model, max_slots=1, max_seq=64)
-        a = eng.generate([7, 8], max_new_tokens=4)
-        b = eng.generate([7, 8], max_new_tokens=4)
-        assert a == b  # stale cache rows must not leak into reuse
-
     def test_int8_engine_decodes(self, model):
         """int8 path: real s8 matmuls end-to-end; tokens are valid and
         deterministic, and the first-step logits stay close to fp."""
-        eng8 = LlamaDecodeEngine(model, max_slots=1, max_seq=64,
-                                 int8=True)
+        eng8 = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=64,
+                                      int8=True)
         out = eng8.generate([5, 9, 11], max_new_tokens=6)
         assert len(out) == 6
         assert all(0 <= t < CFG["vocab_size"] for t in out)
         assert out == eng8.generate([5, 9, 11], max_new_tokens=6)
-
-    def test_export_decode_roundtrip(self, model):
-        """AOT export: the serialized decode step runs without the
-        engine class and matches the live step (ref: the predictor's
-        self-contained analyzed program)."""
-        import jax
-        import jax.numpy as jnp
-
-        eng = LlamaDecodeEngine(model, max_slots=2, max_seq=32)
-        eng.prefill(0, [3, 4, 5])
-        blob = eng.export_decode()
-        assert isinstance(blob, (bytes, bytearray)) and len(blob) > 0
-        rebuilt = jax.export.deserialize(bytearray(blob))
-        args = (eng.params, eng.k_cache, eng.v_cache,
-                jnp.asarray(eng.last_ids), jnp.asarray(eng.pos))
-        nxt_aot, _, _ = rebuilt.call(*args)
-        nxt_live, _, _ = jax.jit(eng._decode_impl)(*args)
-        assert int(nxt_aot[0]) == int(nxt_live[0])
 
 
 class TestContinuousBatching:
@@ -90,7 +55,7 @@ class TestContinuousBatching:
         """Three concurrent requests over two slots: every result
         matches its oracle, and the shared decode loop runs FEWER
         steps than serial execution would (iteration-level batching)."""
-        eng = LlamaDecodeEngine(model, max_slots=2, max_seq=64)
+        eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64)
         srv = GenerationServer(eng)
         jobs = [([1, 2, 3], 8), ([40, 41], 5), ([7, 9, 2, 4], 6)]
         results = {}
@@ -114,7 +79,7 @@ class TestContinuousBatching:
     def test_late_request_joins_running_batch(self, model):
         """A request submitted mid-flight is admitted at a step
         boundary and still matches its oracle."""
-        eng = LlamaDecodeEngine(model, max_slots=2, max_seq=64)
+        eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64)
         srv = GenerationServer(eng)
         first = srv.submit([1, 2, 3], 12)
         # wait until the loop is actually decoding, then join
@@ -131,8 +96,8 @@ class TestContinuousBatching:
     def test_eos_stops_generation(self, model):
         # find the greedy first token for the prompt and use it as eos
         eos = _oracle(model, [5, 9, 11, 3], 1)[0]
-        eng = LlamaDecodeEngine(model, max_slots=1, max_seq=64,
-                                eos_id=int(eos))
+        eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=64,
+                                     eos_id=int(eos))
         srv = GenerationServer(eng)
         out = srv.generate([5, 9, 11, 3], 10, timeout=120)
         assert out == [eos]
@@ -185,7 +150,7 @@ class TestServeGenerateEndpoint:
 
 class TestServingErrorPaths:
     def test_overlong_prompt_fails_loudly(self, model):
-        eng = LlamaDecodeEngine(model, max_slots=1, max_seq=16)
+        eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=16)
         srv = GenerationServer(eng)
         with pytest.raises(ValueError, match="prompt length"):
             srv.generate(list(range(40)), 4, timeout=60)
@@ -194,16 +159,16 @@ class TestServingErrorPaths:
         assert out == _oracle(model, [1, 2, 3], 2)
 
     def test_decode_steps_guards(self, model):
-        eng = LlamaDecodeEngine(model, max_slots=2, max_seq=32)
+        eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=32)
         with pytest.raises(ValueError, match="EVERY slot"):
             eng.decode_steps(2)          # no slot active
         eng.prefill(0, [1, 2, 3])
         eng.prefill(1, [4, 5])
-        with pytest.raises(ValueError, match="cache"):
+        with pytest.raises(ValueError, match="capacity"):
             eng.decode_steps(64)         # would run past max_seq
 
     def test_submit_rejects_nonpositive_budget(self, model):
-        eng = LlamaDecodeEngine(model, max_slots=1, max_seq=32)
+        eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=32)
         srv = GenerationServer(eng)
         with pytest.raises(ValueError, match="max_new_tokens"):
             srv.submit([1, 2], 0)
@@ -216,7 +181,7 @@ class TestDeadlinesAndDrain:
         """Requests in flight (and already queued) when shutdown starts
         run to completion with their full oracle token streams — no
         completed token is dropped; new submissions are rejected."""
-        eng = LlamaDecodeEngine(model, max_slots=2, max_seq=64)
+        eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64)
         srv = GenerationServer(eng)
         reqs = [srv.submit([1, 2, 3], 10), srv.submit([40, 41], 8),
                 srv.submit([7, 9, 2], 6)]  # 3rd waits queued
@@ -238,7 +203,7 @@ class TestDeadlinesAndDrain:
 
     def test_shutdown_no_drain_cancels_queued(self, model):
         import time
-        eng = LlamaDecodeEngine(model, max_slots=1, max_seq=64)
+        eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=64)
         orig_step = eng.step
 
         def slow_step():  # hold the slot long enough that the queue
@@ -268,7 +233,7 @@ class TestDeadlinesAndDrain:
         """A request whose deadline passes while it waits in the queue
         fails with TimeoutError without consuming a slot."""
         import time
-        eng = LlamaDecodeEngine(model, max_slots=1, max_seq=64)
+        eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=64)
         orig_step = eng.step
 
         def slow_step():  # hold the slot past the queued deadline on
@@ -292,7 +257,7 @@ class TestDeadlinesAndDrain:
         """An active request that exceeds its deadline is failed at a
         step boundary but keeps the tokens it already produced."""
         import time
-        eng = LlamaDecodeEngine(model, max_slots=1, max_seq=256)
+        eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=256)
         orig_step = eng.step
 
         def slow_step():  # pin step cost so the deadline bites on any

@@ -1,17 +1,13 @@
 """Paged KV cache serving (ISSUE 11): block-pool allocator invariants,
-paged-vs-dense decode equivalence across bucketed prompt lengths,
-chunked prefill/decode interleave under GenerationServer, exhaustion
-and eviction accounting, and the captured paged decode step's
-0-host-sync steady state.
+decode equivalence with the model's own greedy stream across bucketed
+prompt lengths, chunked prefill/decode interleave under
+GenerationServer, exhaustion and eviction accounting, and the captured
+decode step's 0-host-sync steady state.
 
-Oracle strategy: the dense LlamaDecodeEngine (itself pinned against
-LlamaForCausalLM.generate in test_serving_generation.py) is the token
-reference — the paged engine must reproduce its greedy streams
-exactly, with HBM proportional to active tokens instead of
-slots x max_seq. Reference streams are computed once per prompt on a
-module-scoped dense engine (the hapi-generate oracle costs seconds
-per request; the compiled dense engine costs milliseconds and is
-transitively oracle-pinned).
+Oracle strategy: ``greedy_ref`` (tests/conftest.py) is
+``LlamaForCausalLM.generate``, a forward pass independent of the engine;
+the engine must reproduce its greedy streams exactly. Streams are
+memoized by prompt for the module.
 """
 import threading
 import time
@@ -21,8 +17,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.serving import (GenerationServer, LlamaDecodeEngine,
-                                PagedLlamaDecodeEngine)
+from paddle_tpu.serving import GenerationServer, PagedLlamaDecodeEngine
 from paddle_tpu.serving_cache import PagedKVCache
 
 CFG = dict(vocab_size=64, hidden_size=32, intermediate_size=64,
@@ -37,27 +32,21 @@ def model():
 
 
 @pytest.fixture(scope="module")
-def dense_ref(model):
-    """Module-scoped dense reference engine + memoized greedy streams
-    (max_seq 256 so no reference stream ever truncates early)."""
-    eng = LlamaDecodeEngine(model, max_slots=1, max_seq=256)
-    cache = {}
-
-    def ref(prompt, n_new):
-        key = (tuple(int(t) for t in prompt), int(n_new))
-        if key not in cache:
-            cache[key] = eng.generate(list(key[0]), max_new_tokens=n_new)
-        return cache[key]
-
-    return ref
-
-
-@pytest.fixture(scope="module")
 def paged64(model):
     """Shared paged engine (2 slots, max_seq 64, 8-token blocks and
     prefill chunks); tests release every slot they touch."""
     return PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64,
                                   block_size=8, prefill_chunk=8)
+
+
+@pytest.fixture(params=["block8_chunk8", "defaults"])
+def eng64(request, model):
+    """``paged64``, or the engine at its default block and chunk sizes
+    (16 and 64: the geometry test_serving_generation.py serves at),
+    both 2 slots of 64 positions."""
+    if request.param == "block8_chunk8":
+        return request.getfixturevalue("paged64")
+    return PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64)
 
 
 def _wait_steps(srv, n, tries=400):
@@ -68,37 +57,37 @@ def _wait_steps(srv, n, tries=400):
     return False
 
 
-class TestPagedVsDense:
+class TestPagedVsModel:
     def test_bit_equivalence_across_bucketed_prompt_lengths(
-            self, model, dense_ref, paged64):
-        """Paged greedy streams match the dense engine token-for-token
+            self, model, greedy_ref, paged64):
+        """Paged greedy streams match the model's own token-for-token
         for prompts spanning the prefill buckets (3 -> one sub-chunk
         bucket, 30 -> four 8-token chunks crossing block boundaries)."""
         for prompt in ([5, 9, 11, 3], [2], [1, 2, 3, 4, 5, 6, 7, 8],
                        list(range(1, 14)), list(range(3, 33))):
-            want = dense_ref(prompt, 12)
+            want = greedy_ref(prompt, 12)
             got = paged64.generate(prompt, max_new_tokens=12)
             assert got == want, (len(prompt), got, want)
         # every request released its blocks + reservation
         st = paged64._kv.stats()
         assert st["blocks_used"] == 0 and st["blocks_reserved"] == 0
 
-    def test_slots_are_independent(self, dense_ref, paged64):
+    def test_slots_are_independent(self, greedy_ref, eng64):
         """Interleaved slots over a SHARED block pool produce exactly
         their single-request sequences (no cross-slot block leaks)."""
         p0, p1 = [1, 2, 3], [40, 41, 42, 43, 44]
-        o0 = [paged64.prefill(0, p0, budget=8)]
-        o1 = [paged64.prefill(1, p1, budget=8)]
+        o0 = [eng64.prefill(0, p0, budget=8)]
+        o1 = [eng64.prefill(1, p1, budget=8)]
         for _ in range(5):
-            nxt = paged64.step()
+            nxt = eng64.step()
             o0.append(int(nxt[0]))
             o1.append(int(nxt[1]))
-        paged64.release(0)
-        paged64.release(1)
-        assert o0 == dense_ref(p0, 6)
-        assert o1 == dense_ref(p1, 6)
+        eng64.release(0)
+        eng64.release(1)
+        assert o0 == greedy_ref(p0, 6)
+        assert o1 == greedy_ref(p1, 6)
 
-    def test_decode_window_matches_dense(self, dense_ref, paged64):
+    def test_decode_window_matches_the_model(self, greedy_ref, paged64):
         """decode_steps (device-resident token feedback, one fetch per
         window) over the block pool continues each slot's reference
         stream, with the window's blocks pre-mapped so the device
@@ -109,15 +98,15 @@ class TestPagedVsDense:
         toks = paged64.decode_steps(6)
         paged64.release(0)
         paged64.release(1)
-        assert list(toks[0]) == dense_ref(p0, 7)[1:]
-        assert list(toks[1]) == dense_ref(p1, 7)[1:]
+        assert list(toks[0]) == greedy_ref(p0, 7)[1:]
+        assert list(toks[1]) == greedy_ref(p1, 7)[1:]
 
-    def test_slot_reuse_after_release(self, paged64):
-        a = paged64.generate([7, 8], max_new_tokens=4)
-        b = paged64.generate([7, 8], max_new_tokens=4)
+    def test_slot_reuse_after_release(self, eng64):
+        a = eng64.generate([7, 8], max_new_tokens=4)
+        b = eng64.generate([7, 8], max_new_tokens=4)
         assert a == b  # recycled blocks must not leak stale K/V
 
-    def test_recycled_block_garbage_is_inert(self, dense_ref, paged64):
+    def test_recycled_block_garbage_is_inert(self, greedy_ref, paged64):
         """Blocks recycled from a pathological request (activations
         driven to NaN/inf write non-finite K/V) must be invisible to
         the next request sharing the pool: masked columns contribute
@@ -131,13 +120,13 @@ class TestPagedVsDense:
                             for a in paged64.kvs["v"]]
         prompt = [5, 9, 11, 3]
         assert paged64.generate(prompt, max_new_tokens=12) == \
-            dense_ref(prompt, 12)
+            greedy_ref(prompt, 12)
 
-    def test_quantized_kv_blocks(self, model, dense_ref):
+    def test_quantized_kv_blocks(self, model, greedy_ref):
         """bf16 pools on an f32 model and int8 absmax pools both
         decode deterministically; int8 stays close to the exact
         stream early on (same-first-token sanity)."""
-        want = dense_ref([5, 9, 11], 6)
+        want = greedy_ref([5, 9, 11], 6)
         for quant in ("bfloat16", "int8"):
             eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=64,
                                          block_size=16, kv_quant=quant)
@@ -147,14 +136,18 @@ class TestPagedVsDense:
             assert out == eng.generate([5, 9, 11], max_new_tokens=6)
             assert out[0] == want[0], (quant, out, want)
 
-    def test_export_decode_roundtrip(self, model):
-        """The paged decode step AOT-exports with its block-pool
-        signature and the artifact matches the live step."""
+    @pytest.mark.parametrize("block_size", [8, None],
+                             ids=["block8", "default_block"])
+    def test_export_decode_roundtrip(self, model, block_size):
+        """The decode step AOT-exports with its block-pool signature:
+        the serialized step runs without the engine class and matches
+        the live step (ref: the predictor's self-contained analyzed
+        program)."""
         import jax
         import jax.numpy as jnp
 
         eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=32,
-                                     block_size=8)
+                                     block_size=block_size)
         eng.prefill(0, [3, 4, 5], budget=8)
         blob = eng.export_decode()
         assert isinstance(blob, (bytes, bytearray)) and len(blob) > 0
@@ -297,7 +290,7 @@ class TestBlockAllocator:
 
 
 class TestServerInterleave:
-    def test_concurrent_requests_share_pool(self, model, dense_ref):
+    def test_concurrent_requests_share_pool(self, model, greedy_ref):
         eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64,
                                      block_size=8, prefill_chunk=8)
         srv = GenerationServer(eng)
@@ -314,12 +307,12 @@ class TestServerInterleave:
         for t in ts:
             t.join(timeout=180)
         for i, (p, n) in enumerate(jobs):
-            assert results[i] == dense_ref(p, n), i
+            assert results[i] == greedy_ref(p, n), i
         assert srv.admitted == 3
         assert srv.shutdown(drain=True, timeout=120)
         assert srv.stats()["kv_pool"]["blocks_used"] == 0
 
-    def test_pool_exhaustion_queues_not_crashes(self, model, dense_ref):
+    def test_pool_exhaustion_queues_not_crashes(self, model, greedy_ref):
         """More requests than the pool covers: the overflow WAITS for
         blocks (never a loop crash), is admitted as earlier requests
         release, and every stream still matches its oracle."""
@@ -331,12 +324,12 @@ class TestServerInterleave:
         for r in reqs:
             assert r["done"].wait(120), srv.stats()
             assert r["error"] is None, r["error"]
-            assert list(r["out"]) == dense_ref([1, 2, 3, 4, 5, 6, 7], 8)
+            assert list(r["out"]) == greedy_ref([1, 2, 3, 4, 5, 6, 7], 8)
         st = srv.stats()
         assert st["kv_pool"]["blocks_used"] == 0
         assert srv.shutdown(drain=True, timeout=60)
 
-    def test_deferred_request_is_not_starved(self, model, dense_ref):
+    def test_deferred_request_is_not_starved(self, model, greedy_ref):
         """Head-of-line fairness: while a large request waits for
         blocks, newer small requests must NOT be admitted past it and
         re-consume every freed block — the deferred request admits
@@ -361,11 +354,11 @@ class TestServerInterleave:
         # the big request was admitted BEFORE the later small one
         assert big["t_admit"] < small_c["t_admit"], (
             big["t_admit"], small_c["t_admit"])
-        assert list(big["out"]) == dense_ref(list(range(1, 17)), 15)
+        assert list(big["out"]) == greedy_ref(list(range(1, 17)), 15)
         srv.shutdown()
 
     def test_drain_shutdown_with_prefill_in_flight(self, model,
-                                                   dense_ref):
+                                                   greedy_ref):
         """Drain during a chunked prefill: the half-prefilled long
         prompt AND everything queued complete with full oracle
         streams before the loop exits."""
@@ -383,11 +376,11 @@ class TestServerInterleave:
                             (queued, ([7, 9, 2], 5))):
             assert req["done"].is_set()
             assert req["error"] is None, req["error"]
-            assert list(req["out"]) == dense_ref(p, n)
+            assert list(req["out"]) == greedy_ref(p, n)
         assert srv.stats()["kv_pool"]["blocks_used"] == 0
 
     def test_expired_requests_return_blocks_as_evictions(self, model,
-                                                         dense_ref):
+                                                         greedy_ref):
         """Deadline expiry — waiting-for-blocks OR active — frees the
         blocks and counts them into block_evictions_total."""
         eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=64,
@@ -419,7 +412,7 @@ class TestServerInterleave:
         assert eng._kv.stats()["blocks_used"] == 0
         # pool recovered: a fresh request still serves
         assert srv.generate([1, 2, 3], 2, timeout=60) == \
-            dense_ref([1, 2, 3], 2)
+            greedy_ref([1, 2, 3], 2)
         srv.shutdown()
 
     @pytest.mark.slow
@@ -457,7 +450,7 @@ class TestPagedCapture:
     def test_paged_decode_step_audits_zero_syncs(self, model):
         """The captured paged decode step runs 0 host syncs in steady
         state and counts into sot.captured_steps_total (capture_jit
-        accounting), like the dense step it replaces."""
+        accounting)."""
         import jax.numpy as jnp
         from paddle_tpu import analysis
         from paddle_tpu.observability import metrics as om

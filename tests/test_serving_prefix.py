@@ -9,8 +9,8 @@ aliased prefixes produce BIT-equal greedy streams (the whole point —
 sharing must be invisible in the tokens), including over speculative
 decode's accept/rollback and across a crash-recovery ``reset_state``.
 
-Oracle strategy mirrors test_serving_paged.py: the module-scoped dense
-engine (transitively pinned against hapi generate) provides memoized
+Oracle strategy mirrors test_serving_paged.py: ``greedy_ref``
+(tests/conftest.py: the model's own ``generate``) provides memoized
 reference streams; prefix-cache-off engines re-derive the SAME streams
 so on/off equality is a three-way pin.
 """
@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.serving import LlamaDecodeEngine, PagedLlamaDecodeEngine
+from paddle_tpu.serving import PagedLlamaDecodeEngine
 from paddle_tpu.serving_cache import PagedKVCache
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
@@ -31,20 +31,6 @@ CFG = dict(vocab_size=64, hidden_size=32, intermediate_size=64,
 def model():
     paddle.seed(7)
     return LlamaForCausalLM(LlamaConfig.tiny(**CFG))
-
-
-@pytest.fixture(scope="module")
-def dense_ref(model):
-    eng = LlamaDecodeEngine(model, max_slots=1, max_seq=256)
-    cache = {}
-
-    def ref(prompt, n_new):
-        key = (tuple(int(t) for t in prompt), int(n_new))
-        if key not in cache:
-            cache[key] = eng.generate(list(key[0]), max_new_tokens=n_new)
-        return cache[key]
-
-    return ref
 
 
 def _invariants(kv):
@@ -297,10 +283,10 @@ class TestPrefixCacheFlagOff:
             st["blocks_free"] - st["blocks_reserved"]
 
     @pytest.mark.slow  # ~6s: compiles two engines (flag on AND off)
-    def test_flag_off_streams_match_flag_on(self, model, dense_ref):
+    def test_flag_off_streams_match_flag_on(self, model, greedy_ref):
         """Engine-level pin BOTH ways: repeated shared-prefix prompts
         produce identical greedy streams with the prefix cache on and
-        off, and both equal the dense oracle."""
+        off, and both equal the model's own stream."""
         prev = paddle.get_flags(["FLAGS_serving_prefix_cache"])
         paddle.set_flags({"FLAGS_serving_prefix_cache": 0})
         try:
@@ -320,7 +306,7 @@ class TestPrefixCacheFlagOff:
         got_on = [on.generate(p, max_new_tokens=8) for p in prompts]
         assert on._kv.stats()["prefix_hits"] >= 1
         for p, a, b in zip(prompts, got_off, got_on):
-            want = dense_ref(p, 8)
+            want = greedy_ref(p, 8)
             assert a == want and b == want, (p, a, b, want)
 
 
@@ -337,10 +323,10 @@ def prefix_eng(model):
 
 
 class TestPrefixEngineBitEquality:
-    def test_cow_boundary_bit_equal_vs_dense_oracle(
-            self, model, dense_ref, prefix_eng):
+    def test_cow_boundary_bit_equal_vs_the_models_stream(
+            self, model, greedy_ref, prefix_eng):
         """Cold miss, full block-aligned hit (COW boundary clone) and
-        partial hit all reproduce the dense stream exactly, while the
+        partial hit all reproduce the model's stream exactly, while the
         hit/reuse counters prove sharing actually happened."""
         from paddle_tpu.observability import flight
 
@@ -348,7 +334,7 @@ class TestPrefixEngineBitEquality:
         P = list(range(3, 19))                       # 2 full blocks
         st0 = eng._kv.stats()
         cold = eng.generate(P, max_new_tokens=10)
-        assert cold == dense_ref(P, 10)
+        assert cold == greedy_ref(P, 10)
         assert eng._kv.stats()["prefix_hits"] == st0["prefix_hits"]
         # full hit: n-1 tokens skip prefill, boundary block COW-cloned
         hot = eng.generate(P, max_new_tokens=10)
@@ -361,19 +347,19 @@ class TestPrefixEngineBitEquality:
         assert "prefix_hit" in names and "prefix_cow" in names
         # partial hit: shared head, divergent tail
         Q = P[:8] + [50, 51, 52, 53]
-        assert eng.generate(Q, max_new_tokens=10) == dense_ref(Q, 10)
+        assert eng.generate(Q, max_new_tokens=10) == greedy_ref(Q, 10)
         assert eng._kv.stats()["prefix_hits"] == st1["prefix_hits"] + 1
         _invariants(eng._kv)
         assert eng._kv.stats()["blocks_used"] == 0
 
-    def test_interleaved_sharers_and_metrics(self, model, dense_ref,
+    def test_interleaved_sharers_and_metrics(self, model, greedy_ref,
                                              prefix_eng):
         """Two LIVE slots aliasing one cached prefix decode
         interleaved without cross-talk, and the per-request
         prefix_hit_tokens record survives until release."""
         eng = prefix_eng
         P = list(range(3, 19))
-        dense_ref(P, 6)                              # warm the oracle
+        greedy_ref(P, 6)                              # warm the oracle
         eng.generate(P, max_new_tokens=4)            # seed the tree
         o0 = [eng.prefill(0, P, budget=8)]
         o1 = [eng.prefill(1, P, budget=8)]
@@ -387,23 +373,23 @@ class TestPrefixEngineBitEquality:
         eng.release(0)
         eng.release(1)
         assert 0 not in eng.prefix_hit_tokens
-        want = dense_ref(P, 6)
+        want = greedy_ref(P, 6)
         assert o0 == want and o1 == want
         _invariants(eng._kv)
 
     @pytest.mark.slow  # ~5s: compiles a fresh engine + draft spec tree
-    def test_spec_rollback_over_shared_prefix(self, model, dense_ref):
+    def test_spec_rollback_over_shared_prefix(self, model, greedy_ref):
         """Speculative decode over an aliased prefix: the draft pool
         mirrors the admission (its own radix tree), windows
         accept/roll back across the shared boundary, and the
-        committed stream still matches the dense oracle bit-for-bit
+        committed stream still matches the model's bit-for-bit
         with both pools' invariants intact after every window."""
         eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64,
                                      block_size=8, prefill_chunk=8)
         eng.attach_draft(eng.make_draft(model, num_layers=1),
                          spec_tokens=3)
         P = list(range(3, 19))
-        want = dense_ref(P, 12)
+        want = greedy_ref(P, 12)
         assert eng.generate(P, max_new_tokens=12) == want  # cold
         out = [eng.prefill(0, P, budget=16)]         # hot: prefix hit
         assert eng.prefix_hit_tokens[0] == 15
@@ -420,7 +406,7 @@ class TestPrefixEngineBitEquality:
         _invariants(eng._kv)
         _invariants(eng._draft._kv)
 
-    def test_reset_state_chaos_mid_prefill(self, model, dense_ref,
+    def test_reset_state_chaos_mid_prefill(self, model, greedy_ref,
                                            prefix_eng):
         """Crash recovery with a warm tree, a live sharer AND a
         mid-prefill staged request: reset_state drops the radix cache
@@ -447,8 +433,8 @@ class TestPrefixEngineBitEquality:
         # the tree is gone: the next request is a cold miss that
         # re-seeds it, and the stream is still exact
         st0 = eng._kv.stats()["prefix_hits"]
-        assert eng.generate(P, max_new_tokens=6) == dense_ref(P, 6)
+        assert eng.generate(P, max_new_tokens=6) == greedy_ref(P, 6)
         assert eng._kv.stats()["prefix_hits"] == st0
-        assert eng.generate(P, max_new_tokens=6) == dense_ref(P, 6)
+        assert eng.generate(P, max_new_tokens=6) == greedy_ref(P, 6)
         assert eng._kv.stats()["prefix_hits"] == st0 + 1
         _invariants(eng._kv)

@@ -3,9 +3,9 @@ paged-attention kernel seam (ISSUE 12).
 
 Oracle strategy, in two layers:
 
-- TOKENS: the non-speculative paged engine (itself pinned against the
-  dense engine, transitively against LlamaForCausalLM.generate) is the
-  stream reference — greedy speculative decode must reproduce it
+- TOKENS: the non-speculative paged engine (itself pinned against
+  LlamaForCausalLM.generate in test_serving_paged.py) is the stream
+  reference — greedy speculative decode must reproduce it
   BIT-exactly, because every committed token conditions on a committed
   prefix (the accept rule). A 1-of-2-layer random draft disagrees with
   its target constantly, so these streams exercise rejection mid-window
@@ -22,8 +22,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.serving import (GenerationServer, LlamaDecodeEngine,
-                                PagedLlamaDecodeEngine)
+from paddle_tpu.serving import GenerationServer, PagedLlamaDecodeEngine
 from paddle_tpu.serving_cache import PagedKVCache
 
 CFG = dict(vocab_size=64, hidden_size=32, intermediate_size=64,
@@ -485,7 +484,7 @@ class TestPagedAttentionKernelSeam:
                 ((16, 512, bf16, 64, 8, 128, False), 32),  # Yi chunk
                 ((16, 1024, bf16, 1, 4, 128, False), 32),  # Mistral
                 ((16, 512, i8, 1, 8, 128, True), 32),      # int8 pool
-                ((128, 512, bf16, 1, 8, 16, False), 4),    # dense tile
+                ((128, 512, bf16, 1, 8, 16, False), 4),    # 128-token blocks
                 ((16, 512, bf16, 1, 8, 3, False), 3),      # short table
                 ((8, 512, bf16, 1, 8, 128, False), 1),     # half a tile
                 ((4, 512, f32, 1, 8, 128, False), 1),
@@ -533,16 +532,14 @@ class TestPagedAttentionKernelSeam:
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                    rtol=1e-6, atol=1e-6)
 
-    def test_seam_chooses_by_platform_flag_and_head_width(self,
-                                                          monkeypatch):
+    def test_seam_chooses_by_platform_and_head_width(self, monkeypatch):
         """The seam's choice, from what it can observe. On a TPU the
         kernel runs where head_dim fills whole 128-lane registers and
         the jnp walk runs below that — Mosaic refuses the kernel's
         [bs, KVH*D] -> [bs, KVH, D] view at head_dim 16/32/64 (rule
         found by compiling against a v5e topology; see
-        ops.pallas.paged_attention.kernel_available). Off the TPU, or
-        with FLAGS_paged_attention_kernel=0, always the walk. The walk
-        taken is counted."""
+        ops.pallas.paged_attention.kernel_available). Off the TPU,
+        always the walk. The walk taken is counted."""
         import jax
         import jax.numpy as jnp
         from paddle_tpu import serving_cache as sc
@@ -552,11 +549,6 @@ class TestPagedAttentionKernelSeam:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert [d for d in (16, 32, 64, 128, 256)
                 if sc.use_kernel_default(d)] == [128, 256]
-        paddle.set_flags({"FLAGS_paged_attention_kernel": 0})
-        try:
-            assert sc.use_kernel_default(128) is False
-        finally:
-            paddle.set_flags({"FLAGS_paged_attention_kernel": 1})
         # a tiny-width engine on the "TPU" takes the walk, and says so
         walk = om.default_registry().get("pallas.path_selected_total")
         before = walk.value(kernel="paged_attention", path="jnp_walk")
@@ -608,26 +600,6 @@ class TestJaxprPins:
 
         walk(jaxpr.jaxpr)
         return shapes
-
-    def test_dense_decode_no_trailing_max_seq_intermediate(self,
-                                                           model):
-        """Satellite pin: routing the dense engine's attention through
-        the paged_attention seam removed the [*, max_seq]-trailing
-        score rows (and the col_mask) from the dense decode step —
-        the cache arrays themselves keep max_seq at axis 1, which is
-        the dense layout's contract, so the pin is on the TRAILING
-        axis where score rows and masks lived."""
-        import jax
-        import jax.numpy as jnp
-
-        max_seq = 48
-        eng = LlamaDecodeEngine(model, max_slots=3, max_seq=max_seq)
-        args = (eng.params, eng.k_cache, eng.v_cache,
-                jnp.asarray(eng.last_ids), jnp.asarray(eng.pos))
-        jaxpr = jax.make_jaxpr(eng._decode_impl)(*args)
-        offenders = [(p, s) for p, s in self._walk_shapes(jaxpr)
-                     if s and s[-1] == max_seq]
-        assert offenders == [], offenders
 
     def test_spec_verify_no_dense_view(self, model, spec_eng):
         """The batched verify step obeys the same pin as the decode

@@ -13,8 +13,8 @@ instead of crash-looping the replica; the adaptive policy brownouts
 when pressure clears; and a divergent checkpoint rolled onto a canary
 is auto-rolled-back bit-equal while the rollout halts.
 
-Cost discipline: the oracle streams are memoized on a module-scoped
-dense engine, most chaos mechanics run on jax-free fake engines (the
+Cost discipline: the oracle streams are memoized (``greedy_ref``,
+tests/conftest.py), most chaos mechanics run on jax-free fake engines (the
 test_flight FakeEngine pattern, made causal-LM-faithful: the next
 token is a pure function of the WHOLE sequence so far, so re-prefill
 resumes exactly like the real engines), and only the bit-equality
@@ -32,8 +32,7 @@ import paddle_tpu as paddle
 from paddle_tpu import observability as obs
 from paddle_tpu.observability import flight
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-from paddle_tpu.serving import (GenerationServer, LlamaDecodeEngine,
-                                PagedLlamaDecodeEngine)
+from paddle_tpu.serving import GenerationServer, PagedLlamaDecodeEngine
 from paddle_tpu.serving_cache import PagedKVCache
 from paddle_tpu.serving_supervisor import (AdaptiveAdmissionPolicy,
                                            RolloutPolicy,
@@ -64,21 +63,6 @@ def model():
 def model_b():
     paddle.seed(23)
     return LlamaForCausalLM(LlamaConfig.tiny(**CFG))
-
-
-@pytest.fixture(scope="module")
-def dense_ref(model):
-    """Memoized greedy oracle streams (the uninterrupted reference)."""
-    eng = LlamaDecodeEngine(model, max_slots=1, max_seq=64)
-    cache = {}
-
-    def ref(prompt, n_new):
-        key = (tuple(int(t) for t in prompt), int(n_new))
-        if key not in cache:
-            cache[key] = eng.generate(list(key[0]), max_new_tokens=n_new)
-        return cache[key]
-
-    return ref
 
 
 @pytest.fixture(scope="module")
@@ -113,70 +97,32 @@ def quiet_thread_hook():
 
 class FakeCausalEngine:
     """jax-free duck-typed engine whose next token is a pure function
-    of the WHOLE token sequence so far — prefill(prompt + committed)
-    therefore resumes exactly like the real causal engines, which is
-    the property crash recovery leans on."""
+    of the WHOLE token sequence so far — a request re-admitted with
+    prompt + committed tokens therefore resumes exactly like the real
+    causal engine, which is the property crash recovery leans on. It
+    sits over a REAL PagedKVCache (pure host), so the
+    adaptive-admission evidence (blocks_free/reservations) and the
+    server's path (begin_request/prefill_chunk/defer) are all genuine
+    — without a single compile. ``num_blocks=None`` sizes the pool so
+    that admission never defers."""
 
-    def __init__(self, slots=2, max_seq=64, step_sleep=0.0):
+    def __init__(self, slots=2, max_seq=64, step_sleep=0.0, block_size=8,
+                 num_blocks=None):
         self.max_slots, self.max_seq, self.eos_id = slots, max_seq, None
         self.step_sleep = step_sleep
         self.active = np.zeros(slots, bool)
         self.pos = np.zeros(slots, np.int64)
         self._seq = {}
+        self._kv = PagedKVCache(
+            max_slots=slots, max_seq=max_seq, block_size=block_size,
+            num_blocks=num_blocks or slots * -(-max_seq // block_size))
+        self._prefill_state = {}
+        self._spec_suppressed = False
+        self._chunk_cap = None
 
     @staticmethod
     def _next(seq):
         return (sum(seq) * 7 + len(seq)) % 997
-
-    def prefill(self, slot, prompt):
-        seq = [int(t) for t in np.asarray(prompt).reshape(-1)]
-        tok = self._next(seq)
-        self._seq[slot] = seq + [tok]
-        self.pos[slot] = len(self._seq[slot])
-        self.active[slot] = True
-        return tok
-
-    def step(self):
-        if self.step_sleep:
-            time.sleep(self.step_sleep)
-        out = np.zeros(self.max_slots, np.int64)
-        for s in range(self.max_slots):
-            if self.active[s]:
-                tok = self._next(self._seq[s])
-                self._seq[s].append(tok)
-                self.pos[s] += 1
-                out[s] = tok
-        return out
-
-    def release(self, slot, evicted=False):
-        self.active[slot] = False
-        self.pos[slot] = 0
-        self._seq.pop(slot, None)
-
-    def reset_state(self):
-        self.active[:] = False
-        self.pos[:] = 0
-        self._seq.clear()
-
-
-class FakePagedEngine(FakeCausalEngine):
-    """The causal fake over a REAL PagedKVCache (pure host), so the
-    adaptive-admission evidence (blocks_free/reservations) and the
-    paged server path (begin_request/prefill_chunk/defer) are all
-    genuine — without a single compile."""
-
-    paged = True
-
-    def __init__(self, slots=2, max_seq=64, block_size=8, num_blocks=8,
-                 step_sleep=0.0):
-        super().__init__(slots=slots, max_seq=max_seq,
-                         step_sleep=step_sleep)
-        self._kv = PagedKVCache(max_slots=slots, max_seq=max_seq,
-                                block_size=block_size,
-                                num_blocks=num_blocks)
-        self._prefill_state = {}
-        self._spec_suppressed = False
-        self._chunk_cap = None
 
     def spec_ready(self):
         return False  # no draft on the fake
@@ -199,13 +145,22 @@ class FakePagedEngine(FakeCausalEngine):
         return tok
 
     def step(self):
+        if self.step_sleep:
+            time.sleep(self.step_sleep)
+        out = np.zeros(self.max_slots, np.int64)
         for s in range(self.max_slots):
             if self.active[s]:
                 self._kv.ensure_token(s, int(self.pos[s]))
-        return super().step()
+                tok = self._next(self._seq[s])
+                self._seq[s].append(tok)
+                self.pos[s] += 1
+                out[s] = tok
+        return out
 
     def release(self, slot, evicted=False):
-        super().release(slot, evicted=evicted)
+        self.active[slot] = False
+        self.pos[slot] = 0
+        self._seq.pop(slot, None)
         self._prefill_state.pop(slot, None)
         self._kv.release(slot, evicted=evicted)
 
@@ -213,7 +168,9 @@ class FakePagedEngine(FakeCausalEngine):
         for s in range(self.max_slots):
             self._kv.release(s, evicted=True)
         self._prefill_state.clear()
-        super().reset_state()
+        self.active[:] = False
+        self.pos[:] = 0
+        self._seq.clear()
 
 
 def _terminal_counts(trace_ids):
@@ -229,7 +186,7 @@ def _terminal_counts(trace_ids):
 # ---------------------------------------------------------------------------
 
 class TestCrashRecovery:
-    def test_chaos_killpoint_recovers_bit_equal(self, model, dense_ref,
+    def test_chaos_killpoint_recovers_bit_equal(self, model, greedy_ref,
                                                 paged64, dump_dir):
         """The acceptance chaos scenario on the REAL paged engine:
         KillPoint mid-decode under concurrent submits — the supervisor
@@ -251,7 +208,7 @@ class TestCrashRecovery:
             for req, prompt, n in reqs:
                 assert req["done"].wait(60), srv.stats()
                 assert req["error"] is None
-                assert list(req["out"]) == dense_ref(prompt, n)
+                assert list(req["out"]) == greedy_ref(prompt, n)
             assert sup.restarts == 1
             assert sup.recovered >= 1 and sup.quarantined == 0
             counts = _terminal_counts([r["trace_id"]
@@ -267,7 +224,7 @@ class TestCrashRecovery:
             # the replica is healthy: pool pristine, a fresh request
             # serves the oracle stream
             assert srv.generate([6, 2], max_new_tokens=4,
-                                timeout=60) == dense_ref([6, 2], 4)
+                                timeout=60) == greedy_ref([6, 2], 4)
         finally:
             fi.clear("serving.decode")
             sup.stop()
@@ -464,7 +421,7 @@ class TestAdaptiveAdmission:
         # (starved at the 0.4 threshold but NOT exhausted — shedding
         # engages before the pool runs dry), the second defers, the
         # rest queue behind it
-        eng = FakePagedEngine(num_blocks=8, step_sleep=0.002)
+        eng = FakeCausalEngine(num_blocks=8, step_sleep=0.002)
         srv = GenerationServer(eng, policy=policy)
         try:
             a = srv.submit([1, 2, 3, 4], max_new_tokens=40)
@@ -510,7 +467,7 @@ class TestAdaptiveAdmission:
         it burns blocks; a meetable one is admitted."""
         flight.clear()
         policy = AdaptiveAdmissionPolicy(alpha=0.9, min_steps=3)
-        eng = FakePagedEngine(num_blocks=32, step_sleep=0.02)
+        eng = FakeCausalEngine(num_blocks=32, step_sleep=0.02)
         srv = GenerationServer(eng, policy=policy)
         try:
             # warm the throughput EWMA with a real stream (~50 tok/s

@@ -407,9 +407,9 @@ class TestFlightAndMetrics:
         routes through capture_jit: steady-state decode counts as
         captured steps."""
         from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-        from paddle_tpu.serving import LlamaDecodeEngine
+        from paddle_tpu.serving import PagedLlamaDecodeEngine
         paddle.seed(0)
-        eng = LlamaDecodeEngine(
+        eng = PagedLlamaDecodeEngine(
             LlamaForCausalLM(LlamaConfig.tiny()), max_slots=2,
             max_seq=32)
         eng.prefill(0, np.array([1, 2, 3], np.int32))

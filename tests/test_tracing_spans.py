@@ -23,8 +23,7 @@ from paddle_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
                                      LlamaPretrainingCriterion)
 from paddle_tpu.observability import clock, flight, timeline
 from paddle_tpu.profiler import Profiler, RecordEvent
-from paddle_tpu.serving import (GenerationServer, LlamaDecodeEngine,
-                                PagedLlamaDecodeEngine)
+from paddle_tpu.serving import GenerationServer, PagedLlamaDecodeEngine
 
 CFG = dict(vocab_size=64, hidden_size=32, intermediate_size=64,
            num_hidden_layers=2, num_attention_heads=4,
@@ -315,21 +314,6 @@ def test_served_tokens_are_the_same_with_and_without_a_trace(model, traced):
         srv.shutdown()
     assert plain == traced["outs"]
     assert all(len(o) == NEW for o in plain)
-
-
-def test_dense_engine_step_has_the_same_three_children(model, tmp_path):
-    eng = LlamaDecodeEngine(model, max_slots=1, max_seq=32)
-    eng.prefill(0, np.asarray([3, 1, 4], np.int32))
-    eng.step()
-    _trace(tmp_path)
-    try:
-        with RecordEvent("t26.dense"):
-            eng.step()
-    finally:
-        jax.profiler.stop_trace()
-    names = [s[2] for s in _host_spans(str(tmp_path))]
-    assert names == ["t26.dense", "serving.decode.prepare",
-                     "serving.decode.enqueue", "serving.decode.fetch"]
 
 
 # -- the primitive ---------------------------------------------------------

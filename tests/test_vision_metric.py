@@ -37,7 +37,7 @@ def test_resnet_state_dict_structure():
 
 
 def test_resnet_nhwc_matches_nchw():
-    """data_format='NHWC' (the TPU-native conv layout used by bench.py)
+    """data_format='NHWC' (the TPU-native conv layout)
     must be numerically identical to NCHW — same weights, transposed
     input/activations only."""
     x = np.random.default_rng(0).standard_normal((2, 3, 64, 64)).astype(
