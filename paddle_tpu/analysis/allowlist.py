@@ -174,11 +174,15 @@ CAPTURE_ALLOWLIST = [
      "block writes) advances BETWEEN captured programs by design: "
      "the jitted _decode_impl, the _prefill_impl "
      "chunks and the spec propose/verify pair are the capture "
-     "regions, the server loop is the boundary that replays them"),
+     "regions, the server loop is the boundary that replays them — "
+     "pos where a launch is enqueued (step_enqueue/prefill_enqueue), "
+     "last_ids where its tokens are fetched (step_collect/"
+     "prefill_collect), one launch later"),
     ("PTC003", "paddle_tpu/serving.py*",
      "the per-step/per-window token fetch and the final-prefill-chunk "
      "first-token fetch ARE the decode contract: continuous batching "
-     "must see each token on host to admit/retire requests; "
+     "must see each token on host to deliver it and to see an EOS "
+     "(step_collect/prefill_collect, a launch behind the enqueue); "
      "decode_steps batches it to one fetch per window and a "
      "speculative step fetches ONCE for up to spec_k committed "
      "tokens (the verify outputs drive accept/rollback)"),

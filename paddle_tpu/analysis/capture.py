@@ -675,10 +675,18 @@ REPO_STEPS: List[Tuple[str, str, Tuple[str, ...]]] = [
     ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine._prefill_impl",
      ("params", "kv", "ids", "table_row", "start", "nvalid",
       "true_len")),
-    ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine.step", ()),
+    # the decode iteration and the prompt chunk each as their two
+    # halves (ISSUE 31): the serving loop enqueues launch n+1 before it
+    # collects launch n, `step` / `prefill_chunk` are one after the other
+    ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine.step_enqueue",
+     ()),
+    ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine.step_collect",
+     ()),
     ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine.decode_steps",
      ()),
-    ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine.prefill_chunk",
+    ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine.prefill_enqueue",
+     ()),
+    ("paddle_tpu/serving.py", "PagedLlamaDecodeEngine.prefill_collect",
      ()),
     # prefix-sharing admission (ISSUE 16): the radix match/alias/COW
     # decision runs host-side at admission — begin_request is the

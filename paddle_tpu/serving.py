@@ -26,10 +26,18 @@ the chunk length. Speculation adds ``serving.spec_draft`` /
 
 **Chunking** is the engine's (``begin_request`` reserves blocks,
 ``prefill_chunk`` runs at most ``FLAGS_serving_prefill_chunk`` tokens a call);
-**the loop** is ``GenerationServer._loop``: admit, one prompt chunk, one
-decode step for all active slots, commit, sweep."""
+**the loop** is ``GenerationServer._loop``: admit, enqueue one prompt chunk
+and the decode launch for all active slots, then fetch and commit the launch
+BEFORE it, sweep. The loop keeps one launch ahead of its fetch: a decode
+iteration and a prompt chunk each come as two halves (``step_enqueue`` /
+``step_collect``, ``prefill_enqueue`` / ``prefill_collect``), the tokens a
+launch leaves on the device are the next launch's ``last_ids`` there
+(``_next_ids``), and what can be known by counting (``pos``, ``max_new``,
+the tables) is decided at enqueue. Only an EOS needs the token: it is seen
+one launch late, and that launch's token for its slot is dropped."""
 from __future__ import annotations
 
+import functools
 import itertools
 import queue as _queue
 import threading
@@ -137,6 +145,14 @@ _M_prefix_reused = _M.counter(
     "Prompt tokens served from shared prefix blocks instead of "
     "being re-prefilled (the prefill work the radix cache saved)")
 
+# the loop keeps one launch ahead of its fetch, so a request that ends
+# on a token (an EOS) or on the clock (a deadline) has a token or two
+# enqueued that nobody will read
+_M_overrun = _M.counter(
+    "overrun_tokens_total",
+    "Tokens launched for a request and dropped because it had ended "
+    "(EOS, deadline, failure) by the time they were fetched")
+
 # process-unique request trace ids: every lifecycle event of a request
 # carries one, so a flight dump (or GenerationServer.trace) replays a
 # single request's submit -> queued -> admitted -> decode -> terminal
@@ -146,6 +162,29 @@ _REQ_SEQ = itertools.count(1)
 # 0-d int32 aval for pre-warm lowers: matches the jnp.int32(...) args
 # the live host orchestration passes, without compiling anything
 _I32 = jax.ShapeDtypeStruct((), np.int32)
+
+
+def _feed_impl(last_ids, out, act):
+    """``last_ids`` [S, 1] for the launch after one that is not fetched
+    yet: the rows it stepped (``act``) read its tokens where they are,
+    the head of ``out``; the others keep the host's."""
+    return jnp.where(act[:, None], out[:last_ids.shape[0], None], last_ids)
+
+
+def _feed_first_impl(last_ids, tok, slot):
+    """``last_ids`` with ``slot``'s row set to the first token that its
+    prompt's last chunk left on the device (the head of ``tok``)."""
+    return jax.lax.dynamic_update_slice(
+        last_ids, tok.reshape(-1)[:1, None], (slot, jnp.int32(0)))
+
+
+@functools.lru_cache(maxsize=None)
+def _feed_programs():
+    """The two token-feedback programs (``jit_serving_feed*`` in a
+    device trace), one pair for every engine of the process."""
+    from .jit.sot import jit_named
+    return (jit_named(_feed_impl, "serving.feed"),
+            jit_named(_feed_first_impl, "serving.feed_first"))
 
 
 class PagedLlamaDecodeEngine:
@@ -319,9 +358,23 @@ class PagedLlamaDecodeEngine:
         self.kvs = self._alloc_pools()
         # counts a launch hands back (a model's `aux_names`) that no
         # fetch has read yet: a prompt chunk that is not its prompt's
-        # last is never fetched, the next fetch reads them
+        # last is never fetched, the next launch that is reads them
         self._aux_pending: List[object] = []
         self.last_aux: Dict[str, int] = {}
+        # what is enqueued and not fetched (the serving loop keeps one
+        # launch ahead of its fetch): the newest decode launch, whose
+        # tokens the next launch reads on the device, and the first
+        # token of each prompt whose last chunk no launch has read yet.
+        # With neither, the host's last_ids is all there is
+        self._ahead: Optional[dict] = None
+        self._first_dev: Dict[int, object] = {}
+        self._feed, self._feed_first = _feed_programs()
+        # how many times each slot has been activated: a launch keeps
+        # the numbers it stepped, and its collect writes last_ids only
+        # where the slot still holds that activation (a slot released
+        # and re-activated while the launch was in flight is another
+        # request's now)
+        self._activation = np.zeros(self.max_slots, np.int64)
         # the pool pytree is donated each step/chunk: K/V writes land
         # in place in HBM. The jitted step is registered as a CAPTURED
         # step program (jit.sot.capture_jit): its clean capture plan is
@@ -483,6 +536,9 @@ class PagedLlamaDecodeEngine:
         self._kv.reset_prefix_cache()
         self.prefix_hit_tokens.clear()
         self._prefill_state.clear()
+        self._ahead = None
+        self._first_dev.clear()
+        self._aux_pending = []
         self.pos[:] = 0
         self.active[:] = False
         self.last_ids[:] = 0
@@ -766,40 +822,47 @@ class PagedLlamaDecodeEngine:
     def _tables_dev(self, slot: Optional[int] = None):
         """The block table(s) as a launch takes them: the one array, or
         one a kind of layer; ``slot`` narrows to that slot's row."""
+        # a table is rewritten in place between launches (a block
+        # mapped for the next token, entries freed behind the window):
+        # each launch gets a snapshot, since jnp.asarray may alias host
+        # memory that a launch still in flight reads
         def dev(t):
-            return jnp.asarray(t if slot is None else t[slot])
+            return jnp.asarray((t if slot is None else t[slot]).copy())
         bt = self._kv.block_tables
         if not self._kinded:
             return dev(bt)
-        # a window table is rewritten in place between launches (entries
-        # freed behind the window): each launch gets a snapshot, since
-        # jnp.asarray may alias host memory that a launch still in
-        # flight reads (a prompt chunk is not waited for)
-        return {k: dev(t.copy()) for k, t in bt.items()}
+        return {k: dev(t) for k, t in bt.items()}
 
     def _defer_aux(self, result) -> None:
         """Keep an unfetched launch's result (a prompt chunk that is not
-        its prompt's last: nobody waits for it) for the next fetch to
-        read its counts from."""
+        its prompt's last: nobody waits for it) for the next launch that
+        is fetched to read its counts from."""
         if self._m.n_aux:
             self._aux_pending.append(result)
 
-    def _take_aux(self, fetched: np.ndarray) -> np.ndarray:
+    def _unfetched_aux(self) -> List[object]:
+        """Hand the launches nobody fetches, enqueued so far, to the one
+        being enqueued: its fetch reads their counts, and waits for
+        nothing that was enqueued after it."""
+        taken, self._aux_pending = self._aux_pending, []
+        return taken
+
+    def _take_aux(self, fetched: np.ndarray, unfetched):
         """A fetched launch result's token(s), without the model's
-        counts that ride behind them (``_with_aux``). The counts of this
-        launch and of every launch since the last fetch become
-        ``last_aux`` (``aux_names`` summed, ``moe_launches`` how many
-        launches they cover)."""
+        counts that ride behind them (``_with_aux``), and those counts:
+        this launch's and the ``unfetched`` launches' before it
+        (``aux_names`` summed, ``moe_launches`` how many launches they
+        cover), which ``last_aux`` keeps too. ``{}`` for a model with
+        none."""
         n = self._m.n_aux
         if not n:
-            return fetched
+            return fetched, {}
         tot = fetched[-n:].astype(np.int64)
-        for pending in self._aux_pending:
+        for pending in unfetched:
             tot = tot + np.asarray(pending)[-n:]
         self.last_aux = dict(zip(self._m.aux_names, (int(x) for x in tot)),
-                             moe_launches=len(self._aux_pending) + 1)
-        self._aux_pending.clear()
-        return fetched[:-n]
+                             moe_launches=len(unfetched) + 1)
+        return fetched[:-n], self.last_aux
 
     def _chunk_counts(self, start: int, tokens: int, bucket: int) -> dict:
         """What a prompt chunk's turn did, for the loop's span and
@@ -910,10 +973,13 @@ class PagedLlamaDecodeEngine:
                       **self._warm_geo()})
         return self._prefills[b]
 
-    def prefill_chunk(self, slot: int) -> Optional[int]:
-        """Run the next prompt chunk for ``slot``. Returns None while
-        prefill is incomplete; on the final chunk, activates the slot
-        and returns the first generated token (greedy)."""
+    def prefill_enqueue(self, slot: int) -> Optional[dict]:
+        """Enqueue the next prompt chunk for ``slot`` and wait for
+        nothing. Returns None while prefill is incomplete. On the final
+        chunk the slot is active from the next decode launch on, which
+        reads the first generated token (greedy) where the chunk left
+        it, on the device; what comes back is that token's handle for
+        ``prefill_collect``."""
         st = self._prefill_state[slot]
         ids, start = st["ids"], st["next"]
         n = int(ids.shape[0])
@@ -946,30 +1012,56 @@ class PagedLlamaDecodeEngine:
         # its tail (content-identical blocks dedupe against existing
         # nodes, remapping the table to the cached copy)
         self._kv.commit_prefix(slot, ids, st["next"])
+        draft = self._draft
         if st["next"] < n:
             # draft prefill rides the same interleave budget: one
             # draft chunk per target chunk (same chunk length — a
             # make_draft view — finishes in lockstep; an arbitrary
             # second engine catches up on the final chunk below)
-            if self._draft is not None \
-                    and slot in self._draft._prefill_state:
-                self._draft.prefill_chunk(slot)
+            if draft is not None and slot in draft._prefill_state:
+                draft.prefill_enqueue(slot)
             self._defer_aux(tok)
             return None
-        with _span("serving.prefill.fetch"):
-            first = int(self._take_aux(np.asarray(tok)).reshape(-1)[0])
+        first = {"slot": slot, "tok": tok, "aux": self._unfetched_aux()}
         del self._prefill_state[slot]
         self.pos[slot] = n
         self.active[slot] = True
-        self.last_ids[slot, 0] = first
-        if self._draft is not None:
-            while slot in self._draft._prefill_state:
-                self._draft.prefill_chunk(slot)
+        self._activation[slot] += 1
+        self._first_dev[slot] = tok
+        if draft is not None:
+            while slot in draft._prefill_state:
+                draft.prefill_enqueue(slot)
             # the draft's stream mirrors the TARGET's: its own
             # prefill token is discarded, the target's first token
             # seeds both engines' next step
-            self._draft.last_ids[slot, 0] = first
+            draft._first_dev.pop(slot, None)
         return first
+
+    def prefill_collect(self, first: dict):
+        """Fetch the first token that ``prefill_enqueue`` left on the
+        device; returns it and the model's counts that its fetch brings
+        (``_take_aux``). The host's ``last_ids`` takes it unless a
+        decode launch has read it there already: that launch's collect
+        writes what follows it."""
+        slot = first["slot"]
+        with _span("serving.prefill.fetch"):
+            toks, counts = self._take_aux(np.asarray(first["tok"]),
+                                          first["aux"])
+            tok = int(toks.reshape(-1)[0])
+        if self._first_dev.get(slot) is first["tok"]:
+            del self._first_dev[slot]
+            self.last_ids[slot, 0] = tok
+            if self._draft is not None:
+                self._draft.last_ids[slot, 0] = tok
+        return tok, counts
+
+    def prefill_chunk(self, slot: int) -> Optional[int]:
+        """Run the next prompt chunk for ``slot``. Returns None while
+        prefill is incomplete; on the final chunk, activates the slot
+        and returns the first generated token (greedy): the two halves
+        above, one after the other."""
+        first = self.prefill_enqueue(slot)
+        return None if first is None else self.prefill_collect(first)[0]
 
     def prefill(self, slot: int, prompt_ids,
                 budget: Optional[int] = None) -> int:
@@ -1001,21 +1093,37 @@ class PagedLlamaDecodeEngine:
                 self._shared_write_guard(s)
                 self._kv.ensure_token(s, int(self.pos[s]))
 
-    def step(self) -> np.ndarray:
-        """One decode iteration for ALL active slots; returns next
-        token per slot (garbage for inactive slots — callers consult
-        .active). With a draft attached, the draft runs a mirrored
-        (cheap, truncated-layer) step on the SAME inputs so its KV
-        cache stays complete — a plain-step iteration (capacity
-        fallback, direct use) must not punch holes in the draft's
-        history, or every later speculation window would propose from
-        garbage and acceptance would silently collapse."""
+    def _next_ids(self):
+        """``last_ids`` as the next decode launch reads them: the host's,
+        and over them what only the device has yet: the tokens of the
+        launch that is not fetched, and the first tokens of prompts
+        whose last chunk no launch has read (each is read here once; the
+        launch's own tokens carry it on from there)."""
+        ids = jnp.asarray(self.last_ids.copy())
+        if self._ahead is not None:
+            ids = self._feed(ids, self._ahead["out"], self._ahead["act"])
+        for slot in [s for s in self._first_dev if self.active[s]]:
+            ids = self._feed_first(ids, self._first_dev.pop(slot),
+                                   np.int32(slot))
+        return ids
+
+    def step_enqueue(self) -> dict:
+        """The first half of a decode iteration: extend the tables,
+        upload, and enqueue one token for ALL active slots; ``pos``
+        advances here. Nothing is waited for: the launch's tokens stay
+        on the device, where the next ``step_enqueue`` reads them, until
+        ``step_collect`` fetches them. With a draft attached, the draft
+        runs a mirrored (cheap, truncated-layer) step on the SAME
+        inputs so its KV cache stays complete — a plain-step iteration
+        (capacity fallback, direct use) must not punch holes in the
+        draft's history, or every later speculation window would
+        propose from garbage and acceptance would silently collapse."""
         draft = self._draft
         with _span("serving.decode.prepare"):
             self._extend_tables()
-            act = jnp.asarray(self.active)
-            ids = jnp.asarray(self.last_ids)
-            pos = jnp.asarray(self.pos)
+            act = jnp.asarray(self.active.copy())
+            ids = self._next_ids()
+            pos = jnp.asarray(self.pos.copy())
             tables = self._tables_dev()
         with _span("serving.decode.enqueue"):
             if draft is not None:
@@ -1025,20 +1133,55 @@ class PagedLlamaDecodeEngine:
                         draft._kv.ensure_token(s, int(self.pos[s]))
                 _, draft.kvs = draft._decode(
                     draft.params, draft.kvs, ids, pos,
-                    jnp.asarray(draft._kv.block_tables), act)
+                    draft._tables_dev(), act)
             nxt, self.kvs = self._decode(
                 self.params, self.kvs, ids, pos, tables, act)
         self._count_pa_path()
+        self.pos[self.active] += 1
+        if draft is not None:
+            draft.pos[self.active] = self.pos[self.active]
+        # what the launch was enqueued for: its result, the slots it
+        # steps, each in which of its activations, and the unfetched
+        # launches whose counts its fetch reads
+        slots = np.flatnonzero(self.active)
+        launch = {"out": nxt, "act": act, "slots": slots,
+                  "activation": self._activation[slots],
+                  "aux": self._unfetched_aux()}
+        self._ahead = launch
+        return launch
+
+    def step_collect(self, launch: dict):
+        """The second half: wait for ``launch``'s tokens; returns the
+        next token per slot (garbage for the slots it did not step) and
+        the model's counts that the fetch brings (``_take_aux``). The
+        host's ``last_ids`` takes the tokens of the slots that are still
+        in the batch for the activation the launch stepped: a slot
+        released and taken again meanwhile holds another request's
+        token, which this launch's must not overwrite."""
         with _span("serving.decode.fetch"):
-            nxt = self._take_aux(np.asarray(nxt))
-        for s in range(self.max_slots):
-            if self.active[s]:
-                self.pos[s] += 1
+            nxt, counts = self._take_aux(np.asarray(launch["out"]),
+                                         launch["aux"])
+        if self._ahead is launch:
+            self._ahead = None
+        draft = self._draft
+        for s, stepped in zip(launch["slots"], launch["activation"]):
+            if self.active[s] and self._activation[s] == stepped:
                 self.last_ids[s, 0] = nxt[s]
                 if draft is not None:
-                    draft.pos[s] = self.pos[s]
                     draft.last_ids[s, 0] = nxt[s]
-        return nxt
+        return nxt, counts
+
+    def step(self) -> np.ndarray:
+        """One decode iteration for ALL active slots, enqueued and
+        fetched; returns next token per slot (garbage for inactive
+        slots — callers consult .active)."""
+        return self.step_collect(self.step_enqueue())[0]
+
+    def leave(self, slot: int) -> None:
+        """Take ``slot`` out of the batch from the next launch on. It
+        keeps its blocks and its reservation until ``release``: a launch
+        in flight may still be writing them."""
+        self.active[slot] = False
 
     def spec_ready(self) -> bool:
         """True when the next iteration can run speculatively: a
@@ -1203,6 +1346,7 @@ class PagedLlamaDecodeEngine:
         self.active[slot] = False
         self.pos[slot] = 0
         self._prefill_state.pop(slot, None)
+        self._first_dev.pop(slot, None)
         self.prefix_hit_tokens.pop(slot, None)
         self._kv.release(slot, evicted=evicted)
         if self._draft is not None:
@@ -1304,6 +1448,15 @@ class PagedLlamaDecodeEngine:
         return exported.serialize()
 
 
+def _steps_in_halves(eng) -> bool:
+    """True where the loop may keep a launch of ``eng`` in flight: it
+    offers the two halves (``step_enqueue`` / ``step_collect``,
+    ``prefill_enqueue`` / ``prefill_collect``, ``leave``). A duck-typed
+    engine with only ``step()`` / ``prefill_chunk()`` is stepped through
+    those, and nothing stays in flight."""
+    return hasattr(eng, "step_enqueue")
+
+
 class GenerationServer:
     """Iteration-level continuous batching around a
     :class:`PagedLlamaDecodeEngine`: requests are admitted into free
@@ -1318,6 +1471,20 @@ class GenerationServer:
     ONE prompt chunk before the decode step, so a long prompt admitted
     mid-stream costs already-decoding requests one chunk forward per
     step instead of the whole prompt.
+
+    The loop keeps ONE LAUNCH IN FLIGHT: a pass enqueues its prompt
+    chunk and decode launch n+1 and only then fetches and commits
+    launch n, so the device holds its next program when one ends and
+    the host's work between programs runs beside a program. A launch
+    carries which request each slot stepped for, and its tokens go only
+    to requests that still hold their slot: one that ended meanwhile
+    (an EOS, seen a launch late; a deadline) had its tokens in flight
+    counted into ``serving.overrun_tokens_total`` where it ended. The
+    loop lands everything first where it must not run ahead, and sees
+    why from its own state: a weight swap pending, a speculative step
+    (``spec_ready()``), no batch left (before it parks or leaves). An
+    engine that offers only ``step()`` / ``prefill_chunk()`` steps and
+    collects in one call, and nothing stays in flight.
 
     Robustness contract: ``submit(..., deadline=s)`` bounds a request's
     wall time — expiry (checked at step boundaries; queued, waiting
@@ -1356,6 +1523,10 @@ class GenerationServer:
         self._waiting: List[dict] = []
         self._cancel_waiting = False  # set by shutdown(drain=False)
         self.steps_run = 0
+        self.launched_ahead = 0     # steps enqueued before the last's fetch
+        # a model's counts that the collects returned, until a launch's
+        # span takes them
+        self._aux_carry: Dict[str, int] = {}
         self.admitted = 0
         self.rejected = 0           # submissions after shutdown/shed
         self.shed = 0               # rejections by load-shedding alone
@@ -1529,8 +1700,10 @@ class GenerationServer:
                    "the replica is overloaded (KV blocks exhausted "
                    "with a deferred backlog) — retry later or raise "
                    "FLAGS_serving_num_blocks"))
+        # `launched` counts the tokens enqueued for the request, `out`
+        # holds the ones fetched and delivered
         req = {"prompt": prompt,
-               "max_new": int(max_new_tokens), "out": [],
+               "max_new": int(max_new_tokens), "out": [], "launched": 0,
                "done": threading.Event(), "error": None,
                "trace_id": trace_id,
                "t0": time.monotonic(),
@@ -1673,7 +1846,17 @@ class GenerationServer:
         return (req["expires"] is not None
                 and time.monotonic() > req["expires"])
 
+    def _drop_ahead(self, req) -> None:
+        """A request ends here: whatever is still enqueued for it (a
+        decode launch it was part of, its prompt's first token) will
+        reach nobody."""
+        ahead = req["launched"] - len(req["out"])
+        if ahead > 0:
+            _M_overrun.inc(ahead)
+            req["launched"] = len(req["out"])
+
     def _fail(self, req, error) -> None:
+        self._drop_ahead(req)
         req["error"] = error
         req["done"].set()
         _M_failed.inc()
@@ -1709,8 +1892,11 @@ class GenerationServer:
 
     def _free_slots(self):
         eng = self.engine
+        # a slot that has launched its last token has left the batch
+        # and holds its request until that token is fetched
         return [s for s in range(eng.max_slots)
-                if not eng.active[s] and s not in self._prefilling]
+                if not eng.active[s] and s not in self._prefilling
+                and s not in self._slots]
 
     def _admit_paged(self, req, slot) -> str:
         """Paged admission: allocate + reserve blocks and start the
@@ -1742,6 +1928,7 @@ class GenerationServer:
         if not ok:
             return "defer"
         req["t_admit"] = time.monotonic()
+        req["launched"] = len(req["out"])
         # stamp admission BEFORE prefill: queue_seconds is the pure
         # submit->admission wait and decode_seconds covers prefill +
         # decode (slow prefill must not masquerade as queueing — the
@@ -1809,58 +1996,79 @@ class GenerationServer:
             elif verdict == "defer":
                 self._waiting.append(req)
 
-    def _run_prefill(self):
-        """Advance ONE prompt chunk of the OLDEST-admitted prefilling
+    def _run_prefill(self) -> List[dict]:
+        """Enqueue ONE prompt chunk of the OLDEST-admitted prefilling
         slot (dict insertion order — slot-index order would let a
         newer request admitted into a lower slot starve an older
         in-progress prefill) — the prefill/decode interleave: each
         loop iteration costs at most one chunk forward on top of the
-        decode step, so already-admitted slots keep streaming."""
+        decode step, so already-admitted slots keep streaming. A
+        prompt's last chunk is not waited for: its slot is in this
+        pass's decode launch, and what comes back is the first token
+        still to fetch ([] otherwise, and from an engine that fetched it
+        itself)."""
+        eng = self.engine
+        halves = _steps_in_halves(eng)
         for slot in list(self._prefilling):
             req = self._prefilling[slot]
             tid = req.get("trace_id")
             with _span("serving.prefill", trace_id=tid,
                        slot=slot) as span:
                 try:
-                    first = self.engine.prefill_chunk(slot)
+                    first = (eng.prefill_enqueue if halves
+                             else eng.prefill_chunk)(slot)
                 except Exception as e:  # noqa: BLE001 — per-request
                     if self._fenced():
-                        return  # zombie: recovery owns the request now
+                        return []  # zombie: recovery owns the request now
                     del self._prefilling[slot]
-                    self.engine.release(slot, evicted=True)
+                    eng.release(slot, evicted=True)
                     self._fail(req, e)
-                    return
+                    return []
                 if self._fenced():
-                    return  # zombie woke from a wedged chunk: commit
+                    return []  # zombie woke from a wedged chunk: commit
                     # nothing — the new loop re-admitted this request
                 # the turn this request got: which prompt tokens, in
                 # which bucket (getattr: duck-typed fake engines keep
                 # the bare contract)
-                chunk = dict(getattr(self.engine, "last_chunk", {}))
-                if first is not None:
-                    # a model's own counts (the experts' rows), read in
-                    # the fetch of a prompt's last chunk
-                    chunk.update(getattr(self.engine, "last_aux", {}))
+                chunk = dict(getattr(eng, "last_chunk", {}))
                 span.set(**chunk)
                 _flight.record("serving", "prefill_chunk", trace_id=tid,
                                slot=slot, **chunk)
-                if first is not None:
-                    del self._prefilling[slot]
-                    req["out"].append(first)
-                    self._slots[slot] = req
-                    _flight.record("serving", "prefilled",
-                                   trace_id=tid, slot=slot,
-                                   prompt_len=int(req["prompt"].shape[0]))
-                    self._finish_if_done(slot, req)
-            return
+                if first is None:
+                    return []
+                del self._prefilling[slot]
+                self._slots[slot] = req
+                req["launched"] += 1
+            unfetched = {"slot": slot, "req": req, "handle": first}
+            if halves:
+                return [unfetched]
+            # prefill_chunk() fetched the token itself
+            self._commit_first(unfetched, first)
+            return []
+        return []
+
+    def _commit_first(self, first: dict, tok: int) -> None:
+        """A prompt's first token has been fetched: it goes to the
+        request its prompt was, if that still holds the slot."""
+        slot, req = first["slot"], first["req"]
+        if self._slots.get(slot) is not req or req["done"].is_set():
+            return  # ended meanwhile: counted where it ended
+        req["out"].append(int(tok))
+        _flight.record("serving", "prefilled",
+                       trace_id=req.get("trace_id"), slot=slot,
+                       prompt_len=int(req["prompt"].shape[0]))
+        self._finish_if_done(slot, req)
 
     def _finish_if_done(self, slot, req):
         eng = self.engine
+        # capacity ends a request once its last launched token is here
         done = (len(req["out"]) >= req["max_new"]
                 or (eng.eos_id is not None
                     and req["out"][-1] == eng.eos_id)
-                or eng.pos[slot] >= eng.max_seq - 1)
+                or (req["launched"] <= len(req["out"])
+                    and eng.pos[slot] >= eng.max_seq - 1))
         if done:
+            self._drop_ahead(req)
             eng.release(slot)
             del self._slots[slot]
             req["done"].set()
@@ -1924,7 +2132,8 @@ class GenerationServer:
     def _apply_pending_swap(self) -> None:
         """Apply a pending weight hot-swap HERE, on the loop thread,
         at a step boundary: the previous decode step has fully
-        committed its tokens and no new step has dispatched, so no
+        committed its tokens and no new step has dispatched (the loop
+        lands the launch it keeps in flight before it calls this), so no
         in-flight request drops or corrupts a token — its KV blocks
         and slot state are untouched and the next step simply runs on
         the new weights. A rejected swap (engine validation) leaves
@@ -2010,6 +2219,155 @@ class GenerationServer:
             self._set_gauges()
             self.policy.on_step(self)
 
+    def _leave_by_count(self) -> None:
+        """Before a launch is enqueued: a slot whose request has its
+        last token launched (``max_new`` of them, or the cache's last
+        position) leaves the batch by counting, without waiting to see
+        that token; it holds its request until the token is fetched.
+        Only an EOS needs the token: it is seen one launch late, and the
+        slot's one launch too many is an overrun."""
+        eng = self.engine
+        for slot, req in self._slots.items():
+            if eng.active[slot] and (req["launched"] >= req["max_new"]
+                                     or eng.pos[slot] >= eng.max_seq - 1):
+                eng.leave(slot)
+
+    def _enqueue_decode(self, ahead: bool) -> dict:
+        """Enqueue the next decode launch for every active slot. The
+        launch carries what it was launched for: which request each
+        slot stepped. An engine without the two halves steps here,
+        enqueue and collect as one call, and its tokens are in the
+        launch already."""
+        eng = self.engine
+        launch = {"toks": None,
+                  "reqs": {slot: req for slot, req in self._slots.items()
+                           if eng.active[slot]}}
+        if _steps_in_halves(eng):
+            launch["launch"] = eng.step_enqueue()
+            self.launched_ahead += ahead
+        else:
+            launch["toks"] = eng.step()
+        for req in launch["reqs"].values():
+            req["launched"] += 1
+        return launch
+
+    def _fetch(self, firsts, launch, span=None):
+        """Wait for what is in flight, in the order it was enqueued:
+        the first tokens of the prompts whose last chunk went out before
+        ``launch``, each committed as soon as it is here, then the
+        decode ``launch``, whose tokens come back for ``_commit``. A
+        model's counts, which the collects return, ride on ``span``,
+        each launch's once: what is fetched under no launch's span
+        waits for the next."""
+        eng = self.engine
+        toks = None
+        for first in firsts:
+            tok, counts = eng.prefill_collect(first["handle"])
+            self._carry(counts)
+            if self._fenced():
+                return None
+            self._commit_first(first, tok)
+        if launch is not None:
+            toks, counts = eng.step_collect(launch["launch"])
+            self._carry(counts)
+        if span is not None and self._aux_carry:
+            span.set(**self._aux_carry)
+            self._aux_carry = {}
+        return toks
+
+    def _carry(self, counts: Dict[str, int]) -> None:
+        for key, n in counts.items():
+            self._aux_carry[key] = self._aux_carry.get(key, 0) + n
+
+    def _commit(self, launch, toks, counts=None) -> None:
+        """Deliver a fetched launch's tokens (``counts`` a slot: more
+        than one after a speculative step), each to the request it was
+        launched for, if that still holds the slot and is alive: what
+        was launched for a request that has ended since was counted as
+        overrun there, and reaches nobody."""
+        eng = self.engine
+        self.steps_run += 1
+        _M_steps.inc()
+        with _span("serving.commit") as span:
+            delivered = self.tokens_delivered
+            for slot, req in launch["reqs"].items():
+                if self._slots.get(slot) is not req \
+                        or req["done"].is_set():
+                    continue
+                before = len(req["out"])
+                for tok in (toks[slot:slot + 1] if counts is None
+                            else toks[slot, :int(counts[slot])]):
+                    req["out"].append(int(tok))
+                    if len(req["out"]) >= req["max_new"]:
+                        break
+                    if eng.eos_id is not None and tok == eng.eos_id:
+                        break
+                if counts is not None:      # fetched as it was launched
+                    req["launched"] = len(req["out"])
+                self.tokens_delivered += len(req["out"]) - before
+                _flight.record("serving", "decode",
+                               trace_id=req.get("trace_id"),
+                               step=self.steps_run,
+                               tokens=len(req["out"]))
+                self._finish_if_done(slot, req)
+            span.set(tokens=self.tokens_delivered - delivered)
+
+    def _step_spec(self) -> None:
+        """A speculative iteration, fetched where it is launched: up to
+        spec_k committed tokens per slot for one step's host fetch; the
+        greedy stream is bit-equal to plain stepping, so requests cut
+        off mid-window (eos / budget) see exactly the tokens they would
+        have anyway."""
+        with _span("serving.decode", step=self.steps_run + 1, spec=1,
+                   **self._launch_counts()):
+            toks, counts = self.engine.spec_step()
+        if not self._fenced():
+            self._commit({"reqs": dict(self._slots)}, toks, counts)
+
+    def _step_ahead(self, firsts, ahead, new_firsts):
+        """A pass's decode step: launch n+1 goes out, then launch n
+        (``ahead``) and the ``firsts`` of the pass before come in and
+        are committed, so the device always holds its next program when
+        one ends. Returns what the pass leaves in flight: its launch
+        and the first tokens of its prompt chunk (``new_firsts``)."""
+        eng = self.engine
+        self._leave_by_count()
+        launch = None
+        if np.any(eng.active):
+            with _span("serving.decode",
+                       step=self.steps_run + 1 + (ahead is not None),
+                       spec=0, **self._launch_counts()) as span:
+                launch = self._enqueue_decode(ahead is not None)
+                if ahead is not None:
+                    span.set(ahead=1)
+                toks = self._fetch(firsts, ahead, span)
+        else:   # every slot has launched its last token
+            toks = self._fetch(firsts, ahead)
+        if self._fenced():
+            return None, []
+        if ahead is not None:
+            self._commit(ahead, toks)
+        if launch is not None and launch["toks"] is not None:
+            # the engine stepped in one call: nothing stays in flight
+            # (and _run_prefill has committed its prompt's first token)
+            self._commit(launch, launch["toks"])
+            return None, []
+        return launch, new_firsts
+
+    def _land(self, firsts, launch, new_firsts=()):
+        """Fetch and commit what is in flight, outside a launch and in
+        the order it was enqueued (``firsts``, ``launch``, then this
+        pass's ``new_firsts``): where the loop must not run ahead (a
+        weight swap, a speculative step, no batch left) it lands
+        everything first. Returns what is in flight then: no launch, no
+        first token."""
+        toks = self._fetch(firsts, launch)
+        if launch is not None and not self._fenced():
+            self._commit(launch, toks)
+        if new_firsts and not self._fenced():
+            self._fetch(new_firsts, None)
+        return None, []
+
     def _loop(self):
         # the epoch captured here fences THIS incarnation: after a
         # supervisor restart (crash or stall), a zombie of the old
@@ -2019,6 +2377,12 @@ class GenerationServer:
         # from inside a call the zombie was wedged in)
         my_epoch = self._epoch
         threading.current_thread()._serving_loop_epoch = my_epoch
+        # what this incarnation has enqueued and not fetched: the decode
+        # launch a pass leaves in flight, and the first tokens of the
+        # prompts whose last chunk it enqueued. A fenced loop takes them
+        # with it: the loop that replaces it starts with neither
+        ahead: Optional[dict] = None
+        firsts: List[dict] = []
         while True:
             if self._epoch != my_epoch:
                 return  # fenced: a supervisor replaced this loop
@@ -2032,12 +2396,24 @@ class GenerationServer:
                            prefilling=len(self._prefilling),
                            waiting=self._q.qsize() + len(self._waiting)):
                     if self._swap_req is not None:
+                        # "no new step has dispatched": the swap sees
+                        # every launched token committed
+                        ahead, firsts = self._land(firsts, ahead)
+                        if self._epoch != my_epoch:
+                            return
                         with _span("serving.swap"):
                             self._apply_pending_swap()
                     self._admit_spanned(self._admit)
-                    if self._prefilling:
-                        self._run_prefill()
+                    new_firsts = self._run_prefill() \
+                        if self._prefilling else []
                     if not self._slots:
+                        # whatever is in flight belongs to nobody any
+                        # more (its requests ended on an EOS or the
+                        # clock): land it before the loop cycles, parks
+                        # or leaves
+                        ahead, firsts = self._land(firsts, ahead)
+                        if self._epoch != my_epoch:
+                            return
                         if self._prefilling or self._waiting:
                             # prompts still chunking / requests waiting
                             # on blocks: keep cycling (no decode batch
@@ -2074,52 +2450,23 @@ class GenerationServer:
                     # carries every in-flight request's lifecycle trail
                     _fi.fire("serving.decode")
                     eng = self.engine
-                    spec = bool(eng.spec_ready())
-                    with _span("serving.decode", step=self.steps_run + 1,
-                               spec=int(spec),
-                               **self._launch_counts()) as dspan:
-                        if spec:
-                            # speculative iteration: up to spec_k
-                            # committed tokens per slot for one step's
-                            # host fetch; the greedy stream is bit-equal
-                            # to plain stepping, so requests cut off
-                            # mid-window (eos / budget) see exactly the
-                            # tokens they would have anyway
-                            toks, counts = eng.spec_step()
-                        else:
-                            # plain stepping is the counts == 1 case of
-                            # the same commit loop
-                            toks = eng.step()[:, None]
-                            counts = np.ones(eng.max_slots, np.int32)
-                            # a model's own counts of the launch (the
-                            # experts' rows), read in the step's fetch
-                            dspan.set(**getattr(eng, "last_aux", {}))
+                    if eng.spec_ready():
+                        # the accepted count decides pos, and the draft
+                        # reads the host's last_ids: a speculative step
+                        # starts from everything landed
+                        ahead, firsts = self._land(
+                            firsts, ahead, new_firsts)
+                        new_firsts = []
+                        if self._epoch != my_epoch:
+                            return
+                    if self._slots and eng.spec_ready():
+                        self._step_spec()
+                    else:
+                        ahead, firsts = self._step_ahead(
+                            firsts, ahead, new_firsts)
                     if self._epoch != my_epoch:
                         return  # fenced mid-step (stall restart): the
-                        # new loop owns the slots — do not commit or fail
-                    self.steps_run += 1
-                    _M_steps.inc()
-                    with _span("serving.commit") as span:
-                        delivered = self.tokens_delivered
-                        for slot in list(self._slots):
-                            req = self._slots[slot]
-                            before = len(req["out"])
-                            for j in range(int(counts[slot])):
-                                tok = int(toks[slot, j])
-                                req["out"].append(tok)
-                                if len(req["out"]) >= req["max_new"]:
-                                    break
-                                if eng.eos_id is not None \
-                                        and tok == eng.eos_id:
-                                    break
-                            self.tokens_delivered += \
-                                len(req["out"]) - before
-                            _flight.record("serving", "decode",
-                                           trace_id=req.get("trace_id"),
-                                           step=self.steps_run,
-                                           tokens=len(req["out"]))
-                            self._finish_if_done(slot, req)
-                        span.set(tokens=self.tokens_delivered - delivered)
+                        # new loop owns the slots — nothing was committed
                     self._sweep()
             except Exception as e:  # noqa: BLE001 — fail loudly, stay up
                 if self._epoch != my_epoch:
@@ -2136,6 +2483,7 @@ class GenerationServer:
                     self._fail(req, e)
                     self.engine.release(slot, evicted=True)
                 self._prefilling.clear()
+                ahead, firsts = None, []
                 self._set_gauges()
         self._set_gauges()
         # a swap still pending at loop exit can never apply: unblock
@@ -2207,7 +2555,9 @@ class GenerationServer:
             queued = sum(1 for r in self._q.queue
                          if r is not self._STOP
                          and not r["done"].is_set())
-        out = {"steps_run": self.steps_run, "admitted": self.admitted,
+        out = {"steps_run": self.steps_run,
+               "launched_ahead": self.launched_ahead,
+               "admitted": self.admitted,
                "rejected": self.rejected, "shed": self.shed,
                "deadline_rejected": self.deadline_rejected,
                "deadline_expired": self.deadline_expired,

@@ -645,7 +645,12 @@ class TestRepoStepFixtures:
         from paddle_tpu.analysis.lint import REPO_ROOT
         path = os.path.join(REPO_ROOT, "paddle_tpu", "serving.py")
         expected = {
-            "PagedLlamaDecodeEngine.step": {"PTC002": 2, "PTC003": 1},
+            # step_enqueue: pos advances where the launch goes out;
+            # step_collect: the fetched tokens land in last_ids (the
+            # fetch reads a launch record's entry, which the scan does
+            # not follow)
+            "PagedLlamaDecodeEngine.step_enqueue": {"PTC002": 1},
+            "PagedLlamaDecodeEngine.step_collect": {"PTC002": 1},
             "PagedLlamaDecodeEngine.decode_steps":
                 {"PTC002": 1, "PTC003": 1},
             # begin_request: admission bookkeeping only — slot
@@ -654,15 +659,17 @@ class TestRepoStepFixtures:
             # decision is allocator method calls, not step-state
             # mutation, so it adds NO findings beyond the hit record
             "PagedLlamaDecodeEngine.begin_request": {"PTC002": 4},
-            # prefill_chunk: prompt staging into the padded host
+            # prefill_enqueue: prompt staging into the padded host
             # buffer (the per-bucket program-cache insert lives in
-            # _prefill_program, no step), slot activation bookkeeping
-            # (pos/active/last_ids), the draft-mirror last_ids seed +
-            # the final-chunk first-token fetch (the radix
-            # commit_prefix after each chunk is an allocator call —
-            # no new finding)
-            "PagedLlamaDecodeEngine.prefill_chunk":
-                {"PTC002": 5, "PTC003": 1},
+            # _prefill_program, no step) and slot activation
+            # bookkeeping (pos/active, the activation's count, the
+            # first token's handle for the next launch);
+            # prefill_collect: the fetched first
+            # token lands in last_ids and seeds the draft's mirror (the
+            # radix commit_prefix after each chunk is an allocator
+            # call — no new finding)
+            "PagedLlamaDecodeEngine.prefill_enqueue": {"PTC002": 5},
+            "PagedLlamaDecodeEngine.prefill_collect": {"PTC002": 2},
             # spec_step: commit bookkeeping (pos/last_ids) between the
             # propose/verify executables + the ONE window fetch
             # (tokens + accepted counts, both hoisted to the tail)

@@ -204,13 +204,13 @@ class TestDeadlinesAndDrain:
     def test_shutdown_no_drain_cancels_queued(self, model):
         import time
         eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=64)
-        orig_step = eng.step
+        orig_step = eng.step_collect
 
-        def slow_step():  # hold the slot long enough that the queue
+        def slow_step(launch):  # hold the slot long enough that the queue
             time.sleep(0.15)  # is still populated at shutdown time
-            return orig_step()
+            return orig_step(launch)
 
-        eng.step = slow_step
+        eng.step_collect = slow_step
         srv = GenerationServer(eng)
         first = srv.submit([1, 2, 3], 8)
         queued = [srv.submit([4, 5], 8) for _ in range(3)]
@@ -234,13 +234,13 @@ class TestDeadlinesAndDrain:
         fails with TimeoutError without consuming a slot."""
         import time
         eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=64)
-        orig_step = eng.step
+        orig_step = eng.step_collect
 
-        def slow_step():  # hold the slot past the queued deadline on
+        def slow_step(launch):  # hold the slot past the queued deadline on
             time.sleep(0.02)  # fast hosts too
-            return orig_step()
+            return orig_step(launch)
 
-        eng.step = slow_step
+        eng.step_collect = slow_step
         srv = GenerationServer(eng)
         blocker = srv.submit([1, 2, 3], 30)      # hog the only slot
         starved = srv.submit([9, 8], 8, deadline=0.2)
@@ -258,14 +258,18 @@ class TestDeadlinesAndDrain:
         step boundary but keeps the tokens it already produced."""
         import time
         eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=256)
-        orig_step = eng.step
+        orig_step = eng.step_collect
 
-        def slow_step():  # pin step cost so the deadline bites on any
+        def slow_step(launch):  # pin step cost so the deadline bites on any
             time.sleep(0.05)  # host, fast or slow
-            return orig_step()
+            return orig_step(launch)
 
-        eng.step = slow_step
+        eng.step_collect = slow_step
         srv = GenerationServer(eng)
+        # compile prefill + decode BEFORE the deadline clock starts: a
+        # first token is fetched a pass after its chunk, and a deadline
+        # that a cold compile outlasts would expire with nothing fetched
+        srv.generate([1, 2, 3], 2, timeout=120)
         req = srv.submit(list(range(1, 6)), 200, deadline=0.75)
         assert req["done"].wait(120)
         assert isinstance(req["error"], TimeoutError)

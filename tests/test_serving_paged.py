@@ -337,13 +337,13 @@ class TestServerInterleave:
         eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64,
                                      block_size=8, num_blocks=4,
                                      prefill_chunk=8)
-        orig_step = eng.step
+        orig_step = eng.step_collect
 
-        def slow_step():
+        def slow_step(launch):
             time.sleep(0.03)
-            return orig_step()
+            return orig_step(launch)
 
-        eng.step = slow_step
+        eng.step_collect = slow_step
         srv = GenerationServer(eng)
         small_a = srv.submit([1, 2, 3], 12)       # 2 blocks, runs long
         assert _wait_steps(srv, 2)
@@ -386,13 +386,13 @@ class TestServerInterleave:
         eng = PagedLlamaDecodeEngine(model, max_slots=1, max_seq=64,
                                      block_size=8, num_blocks=4,
                                      prefill_chunk=8)
-        orig_step = eng.step
+        orig_step = eng.step_collect
 
-        def slow_step():
+        def slow_step(launch):
             time.sleep(0.05)
-            return orig_step()
+            return orig_step(launch)
 
-        eng.step = slow_step
+        eng.step_collect = slow_step
         srv = GenerationServer(eng)
         # compile prefill (bucket 8) + decode BEFORE any deadline clock
         # starts: the deadlines below race 50 ms steps, not XLA

@@ -759,13 +759,13 @@ class TestLoadShedding:
         eng = PagedLlamaDecodeEngine(model, max_slots=4, max_seq=64,
                                      block_size=8, num_blocks=4,
                                      prefill_chunk=8)
-        orig_step = eng.step
+        orig_step = eng.step_collect
 
-        def slow_step():
+        def slow_step(launch):
             time.sleep(0.02)
-            return orig_step()
+            return orig_step(launch)
 
-        eng.step = slow_step
+        eng.step_collect = slow_step
         srv = GenerationServer(eng)
         try:
             # 12 prompt + 20 budget = 32 tokens = the whole 4-block

@@ -130,16 +130,35 @@ def test_a_traced_run_has_every_serving_span_with_its_attributes(traced, name):
     spans = _named(traced, name)
     assert spans, f"no {name} span in the trace"
     for _, _, _, stats, _ in spans:
-        assert set(stats) == SERVING_SPANS[name], (name, stats)
+        # a launch enqueued while the last was not fetched says so
+        assert set(stats) - {"ahead"} == SERVING_SPANS[name], (name, stats)
+        assert stats.get("ahead", 1) == 1 and \
+            ("ahead" not in stats or name == "serving.decode")
+
+
+def test_most_decode_launches_go_out_ahead_of_the_last_ones_fetch(traced):
+    """The loop keeps one launch in flight: a `serving.decode` span enqueues
+    launch n+1 and its `.fetch` waits for launch n, so `ahead` is on every
+    span but the first after the loop had nothing in flight (its start, a
+    weight swap, a batch that ended)."""
+    spans = _named(traced, "serving.decode")
+    ahead = [s for s in spans if s[3].get("ahead")]
+    assert len(ahead) >= len(spans) // 2
+    fetches = _named(traced, "serving.decode.fetch")
+    inside = [f for f in fetches if any(
+        d[0] <= f[0] and f[1] <= d[1] for d in ahead)]
+    assert len(inside) == len(ahead)
 
 
 @pytest.mark.parametrize("child,parent", [
-    ("serving.decode.fetch", "serving.decode"),
+    # a fetch waits for the launch in flight: under the `serving.decode`
+    # that enqueues the next launch, or with none to enqueue under the pass
+    ("serving.decode.fetch", "serving.iter"),
     ("serving.decode.prepare", "serving.decode"),
     ("serving.decode.enqueue", "serving.decode"),
     ("serving.decode", "serving.iter"),
     ("serving.prefill.enqueue", "serving.prefill"),
-    ("serving.prefill.fetch", "serving.prefill"),
+    ("serving.prefill.fetch", "serving.iter"),
     ("serving.prefill", "serving.iter"),
     ("serving.commit", "serving.iter"),
     ("serving.sweep", "serving.iter"),
