@@ -36,19 +36,29 @@ __all__ = ["sigmoid_topk_route", "row_tile", "held_experts_forward"]
 _HI = jax.lax.Precision.HIGHEST
 
 
-def sigmoid_topk_route(x, w_router, top_k: int, normalize: bool = True):
+def sigmoid_topk_route(x, w_router, top_k: int, normalize: bool = True,
+                       bias=None, scale: float = 1.0):
     """Float32 sigmoid router over ALL experts. ``x [T, H]``, ``w_router
     [n_experts, H]``. Returns ``(idx [T, k] int32, weight [T, k]
     float32)``: the ``k`` largest scores of each row and, ``normalize``,
-    each over the sum of the row's ``k``. Float32 at the highest matmul
-    precision whatever the activations' dtype: a top-k choice near a tie
-    must not turn on bfloat16 rounding."""
+    each over the sum of the row's ``k``. With ``bias [n_experts]`` (a
+    score-correction bias) the choice is by ``score + bias`` and the
+    weight still the score itself; ``scale`` multiplies the weights last
+    (a routed scaling factor). Float32 at the highest matmul precision
+    whatever the activations' dtype: a top-k choice near a tie must not
+    turn on bfloat16 rounding."""
     scores = jax.nn.sigmoid(jnp.einsum(
         "th,eh->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=_HI, preferred_element_type=jnp.float32))
-    weight, idx = jax.lax.top_k(scores, int(top_k))
+    if bias is None:
+        weight, idx = jax.lax.top_k(scores, int(top_k))
+    else:
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), int(top_k))
+        weight = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
         weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weight = weight * scale
     return idx.astype(jnp.int32), weight
 
 

@@ -18,3 +18,6 @@ from .llama_pipe import LlamaForCausalLMPipe  # noqa: F401
 from .cohere2_moe import (  # noqa: F401
     Cohere2MoeConfig, Cohere2MoeForCausalLM,
 )
+from .glm_moe_dsa import (  # noqa: F401
+    GlmMoeDsaConfig, GlmMoeDsaForCausalLM,
+)

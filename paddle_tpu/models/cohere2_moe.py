@@ -309,9 +309,10 @@ def full_forward(cfg: Cohere2MoeConfig, params, ids, held):
 
 
 class Cohere2MoeServe:
-    """The model's side of the serving seam: its cache spec (a kind, KV
-    heads and head width a layer), its parameters in the engine's pytree,
-    and one layer's step over the paged cache."""
+    """The model's side of the serving seam: its cache spec (a layer's
+    kind, its K and V pools by row width, KV heads and head width), its
+    parameters in the engine's pytree, and one layer's step over the paged
+    cache."""
 
     n_aux = 3            # moe_rows, moe_experts_hit, moe_max_rows a launch
     aux_names = ("moe_rows", "moe_experts_hit", "moe_max_rows")
@@ -324,9 +325,11 @@ class Cohere2MoeServe:
 
     def cache_spec(self, n_layers: int) -> list:
         cfg = self.cfg
+        width = cfg.num_key_value_heads * cfg.head_dim
         return [{"kind": cfg.layer_kind(i),
                  "window": cfg.sliding_window
                  if cfg.layer_kind(i) == "window" else None,
+                 "pools": {"k": width, "v": width},
                  "kv_heads": cfg.num_key_value_heads,
                  "head_dim": cfg.head_dim,
                  "q_heads": cfg.num_attention_heads}
@@ -338,7 +341,8 @@ class Cohere2MoeServe:
     def embed(self, eng, params, ids):
         return jnp.take(params["emb"], ids, axis=0).astype(eng.dtype)
 
-    def layer(self, eng, li, lp, h, kvl, positions, tables, n_tiles, wmask):
+    def layer(self, eng, li, lp, h, kvl, positions, tables, n_tiles, wmask,
+              carry=None):
         """One parallel block over ``h [S, T, H]``: K/V written into the
         layer's pool, attention through the paged seam (a window layer
         with each row's first visible position), experts on the same
@@ -361,7 +365,7 @@ class Cohere2MoeServe:
                 n_tiles=n_tiles, use_kernel=eng._pa_kernel, lower=lower)
         a = _mm(att.reshape(S, T, -1), lp["o"])
         m, counts = experts_block(cfg, lp, n.reshape(S * T, H), self.held)
-        return h + a + m.reshape(S, T, H), kvl, counts
+        return h + a + m.reshape(S, T, H), kvl, counts, carry
 
     def head(self, eng, params, h):
         h = layer_norm(h, params["norm"], self.cfg.layer_norm_eps)

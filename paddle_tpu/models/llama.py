@@ -464,10 +464,11 @@ def _rope_at(x, positions, theta):
 
 class LlamaServe:
     """The model's side of the serving seam: its cache spec (for each
-    layer a kind, ``full`` or ``window`` of ``W`` positions, and KV heads
-    x head_dim), its parameters as the engine's pytree, and a step a
-    layer ``(h, the layer's pools, positions, the kind's block table) ->
-    (h, pools, counts)``. From the engine it takes cache services only:
+    layer a kind, ``full`` or ``window`` of ``W`` positions, its pools
+    ``k`` and ``v`` by row width, and KV heads x head_dim), its parameters
+    as the engine's pytree, and a step a layer ``(h, the layer's pools,
+    positions, the kind's block table, carry) -> (h, pools, counts,
+    carry)``. From the engine it takes cache services only:
     ``_write_kv``, ``_sc.paged_attention``, ``block_size``, ``n_rep``,
     ``_pa_kernel``, and ``dtype`` / ``n_layers`` / ``int8`` to lay out
     its parameters."""
@@ -483,7 +484,9 @@ class LlamaServe:
 
     def cache_spec(self, n_layers: int) -> list:
         cfg = self.cfg
+        width = cfg.num_key_value_heads * self.head_dim
         return [{"kind": "full", "window": None,
+                 "pools": {"k": width, "v": width},
                  "kv_heads": cfg.num_key_value_heads,
                  "head_dim": self.head_dim,
                  "q_heads": cfg.num_attention_heads}] * n_layers
@@ -535,7 +538,8 @@ class LlamaServe:
     def embed(self, eng, params, ids):
         return jnp.take(params["emb"], ids, axis=0).astype(eng.dtype)
 
-    def layer(self, eng, li, lp, h, kvl, positions, tables, n_tiles, wmask):
+    def layer(self, eng, li, lp, h, kvl, positions, tables, n_tiles, wmask,
+              carry=None):
         """One decoder layer over [S, T, H] with block-pool K/V writes
         and the tiled streaming attention."""
         cfg = self.cfg
@@ -565,7 +569,7 @@ class LlamaServe:
                 _mm(x, lp["gate_proj"]).astype(jnp.float32)).astype(
                     x.dtype) * _mm(x, lp["up_proj"]),
                 lp["down_proj"])
-            return res + ff, kvl, None
+            return res + ff, kvl, None, carry
 
     def head(self, eng, params, h):
         return _mm(_rms(h, params["norm"], self.cfg.rms_norm_eps),

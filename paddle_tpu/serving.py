@@ -1,21 +1,32 @@
 """Generation serving: fixed-slot continuous batching over compiled,
 static-shaped step programs, with one decode engine.
 
-``PagedLlamaDecodeEngine`` keeps the slots, a shared block pool a layer
-``[num_blocks, block_size, KVH*D]`` behind per-slot block tables
+``PagedLlamaDecodeEngine`` keeps the slots, the shared block pools a layer
+names (``[num_blocks, block_size, row width]`` each) behind per-slot block tables
 (``serving_cache``: ``PagedKVCache``, or ``KindedKVCache`` with a table a
 kind of layer for a model with window layers), donation, warm-up bundles,
 weight swaps and speculation. It holds no model's math.
 
 **The seam.** ``model.serve_model()`` hands the engine a cache spec (a
-kind, KV heads and head width a layer), ``build_params`` (its weights as
-the engine's pytree), ``embed``, ``head`` and one layer's step ``(h, the
-layer's pools, positions, the kind's block table) -> (h, pools, counts)``.
-The engine offers that step ``_write_kv`` (rope'd K/V rows into their
-blocks), ``_sc.paged_attention`` (``_pa_kernel``: the Pallas kernel on a
-TPU at head widths of whole 128-lane rows, the counted jnp walk elsewhere),
-``block_size``, ``n_rep``, and ``dtype`` / ``n_layers`` / ``int8`` for its
-parameters' layout. ``models/llama.py`` and ``models/cohere2_moe.py`` use it.
+layer's kind and the pools it owns, each named with its row width:
+``{"k": KVH*D, "v": KVH*D}`` for per-head K and V, ``{"latent": 640}`` and
+on some layers ``"index": 128`` beside it for a latent cache with a learned
+indexer; every pool of a kind under that kind's one block table),
+``build_params`` (its weights as the engine's pytree), ``embed``, ``head``
+and one layer's step ``(h, the layer's pools, positions, the kind's block
+table, carry) -> (h, pools, counts, carry)``; ``carry`` is whatever one
+layer hands the layers after it within a launch (a selection of positions
+that several layers share; None for a model with nothing to hand on). A
+decode launch returns, beside its tokens, the positions that their rows
+attended on each layer that made such a selection, as bitsets: they stay
+on the device (``launch["selected"]``) for whoever checks the program.
+The engine offers that step ``_write_rows`` (one row a token into each
+named pool) and ``_write_kv`` (rope'd K/V rows, quantized for an int8
+pool), ``_sc`` (``paged_attention``: ``_pa_kernel`` says whether the Pallas
+kernel runs; ``paged_index_scores``, ``select_topk``,
+``paged_latent_attention``), ``block_size``, ``n_rep``, and ``dtype`` /
+``n_layers`` / ``int8`` for its parameters' layout. ``models/llama.py``,
+``models/cohere2_moe.py`` and ``models/glm_moe_dsa.py`` use it.
 
 **Two program families**, through ``jit.sot.capture_jit``: ``serving.decode``
 (``jit_serving_decode`` in a device trace), one token for every slot at its
@@ -276,16 +287,33 @@ class PagedLlamaDecodeEngine:
         if self.int8 and not self._m.supports_int8:
             raise NotImplementedError(
                 f"int8 projections are not built for {type(model).__name__}")
+        if not getattr(self._m, "supports_prefix_sharing", True):
+            if self._prefix_cache:
+                raise ValueError(
+                    f"prefix sharing is not supported for "
+                    f"{type(model).__name__}")
+            self._prefix_cache = False
         self.cache_spec = self._m.cache_spec(self.n_layers)
-        self.head_dim = self.cache_spec[0]["head_dim"]
-        self.n_rep = self.cache_spec[0]["q_heads"] \
-            // self.cache_spec[0]["kv_heads"]
+        # per-head K/V pools say their heads; a spec that does not (a
+        # latent pool: one row a token, nothing per head) has no head
+        # width for the paged kernel and no scale a head for int8
+        sp0 = self.cache_spec[0]
+        self.head_dim = sp0.get("head_dim")
+        self.n_rep = sp0["q_heads"] // sp0["kv_heads"] \
+            if "kv_heads" in sp0 else 1
+        if self.head_dim is None and kv_quant == "int8":
+            raise NotImplementedError(
+                "an int8 KV pool keeps a scale a (token, head): not built "
+                "for a cache spec without per-head pools")
         windows = {sp["window"] for sp in self.cache_spec if sp["window"]}
         if len(windows) > 1:
             raise NotImplementedError(
                 f"window layers of several widths {sorted(windows)}")
         # the window of the model's window layers (None: every layer full)
         self.window = windows.pop() if windows else None
+        # positions a row attends at most where the model selects them
+        # (learned sparse attention; None: every visible position)
+        self.select_k = getattr(self._m, "select_k", None)
 
         dt = jnp.bfloat16 if str(cfg.dtype) == "bfloat16" else jnp.float32
         self.dtype = dt
@@ -314,7 +342,9 @@ class PagedLlamaDecodeEngine:
         # the implementation behind the paged_attention seam — Pallas
         # kernel vs jnp walk — is decided here ONCE so the per-step path
         # counters report what the compiled programs actually baked in
-        self._pa_kernel = _sc.use_kernel_default(self.head_dim)
+        # (None where the cache has no per-head pool: that seam is not run)
+        self._pa_kernel = None if self.head_dim is None \
+            else _sc.use_kernel_default(self.head_dim)
         self._draft: Optional["PagedLlamaDecodeEngine"] = None
         self._spec_k = 0
         # adaptive-admission brownout knobs, applied by the server at
@@ -356,6 +386,12 @@ class PagedLlamaDecodeEngine:
                 block_size=self.block_size, num_blocks=self.num_blocks,
                 prefix_cache=self._prefix_cache)
         self.kvs = self._alloc_pools()
+        # layers that own a pool, by (kind, pool name): for the gauges
+        self._pool_layers: Dict[tuple, int] = {}
+        for sp in self.cache_spec:
+            for name in sp["pools"]:
+                key = (sp["kind"], name)
+                self._pool_layers[key] = self._pool_layers.get(key, 0) + 1
         # counts a launch hands back (a model's `aux_names`) that no
         # fetch has read yet: a prompt chunk that is not its prompt's
         # last is never fetched, the next launch that is reads them
@@ -497,26 +533,41 @@ class PagedLlamaDecodeEngine:
                       if k in meta and meta[k] != v)
 
     def _alloc_pools(self) -> Dict[str, list]:
-        """Fresh zeroed block pools (per-layer K/V + optional int8
-        scales, each layer's sized by its kind) — built at boot and
-        again at crash recovery (``reset_state``), where the donated
-        pool pytree may be mid-donation."""
+        """Fresh zeroed block pools as the cache spec names them: ``{pool
+        name: [one entry a layer]}``, a layer's entry ``[num_blocks of its
+        kind, block_size, row width]`` where its spec owns that pool and
+        None where it does not (an int8 K/V pool also gets its scales).
+        Built at boot and again at crash recovery (``reset_state``), where
+        the donated pool pytree may be mid-donation."""
         pool_dt = {"int8": jnp.int8,
                    "bfloat16": jnp.bfloat16}.get(self.kv_quant,
                                                  self.dtype)
         bs = self.block_size
-        # (blocks, KV heads, head width) a layer, each by its kind
-        geo = [(self.num_blocks[sp["kind"]] if self._kinded
-                else self.num_blocks, sp["kv_heads"], sp["head_dim"])
-               for sp in self.cache_spec]
-        # K and V with the heads flat: what the kernel's block copy reads
-        kv = {name: [jnp.zeros((nb, bs, kvh * d), pool_dt)
-                     for nb, kvh, d in geo] for name in ("k", "v")}
+        blocks = [self.num_blocks[sp["kind"]] if self._kinded
+                  else self.num_blocks for sp in self.cache_spec]
+        names = list(dict.fromkeys(
+            n for sp in self.cache_spec for n in sp["pools"]))
+        # a row a token, the heads flat: what a block copy reads
+        kv = {name: [jnp.zeros((nb, bs, sp["pools"][name]), pool_dt)
+                     if name in sp["pools"] else None
+                     for nb, sp in zip(blocks, self.cache_spec)]
+              for name in names}
         if self.kv_quant == "int8":
             for name in ("ksc", "vsc"):     # a scale a (token, head)
-                kv[name] = [jnp.zeros((nb, bs, kvh), jnp.float32)
-                            for nb, kvh, _ in geo]
+                kv[name] = [jnp.zeros((nb, bs, sp["kv_heads"]), jnp.float32)
+                            for nb, sp in zip(blocks, self.cache_spec)]
         return kv
+
+    def pool_blocks_in_use(self) -> Dict[str, int]:
+        """Blocks mapped to slots by pool name, summed over the layers
+        that own a pool of that name (a block of a table is one block in
+        every pool under it)."""
+        used = {k: c.used_blocks() for k, c in self._kv.kinds.items()} \
+            if self._kinded else {"full": self._kv.used_blocks()}
+        out: Dict[str, int] = {}
+        for (kind, name), layers in self._pool_layers.items():
+            out[name] = out.get(name, 0) + layers * used[kind]
+        return out
 
     def reset_state(self) -> None:
         """Discard ALL slot and cache state — the crash-recovery seam:
@@ -547,35 +598,32 @@ class PagedLlamaDecodeEngine:
             self._draft.reset_state()
 
     # -- device side --------------------------------------------------------
+    def _write_rows(self, kvl, rows, positions, tables, wmask):
+        """Scatter one row a token into each pool ``rows`` names (``{pool
+        name: [S, T, row width]}``) at its (physical block, offset) cell;
+        rows with ``wmask`` False or an unmapped table entry are dropped
+        (OOB index), so prefill padding and inactive slots never touch a
+        real block. Returns the layer's pools with those written."""
+        return dict(kvl, **self._sc.write_rows(
+            kvl, rows, positions, tables, wmask, self.block_size))
+
     def _write_kv(self, kvl, k, v, positions, tables, wmask):
-        """Scatter rope'd K/V rows [S, T, KVH, D] into their (physical
-        block, offset) cells, each a row of KVH*D in the pool; rows
-        with ``wmask`` False or an unmapped table entry are dropped
-        (OOB index), so prefill padding and inactive slots never touch
-        a real block."""
+        """Rope'd K/V rows [S, T, KVH, D] into their cells, each a row of
+        KVH*D in the pool (``_write_rows``); an int8 pool takes codes and
+        a scale a (token, head)."""
         S, T = positions.shape
-        bidx = jnp.minimum(positions // self.block_size,
-                           self._kv.max_blocks_per_slot - 1)
-        phys = jnp.take_along_axis(tables, bidx, axis=1)
-        ok = jnp.logical_and(wmask, phys >= 0)
-        phys = jnp.where(ok, phys, kvl["k"].shape[0]).reshape(-1)
-        off = (positions % self.block_size).reshape(-1)
-        kf = k.reshape((S * T,) + k.shape[2:])
-        vf = v.reshape((S * T,) + v.shape[2:])
-        out = dict(kvl)
+        rows = {"k": k, "v": v}
         if self.kv_quant == "int8":
             # a scale a (token, head), taken before the heads are flat
-            kf, ks = self._sc.absmax_quantize(kf)
-            vf, vs = self._sc.absmax_quantize(vf)
-            out["ksc"] = self._sc.write_kv_tokens(kvl["ksc"], phys,
-                                                  off, ks)
-            out["vsc"] = self._sc.write_kv_tokens(kvl["vsc"], phys,
-                                                  off, vs)
-        out["k"] = self._sc.write_kv_tokens(
-            kvl["k"], phys, off, kf.reshape(S * T, -1))
-        out["v"] = self._sc.write_kv_tokens(
-            kvl["v"], phys, off, vf.reshape(S * T, -1))
-        return out
+            rows["k"], rows["ksc"] = self._sc.absmax_quantize(
+                k.reshape((S * T,) + k.shape[2:]))
+            rows["v"], rows["vsc"] = self._sc.absmax_quantize(
+                v.reshape((S * T,) + v.shape[2:]))
+        # (through the class: a caller may hand any object with `_sc` and
+        # `block_size` as the engine)
+        return PagedLlamaDecodeEngine._write_rows(
+            self, kvl, {n: r.reshape(S, T, -1) for n, r in rows.items()},
+            positions, tables, wmask)
 
     def _cow_impl(self, params, kvs, src, dst):
         """Boundary copy-on-write: clone physical block ``src`` into
@@ -583,14 +631,18 @@ class PagedLlamaDecodeEngine:
         One captured executable with the pool pytree donated — the
         copy lands in place in HBM like every other pool write."""
         del params
-        return {name: [self._sc.copy_block(p, src, dst)
+        return {name: [None if p is None
+                       else self._sc.copy_block(p, src, dst)
                        for p in pools]
                 for name, pools in kvs.items()}
 
-    def walk_group_tokens(self, T: int = 1) -> int:
+    def walk_group_tokens(self, T: int = 1) -> Optional[int]:
         """Tokens the paged-attention kernel fetches and computes on a
         loop step at this engine's shapes (``T`` rows a slot): a slot
-        at ``pos`` walks ``pos + 1`` rounded up to it."""
+        at ``pos`` walks ``pos + 1`` rounded up to it. None where the
+        model's cache has no per-head pool for that kernel to walk."""
+        if self.head_dim is None:       # no per-head pool: no such walk
+            return None
         from .ops.pallas.paged_attention import group_tokens
         return group_tokens(
             self.block_size,
@@ -601,28 +653,37 @@ class PagedLlamaDecodeEngine:
     def _forward_paged(self, params, kv, ids, positions, tables,
                        n_tiles, wmask):
         """Shared chunked-prefill/decode body: ids [S, T] -> logits
-        [S, T, V]; the pool pytree is donated, writes land in place."""
+        [S, T, V]; the pool pytree is donated, writes land in place.
+        Also returns the model's counts summed over the layers, and
+        ``{layer: what it made and handed on}`` for each layer that did
+        not pass its ``carry`` on as it came (a selection ``[S, T, N]``
+        bool that the layers after it share; ``{}`` for a model with
+        none)."""
         h = self._m.embed(self, params, ids)
         out_kv = {key: [] for key in kv}
-        aux = None
+        aux = carry = None
+        made = {}
         for li, lp in enumerate(params["layers"]):
-            kvl = {key: kv[key][li] for key in kv}
+            kvl = {key: kv[key][li] for key in kv
+                   if kv[key][li] is not None}
             # each layer reads the block table of its kind
             tab = tables[self.cache_spec[li]["kind"]] \
                 if isinstance(tables, dict) else tables
-            h, kvl, counts = self._m.layer(self, li, lp, h, kvl, positions,
-                                           tab, n_tiles, wmask)
+            h, kvl, counts, handed = self._m.layer(
+                self, li, lp, h, kvl, positions, tab, n_tiles, wmask, carry)
+            if handed is not carry:
+                made[li] = carry = handed
             if counts is not None:       # summed over the layers
                 aux = counts if aux is None else aux + counts
             for key in out_kv:
-                out_kv[key].append(kvl[key])
+                out_kv[key].append(kvl.get(key))
         with jax.named_scope("paged.head"):
             logits = self._m.head(self, params, h)
             # barrier: without it XLA fuses the [H, V] head matmul into
             # the consumer argmax as a VPU reduce-loop fusion instead of
             # running the contraction on the MXU
             logits = jax.lax.optimization_barrier(logits)
-        return logits, out_kv, aux
+        return logits, out_kv, aux, made
 
     @staticmethod
     def _with_aux(tok, aux):
@@ -638,14 +699,25 @@ class PagedLlamaDecodeEngine:
         (inactive slots neither write nor advance). ``n_tiles`` caps
         the block walk at the LONGEST history; behind the seam the
         jnp walk runs that far for every slot, the Pallas kernel stops
-        each slot at its own last block."""
+        each slot at its own last block. Beside the tokens and the
+        pools, ``{layer: [S, words] uint32}``: the positions each row
+        attended where a layer selected them, as bitsets (``{}`` for a
+        model that selects nothing); they stay on the device unless
+        somebody reads them."""
         positions = pos[:, None]                        # [S, 1]
         n_tiles = jnp.max(pos) // self.block_size + 1
-        logits, kv, aux = self._forward_paged(params, kv, last_ids,
-                                              positions, tables, n_tiles,
-                                              act[:, None])
+        logits, kv, aux, made = self._forward_paged(
+            params, kv, last_ids, positions, tables, n_tiles, act[:, None])
         nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return self._with_aux(nxt, aux), kv
+        # barrier: the bitsets are packed after everything else. Without it
+        # XLA:TPU plans the whole program's fast memory anew around them
+        # (which weights it prefetches when, where the attention kernel's
+        # operands live) and the launch came out 4 ms slower for 0.4 MB
+        # more written (PERF.md, PR 33)
+        made = jax.lax.optimization_barrier(made)
+        return (self._with_aux(nxt, aux), kv,
+                {li: self._sc.positions_bitset(sel[:, -1])
+                 for li, sel in made.items()})
 
     def _prefill_impl(self, params, kv, ids, table_row, start, nvalid,
                       true_len):
@@ -661,8 +733,8 @@ class PagedLlamaDecodeEngine:
         wmask = (offs < nvalid)[None, :]
         tables = jax.tree.map(lambda r: r[None, :], table_row)
         n_tiles = (start + nvalid - 1) // self.block_size + 1
-        logits, kv, aux = self._forward_paged(params, kv, ids, positions,
-                                              tables, n_tiles, wmask)
+        logits, kv, aux, _ = self._forward_paged(params, kv, ids, positions,
+                                                 tables, n_tiles, wmask)
         last = jnp.clip(true_len - 1 - start, 0, B - 1)
         tok = jnp.argmax(logits[0, last, :]).astype(jnp.int32)
         return self._with_aux(tok, aux), kv
@@ -671,8 +743,8 @@ class PagedLlamaDecodeEngine:
                              tables, act):
         """Decode step + on-device token collection (buf [S, n]
         donated; column i written in-place)."""
-        nxt, kv = self._decode_impl(params, kv, last_ids, pos, tables,
-                                    act)
+        nxt, kv, _ = self._decode_impl(params, kv, last_ids, pos, tables,
+                                       act)
         buf = jax.lax.dynamic_update_slice(buf, nxt[:, None],
                                            (jnp.int32(0), i))
         return nxt, kv, buf
@@ -686,8 +758,8 @@ class PagedLlamaDecodeEngine:
         ids, p = last_ids, pos
         toks = []
         for _ in range(self._spec_propose_k):
-            nxt, kv = self._decode_impl(params, kv, ids, p, tables,
-                                        act)
+            nxt, kv, _ = self._decode_impl(params, kv, ids, p, tables,
+                                           act)
             toks.append(nxt)
             ids = nxt[:, None]
             p = p + 1
@@ -710,8 +782,8 @@ class PagedLlamaDecodeEngine:
         positions = pos[:, None] + jnp.arange(k + 1)[None, :]
         n_tiles = (jnp.max(pos) + k) // self.block_size + 1
         wmask = jnp.broadcast_to(act[:, None], positions.shape)
-        logits, kv, _ = self._forward_paged(params, kv, ids, positions,
-                                            tables, n_tiles, wmask)
+        logits, kv, _, _ = self._forward_paged(params, kv, ids, positions,
+                                               tables, n_tiles, wmask)
         t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         match = (draft_tok == t[:, :k]).astype(jnp.int32)
         n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
@@ -729,7 +801,8 @@ class PagedLlamaDecodeEngine:
         paged_attention seam ran — Pallas kernel vs jnp walk, decided
         once at program-build time (``_pa_kernel``), so the counters
         report what the compiled steps actually baked in."""
-        (_M_pa_kernel if self._pa_kernel else _M_pa_fallback).inc(n)
+        if self._pa_kernel is not None:
+            (_M_pa_kernel if self._pa_kernel else _M_pa_fallback).inc(n)
 
     def make_draft(self, model,
                    num_layers: Optional[int] = None
@@ -867,11 +940,17 @@ class PagedLlamaDecodeEngine:
     def _chunk_counts(self, start: int, tokens: int, bucket: int) -> dict:
         """What a prompt chunk's turn did, for the loop's span and
         flight event; with window layers, also the positions one of
-        them reads for these rows."""
+        them reads for these rows; with selected attention, the (row,
+        position) pairs a layer attends."""
         out = {"start": start, "tokens": tokens, "bucket": bucket}
         if self.window is not None:
             out["window_tokens"] = start + tokens \
                 - max(start - self.window + 1, 0)
+        if self.select_k is not None:
+            # sum over the rows of min(pos + 1, k)
+            below = max(min(self.select_k - start, tokens), 0)
+            out["selected_tokens"] = below * start \
+                + below * (below + 1) // 2 + (tokens - below) * self.select_k
         return out
 
     def _device_cow(self, slot: int, src: int, dst: int) -> None:
@@ -1131,22 +1210,24 @@ class PagedLlamaDecodeEngine:
                     if self.active[s]:
                         draft._shared_write_guard(s)
                         draft._kv.ensure_token(s, int(self.pos[s]))
-                _, draft.kvs = draft._decode(
+                _, draft.kvs, _ = draft._decode(
                     draft.params, draft.kvs, ids, pos,
                     draft._tables_dev(), act)
-            nxt, self.kvs = self._decode(
+            nxt, self.kvs, selected = self._decode(
                 self.params, self.kvs, ids, pos, tables, act)
         self._count_pa_path()
         self.pos[self.active] += 1
         if draft is not None:
             draft.pos[self.active] = self.pos[self.active]
         # what the launch was enqueued for: its result, the slots it
-        # steps, each in which of its activations, and the unfetched
-        # launches whose counts its fetch reads
+        # steps, each in which of its activations, the unfetched
+        # launches whose counts its fetch reads, and the positions its
+        # rows attended where the model selects them (on the device; no
+        # serving path fetches them)
         slots = np.flatnonzero(self.active)
         launch = {"out": nxt, "act": act, "slots": slots,
                   "activation": self._activation[slots],
-                  "aux": self._unfetched_aux()}
+                  "aux": self._unfetched_aux(), "selected": selected}
         self._ahead = launch
         return launch
 
@@ -1438,7 +1519,9 @@ class PagedLlamaDecodeEngine:
         artifact a serving process can run without this class (ref: the
         reference predictor's save/load of an analyzed program). The
         signature carries the block pools, per-slot block tables and
-        the active mask."""
+        the active mask; it returns what ``_decode_impl`` does: the
+        tokens, the pools and the selecting layers' positions (``{}``
+        for a model that selects none)."""
         avals = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             (self.params, self.kvs, jnp.asarray(self.last_ids),
@@ -1524,6 +1607,7 @@ class GenerationServer:
         self._cancel_waiting = False  # set by shutdown(drain=False)
         self.steps_run = 0
         self.launched_ahead = 0     # steps enqueued before the last's fetch
+        self._pool_gauged = None    # the pools' block counts last gauged
         # a model's counts that the collects returned, until a launch's
         # span takes them
         self._aux_carry: Dict[str, int] = {}
@@ -2191,20 +2275,26 @@ class GenerationServer:
         (each row's history and the token it writes), `max_ctx` the
         longest of them, and `walk_tokens`, what the paged kernel
         walks for them: each slot's `pos + 1` rounded up to the
-        kernel's group of blocks. `live_tokens / walk_tokens` is the
+        kernel's group of blocks (left out where the model's cache has
+        no pool that kernel walks). `live_tokens / walk_tokens` is the
         live share of the walk; `rows * max_ctx` is what walking every
         slot to the longest context cost."""
         eng = self.engine
         ctx = np.asarray(eng.pos)[np.asarray(eng.active, bool)] + 1
+        out = {"rows": int(ctx.size), "live_tokens": int(ctx.sum()),
+               "max_ctx": int(ctx.max()) if ctx.size else 0}
         # an engine that is not this module's has no kernel to ask
         group = getattr(eng, "walk_group_tokens", lambda: 1)()
-        out = {"rows": int(ctx.size), "live_tokens": int(ctx.sum()),
-               "max_ctx": int(ctx.max()) if ctx.size else 0,
-               "walk_tokens": int((-(-ctx // group) * group).sum())}
+        if group is not None:
+            out["walk_tokens"] = int((-(-ctx // group) * group).sum())
         window = getattr(eng, "window", None)
         if window is not None:
             # what a window layer reads of them: the last `window` each
             out["window_tokens"] = int(np.minimum(ctx, window).sum())
+        select_k = getattr(eng, "select_k", None)
+        if select_k is not None:
+            # what a layer that attends selected positions reads of them
+            out["selected_tokens"] = int(np.minimum(ctx, select_k).sum())
         return out
 
     def _sweep(self) -> None:
@@ -2501,6 +2591,12 @@ class GenerationServer:
         # see them (queue_seconds keeps accruing for them too)
         _G_queue.set(self._q.qsize() + len(self._waiting))
         _G_inflight.set(len(self._slots) + len(self._prefilling))
+        pools = getattr(self.engine, "pool_blocks_in_use", None)
+        if pools is not None:           # an engine of this module
+            blocks = pools()
+            if blocks != self._pool_gauged:     # only when a count moved
+                self._pool_gauged = blocks
+                self.engine._sc.set_pool_gauges(blocks)
 
     def shutdown(self, drain: bool = True,
                  timeout: Optional[float] = 300.0) -> bool:

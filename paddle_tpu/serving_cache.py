@@ -74,8 +74,9 @@ _M_evictions = _M.counter(
 _G_kind_blocks = _M.gauge(
     "kv_blocks_in_use",
     "Paged KV blocks mapped to slots, by the kind of layer whose table "
-    "holds them (full / window): a block of a kind is one block in "
-    "each of that kind's layers")
+    "holds them (`kind`: full / window; a block of a kind is one block "
+    "in each of that kind's layers) and by the pool they lie in (`pool`: "
+    "k, v, latent, index ...; summed over the layers that own one)")
 _M_window_freed = _M.counter(
     "kv_window_blocks_freed_total",
     "Blocks a window layer's table gave back because every position in "
@@ -946,6 +947,13 @@ class KindedKVCache:
 # device side: quantized block writes + tiled streaming attention
 # ---------------------------------------------------------------------------
 
+def set_pool_gauges(blocks: Dict[str, int]) -> None:
+    """``serving.kv_blocks_in_use{pool}`` from an engine's count of
+    mapped blocks by pool name (``pool_blocks_in_use``)."""
+    for name, n in blocks.items():
+        _G_kind_blocks.set(n, pool=name)
+
+
 def absmax_quantize(x, bits: int = 8):
     """Symmetric per-(token, head) absmax int8 of K/V rows
     ``[N, KVH, D]`` -> ``(codes int8 [N, KVH, D], scale f32 [N, KVH])``
@@ -1146,3 +1154,146 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 3, 1, 2, 4).reshape(S, T, H, D).astype(
         q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention over a latent pool: index scores over a paged
+# key pool, the top-k of each row, attention over the selected rows only
+# ---------------------------------------------------------------------------
+
+_SELECT_BITS = 2         # bits of the threshold a pass of the search fixes
+
+
+def _ordered_u32(x):
+    """float32 -> uint32, monotone: a larger float is a larger integer
+    (``-0.0`` first made ``0.0``, so equal floats are equal integers)."""
+    x = jnp.where(x == 0, 0.0, x).astype(jnp.float32)
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
+
+
+def select_topk(scores, valid, k: int):
+    """The selection rule of learned sparse attention, defined here once.
+
+    ``scores [..., N]`` float32, ``valid [..., N]`` bool. A row's selected
+    set is its ``k`` largest valid scores, a tie at the ``k``-th value
+    going to the LOWER position; every valid position where fewer than
+    ``k`` are valid. Returns ``(selected [..., N] bool, n_sel [...]
+    int32)``. Exact, and free of a sort: the ``k``-th largest value is
+    found by a search over the bits of the float (``_SELECT_BITS`` a pass,
+    each pass one counting read of the row: at 2 bits 16 reads and 3
+    compares an element, against 8 and 15 at 4 bits, which the v5e's VPU
+    took 0.69 ms a pass for at ``[512, 51200]``); the positions above it
+    and the first ties at it are kept, the ties ranked only where some
+    row has more of them than it has room for."""
+    k = int(k)
+    lead = scores.shape[:-1]
+    u = jnp.where(valid, _ordered_u32(scores), jnp.uint32(0))
+    prefix = jnp.zeros(lead, jnp.uint32)
+    steps = jnp.arange(1, 1 << _SELECT_BITS, dtype=jnp.uint32)
+    for shift in range(32 - _SELECT_BITS, -1, -_SELECT_BITS):
+        cands = prefix[..., None] | (steps << shift)   # [..., 2**bits - 1]
+        above = jnp.sum(u[..., None, :] >= cands[..., :, None], axis=-1,
+                        dtype=jnp.int32)
+        # counts fall as the candidate grows: those with k or more at or
+        # above them are a prefix of the candidates
+        prefix = prefix | (jnp.sum(above >= k, axis=-1).astype(jnp.uint32)
+                           << shift)
+    # prefix: the k-th largest value (0 where fewer than k are valid)
+    gt = u > prefix[..., None]
+    eq = (u == prefix[..., None]) & valid
+    room = k - jnp.sum(gt, axis=-1, dtype=jnp.int32)
+    crowded = jnp.any(jnp.sum(eq, axis=-1, dtype=jnp.int32) > room)
+    sel = jax.lax.cond(
+        crowded,
+        lambda: gt | (eq & (jnp.cumsum(eq, axis=-1, dtype=jnp.int32)
+                            <= room[..., None])),
+        lambda: gt | eq)
+    return sel, jnp.sum(sel, axis=-1, dtype=jnp.int32)
+
+
+def positions_bitset(selected):
+    """``[..., N]`` bool -> ``[..., ceil(N / 32)]`` uint32: position ``p`` is
+    bit ``p % 32`` of word ``p // 32``. What a launch hands back of a
+    selection (a 51,200-position row in 6.4 KB); ``bitset_positions`` reads
+    it on the host."""
+    n = selected.shape[-1]
+    words = -(-n // 32)
+    bits = jnp.pad(selected, [(0, 0)] * (selected.ndim - 1)
+                   + [(0, words * 32 - n)])
+    bits = bits.reshape(selected.shape[:-1] + (words, 32)).astype(jnp.uint32)
+    return jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                   dtype=jnp.uint32)
+
+
+def bitset_positions(words) -> np.ndarray:
+    """The positions set in one row of ``positions_bitset`` (host side)."""
+    words = np.ascontiguousarray(np.asarray(words), dtype="<u4")
+    return np.flatnonzero(np.unpackbits(words.view(np.uint8),
+                                        bitorder="little"))
+
+
+def write_rows(pools, rows, positions, tables, wmask, block_size: int):
+    """Scatter one row a token into each named pool: ``pools {name:
+    [num_blocks, block_size, width]}``, ``rows {name: [S, T, width]}`` at
+    ``positions [S, T]`` through ``tables [S, max_blocks]``. Rows with
+    ``wmask`` False or an unmapped table entry are dropped (an index past
+    the pool), so padding and inactive slots never touch a real block.
+    Returns the pools named in ``rows``, written."""
+    S, T = positions.shape
+    bidx = jnp.minimum(positions // block_size, tables.shape[1] - 1)
+    phys = jnp.take_along_axis(tables, bidx, axis=1)
+    ok = jnp.logical_and(wmask, phys >= 0)
+    off = (positions % block_size).reshape(-1)
+    out = {}
+    for name, vals in rows.items():
+        pool = pools[name]
+        p = jnp.where(ok, phys, pool.shape[0]).reshape(-1)
+        out[name] = write_kv_tokens(pool, p, off, vals.reshape(S * T, -1))
+    return out
+
+
+def paged_index_scores(q, w, k_pool, tables, positions, *, block_size: int,
+                       use_kernel=None):
+    """Indexer scores of each query row over its slot's paged key pool.
+
+    ``q [S, T, J, D]``, ``w [S, T, J]`` (float32 head weights), ``k_pool
+    [num_blocks, block_size, D]``, ``tables [S, max_blocks]``, ``positions
+    [S, T]``. Returns ``(scores [S, T, N] float32, valid [S, T, N])`` with
+    ``N >= max_blocks * block_size`` (whole key tiles): ``scores[s, t, c]
+    = sum_j w_j relu(q_j . k_c)`` and ``valid`` where ``c <= positions[s,
+    t]`` lies in a mapped block. A slot's keys are gathered through its
+    table row into position order (XLA), then scored by
+    ``ops.pallas.sparse_latent.index_scores``."""
+    from .ops.pallas import sparse_latent as _sl
+    S, MB = tables.shape
+    per = _sl.KEY_TILE // block_size if _sl.KEY_TILE % block_size == 0 else 1
+    pad = -MB % per
+    tab = jnp.pad(tables, ((0, 0), (0, pad)), constant_values=-1)
+    N = (MB + pad) * block_size
+    keys = k_pool[jnp.maximum(tab, 0)].reshape(S, N, k_pool.shape[-1])
+    scores = _sl.index_scores(q, w, keys, positions, use_kernel=use_kernel)
+    cols = jnp.arange(N)
+    mapped = jnp.repeat(tab >= 0, block_size, axis=1)            # [S, N]
+    valid = (cols[None, None, :] <= positions[:, :, None]) \
+        & mapped[:, None, :]
+    return scores, valid
+
+
+def paged_latent_attention(q, pool, tables, selected, positions, *,
+                           block_size: int, rank: int, scale: float,
+                           use_kernel=None):
+    """Absorbed latent attention over the SELECTED rows of a paged pool.
+
+    ``q [S, T, H, W]`` (each head's query folded through the key
+    up-projection, then its rope part, zero-padded to ``W``), ``pool
+    [num_blocks, block_size, W]`` rows ``[c_kv ; k_rope ; 0]``, ``selected
+    [S, T, N]`` the positions each row attends (``select_topk``). Returns
+    ``[S, T, H, rank]``: the softmax runs over the selected positions and
+    no other; a slot's blocks are walked through its table row under that
+    mask (``ops.pallas.sparse_latent.latent_attention``, which says why a
+    walk and not a gather)."""
+    from .ops.pallas import sparse_latent as _sl
+    return _sl.latent_attention(q, pool, tables, selected, positions,
+                                block_size=block_size, rank=rank, scale=scale,
+                                use_kernel=use_kernel)

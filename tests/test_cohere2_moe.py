@@ -151,7 +151,7 @@ def _engine(cfg, **kw):
 
 def _step_logits(eng):
     """The logits the next decode step computes, without running it."""
-    logits, _, _ = eng._forward_paged(
+    logits, _, _, _ = eng._forward_paged(
         eng.params, eng.kvs, jnp.asarray(eng.last_ids),
         jnp.asarray(eng.pos)[:, None], eng._tables_dev(),
         jnp.max(jnp.asarray(eng.pos)) // eng.block_size + 1,

@@ -156,8 +156,8 @@ class TestPagedVsModel:
                 jnp.asarray(eng.pos),
                 jnp.asarray(eng._kv.block_tables),
                 jnp.asarray(eng.active))
-        nxt_aot, _ = rebuilt.call(*args)
-        nxt_live, _ = jax.jit(eng._decode_impl)(*args)
+        nxt_aot, _, _ = rebuilt.call(*args)
+        nxt_live, _, _ = jax.jit(eng._decode_impl)(*args)
         assert int(nxt_aot[0]) == int(nxt_live[0])
 
     def test_no_dense_view_in_paged_attention(self, model):
@@ -464,7 +464,7 @@ class TestPagedCapture:
 
         def one_captured_step():
             eng._extend_tables()
-            nxt, eng.kvs = eng._decode(
+            nxt, eng.kvs, _ = eng._decode(
                 eng.params, eng.kvs, jnp.asarray(eng.last_ids),
                 jnp.asarray(eng.pos), jnp.asarray(eng._kv.block_tables),
                 jnp.asarray(eng.active))

@@ -47,7 +47,7 @@ def test_served_llama_logits_match_the_training_model(kv_heads, tied):
     padded = np.zeros((1, bucket), np.int32)
     padded[0, :n] = ids
     offs = jnp.arange(bucket)
-    logits, _, aux = eng._forward_paged(
+    logits, _, aux, _ = eng._forward_paged(
         eng.params, eng.kvs, jnp.asarray(padded), offs[None, :],
         eng._tables_dev(slot)[None, :], (n - 1) // eng.block_size + 1,
         (offs < n)[None, :])

@@ -195,3 +195,63 @@ def test_expert_rows_matmul_compiles_for_the_v5e(one_chip, name):
     assert "tpu_custom_call" in text
     # the trace and the benchmark's readers find it by this
     assert "_expert_rows_matmul_call" in text
+
+
+# GLM-5.2's served shapes (64 heads over one latent row of 640 = 576 padded,
+# 32 index heads of 128, blocks of 64, 7968 blocks, 776 a slot): a 32-slot
+# decode step, a 512-row chunk (the latent kernel takes it 16 rows at a time),
+# the smallest chunk
+DSA = {"decode_32_slots": (32, 1), "chunk_512": (1, 512), "chunk_8": (1, 8)}
+
+
+@pytest.mark.parametrize("name", sorted(DSA))
+def test_the_sparse_latent_kernels_compile_for_the_v5e(one_chip, name):
+    from paddle_tpu.ops.pallas import sparse_latent as sl
+    S, T = DSA[name]
+    bs, NB, MB, N = 64, 7968, 776, 51200
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    compiled = jax.jit(lambda q, w, keys, pos: sl.index_scores(
+        q, w, keys, pos, use_kernel=True)).lower(
+        sds((S, T, 32, 128), "bfloat16"), sds((S, T, 32), "float32"),
+        sds((S, N, 128), "bfloat16"), sds((S, T), "int32")).compile()
+    # the trace and the benchmark's readers find the kernels by these names
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "_index_score_call" in compiled.as_text()
+    compiled = jax.jit(lambda q, pool, tables, mask, pos: sl.latent_attention(
+        q, pool, tables, mask, pos, block_size=bs, rank=512, scale=1 / 16,
+        use_kernel=True)).lower(
+        sds((S, T, 64, 640), "bfloat16"), sds((NB, bs, 640), "bfloat16"),
+        sds((S, MB), "int32"), sds((S, T, N), "bool"),
+        sds((S, T), "int32")).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "_latent_attention_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", [640, 128])
+def test_no_launch_copies_a_latent_or_an_index_pool(one_chip, width):
+    """A row a token written into a donated pool of whole 128-lane rows lands
+    in place, in the layout the pool came in. (A latent row of 576 does not:
+    compiled for the v5e a `[NB, bs, 576]` pool comes out `{0,2,1}` and is
+    copied whole into and out of every launch, which is why the model pads
+    its row to 640.)"""
+    NB, bs, MB = 7968, 64, 776
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    def launch(pools, rows, tables, pos):
+        return sc.write_rows(pools, {"p": rows}, pos, tables,
+                             jnp.ones(pos.shape, bool), bs)
+
+    compiled = jax.jit(launch, donate_argnums=(0,)).lower(
+        {"p": sds((NB, bs, width), "bfloat16")}, sds((32, 1, width), "bfloat16"),
+        sds((32, MB), "int32"), sds((32, 1), "int32")).compile()
+    made = re.findall(r"= \(?\w+\[%d,%d,%d\]\S* ([\w-]+)\(" % (NB, bs, width),
+                      compiled.as_text())
+    assert set(made) <= {"parameter", "scatter", "fusion"}, sorted(set(made))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == NB * bs * width * 2
+    assert mem.temp_size_in_bytes < 1 << 20
