@@ -10,15 +10,29 @@ online softmax, and walks only what is live:
   block count (``max_t positions[s, t] // block_size + 1``, capped by
   the caller's ``n_tiles``) are scalar-prefetched to SMEM; the pools
   stay in HBM (``memory_space=pl.ANY``);
-- inside a step a ``fori_loop`` runs over that slot's blocks a group
-  of ``C`` at a time (``group_blocks``: from static shapes, aiming at
-  ``_GROUP_TOKENS`` tokens). Each loop step starts the async copies of
-  the next group (one a block, K and V, ids read from the table: one
-  block is one contiguous ``[bs, KVH*D]`` slab, so one copy brings all
-  KV heads) into the other half of a two-slot VMEM buffer, waits for
-  its own, and computes on it as one ``[C*bs, KVH*D]`` tile;
+- a slot's blocks are walked a group of ``C`` at a time
+  (``group_blocks``: from static shapes, aiming at ``_GROUP_TOKENS``
+  tokens): the async copies of a group (one a block, K and V, ids read
+  from the table: one block is one contiguous ``[bs, KVH*D]`` slab, so
+  one copy brings all KV heads) land in one half of a two-half VMEM
+  buffer and are computed on as one ``[C*bs, KVH*D]`` tile;
+- the halves alternate over (slot, group) across grid steps (scratch
+  and semaphores outlive a step), so the copies of a slot's first
+  group are in flight while the slot before it is computed;
+- what a copy costs this kernel is its START (a block is 16 KB a
+  stream: the scalar core spends longer describing a group's copies
+  than the DMA engines moving them). Where a slot is narrow (``T *
+  n_rep`` query rows a KV head up to ``_NARROW_ROWS``: a decode call,
+  whose arithmetic a group is about as long as the starts of its copies)
+  half a group's starts are straight-line code inside the arithmetic
+  of the group before, the rest a loop of four a pass, and a group is
+  waited for with six waits at most, not one a block. A wide slot (a
+  prompt chunk, whose arithmetic dwarfs all this) keeps the plain
+  loops: every line here is traced and lowered again by every serving
+  process, once a program;
 - a slot's last group is ragged: blocks past the slot's count are not
-  fetched, their columns are masked;
+  fetched, their columns are masked. A slot with no block at all
+  (``n_tiles`` 0) starts no copy of its own and computes nothing;
 - with ``lower`` (a window layer: each row's first visible position)
   the walk starts at the slot's first live block, ``min_t lower[s, t]
   // block_size``, and masks the ragged head of it. Without it nothing
@@ -47,7 +61,11 @@ tests/test_serving_spec.py):
   zeroed before the PV dot.
 
 ``interpret=True`` runs the same kernel through the Pallas interpreter
-— how the CPU parity test asserts same-numerics without a TPU.
+— how the CPU parity test asserts same-numerics without a TPU. That
+interpreter copies at a DMA's start and takes every wait as done;
+``interpret=pltpu.InterpretParams()`` counts a semaphore's bytes as the
+chip does (a wait for more than was started blocks, one for less leaves
+the data behind), which is how tier 1 holds the starts and waits above.
 """
 from __future__ import annotations
 
@@ -70,6 +88,17 @@ _LANES = 128
 _GROUP_TOKENS = 512
 _KV_VMEM_BUDGET = 4 * 1024 * 1024
 _SCORE_VMEM_BUDGET = 1024 * 1024
+# query rows a KV head (T * n_rep) up to which a slot is narrow: one packed
+# register tile of bf16 rows, which is a decode call (8 rows at Yi's heads,
+# 16 at Command A+'s). There a group's arithmetic is about as long as the
+# starts of its copies (1.06 against 1.3 us at 8 rows and 32 blocks of 16
+# tokens on the v5e) and the starts are worth more code. Every line of the
+# kernel is traced and lowered again by every process that serves, once a
+# program (the cells' set-up time shows it: a second and more a narrow
+# program), so the prompt chunks' programs keep the plain loops
+_NARROW_ROWS = 16
+# blocks a pass of the loop that starts a narrow slot's copies takes
+_START_UNROLL = 4
 
 
 def kernel_available(head_dim: int, interpret: bool = False) -> bool:
@@ -142,19 +171,29 @@ def group_tokens(block_size: int, *shapes) -> int:
     return block_size * group_blocks(block_size, *shapes)
 
 
-def _kernel(tables_ref, nblk_ref, *refs, block_size, C, kvh, head_dim,
-            dequant, cdtype, bounded):
+def _kernel(tables_ref, nblk_ref, *refs, block_size, C, narrow, kvh,
+            head_dim, dequant, cdtype, bounded):
     """One slot's program. Scalar-prefetch refs (SMEM): the block table
     and each slot's live block count (and, ``bounded``, its first live
-    block). Tensor refs: q [1, K, T*R, D] and row positions [1, T*R, 1]
-    (and, ``bounded``, the rows' first visible positions, same shape) in
-    VMEM | K and V pools [NB, bs, K*D], the layout they are allocated
-    in (one block is one contiguous slab, so one copy brings all KV
-    heads), and their scales [NB, bs, K], left in HBM | out
-    [1, K, T*R, D]. Scratch: the two-slot group
-    buffers [2, C, bs, .] the DMAs land in, their semaphores [stream,
-    slot], and the float32 online-softmax state m/l [K, T*R, 1], acc
-    [K, T*R, D]."""
+    block), with a zero past the last slot. Tensor refs: q [1, K, T*R, D]
+    and row positions [1, T*R, 1] (and, ``bounded``, the rows' first
+    visible positions, same shape) in VMEM | K and V pools [NB, bs, K*D],
+    the layout they are allocated in (one block is one contiguous slab,
+    so one copy brings all KV heads), and their scales [NB, bs, K], left
+    in HBM | out [1, K, T*R, D]. Scratch (it outlives a grid step): the
+    two-half group buffers [2, C, bs, .] the DMAs land in, their
+    semaphores [stream, half], the float32 online-softmax state m/l
+    [K, T*R, 1], acc [K, T*R, D], and in SMEM the half that the next
+    group to be computed lands in.
+
+    The halves alternate over (slot, group) in the order the groups are
+    computed, across grid steps: under a group's arithmetic runs the
+    copy of the slot's next group or, under its last group, of the NEXT
+    slot's first one. Only the call's first slot starts its own first
+    group. A slot with no block (a count of 0) starts no copy of its own
+    and computes nothing; it hands the start of the next slot's first
+    group on. ``narrow`` (static) chooses how a group's copies are
+    started and waited for: see the module's text."""
     if bounded:
         first_ref, refs = refs[0], refs[1:]
     n = 4 if dequant else 2                # K, V (and their scales)
@@ -164,63 +203,112 @@ def _kernel(tables_ref, nblk_ref, *refs, block_size, C, kvh, head_dim,
     o_ref = refs[2 + n]
     bufs = refs[3 + n:3 + 2 * n]
     streams = tuple(zip(refs[2:2 + n], bufs))
-    sems, m_s, l_s, acc_s = refs[3 + 2 * n:]
+    sems, m_s, l_s, acc_s, half_ref = refs[3 + 2 * n:]
     s = pl.program_id(0)
     D, G = head_dim, C * block_size
-    nb = nblk_ref[s]                       # this slot's own blocks
-    if bounded:
-        # a window layer's walk: blocks [b0, nb), b0 the first live one
-        b0 = jnp.minimum(first_ref[s], nb)
-        n_groups = (nb - b0 + C - 1) // C
-    else:
-        n_groups = (nb + C - 1) // C
+    # blocks of a group whose copies are started from inside the
+    # arithmetic of the group before it
+    NI = C // 2 if narrow else 0
 
-    def copies(g, half, act):
-        """Start, or wait for, the copy of every live block of group
-        ``g`` into buffer ``half`` (blocks past the slot's count are
-        never fetched)."""
+    def walk(s):
+        """Slot ``s``'s own blocks [b0, nb) and their groups (a window
+        layer's walk starts at its first live block)."""
+        nb = nblk_ref[s]
+        b0 = jnp.minimum(first_ref[s], nb) if bounded else 0
+        return nb, b0, (nb - b0 + C - 1) // C
+
+    def start_block(s, b0, g, half, j):
+        # unmapped (-1) entries clamp to block 0, as the jnp walk's
+        # max(tables, 0): the mask is by position only
+        blk = jnp.maximum(tables_ref[s, b0 + g * C + j], 0)
+        for i, (hbm, buf) in enumerate(streams):
+            pltpu.make_async_copy(
+                hbm.at[blk], buf.at[half, j], sems.at[i, half]).start()
+
+    def live(nb, b0, g):
+        """Blocks of a slot's group ``g`` that are fetched (those past
+        the slot's count never are: none where it has no group ``g``)."""
+        return jnp.clip(nb - b0 - g * C, 0, C)
+
+    def start(s, nb, b0, g, half, frm=0, unroll=1):
+        """Start the copies of slot ``s``'s group ``g`` into buffer
+        ``half``, from its block ``frm`` on, ``unroll`` blocks a pass
+        of the loop (a copy's start is some twenty scalar instructions,
+        and a pass of one block spends as long on the loop as on the
+        start)."""
+        cnt = live(nb, b0, g)
+
+        def many(jj, carry):
+            return jax.lax.fori_loop(
+                0, unroll, lambda u, c: start_block(
+                    s, b0, g, half, frm + jj * unroll + u) or c,
+                carry, unroll=True)
+
         def one(j, carry):
-            # unmapped (-1) entries clamp to block 0, as the jnp
-            # walk's max(tables, 0): the mask is by position only
-            idx = g * C + j
-            if bounded:
-                idx = idx + b0
-            blk = jnp.maximum(tables_ref[s, idx], 0)
-            for i, (hbm, buf) in enumerate(streams):
-                getattr(pltpu.make_async_copy(
-                    hbm.at[blk], buf.at[half, j], sems.at[i, half]),
-                    act)()
+            start_block(s, b0, g, half, j)
             return carry
-        left = nb - g * C
-        if bounded:
-            left = left - b0
-        jax.lax.fori_loop(0, jnp.minimum(C, left), one, 0)
+        if unroll > 1:
+            n_many = jnp.maximum(cnt - frm, 0) // unroll
+            jax.lax.fori_loop(0, n_many, many, 0)
+            frm = frm + n_many * unroll
+        jax.lax.fori_loop(frm, cnt, one, 0)
+
+    def wait(nb, b0, g, half):
+        """Wait for every copy of a group into buffer ``half``. A
+        stream's copies signal one semaphore and a semaphore counts
+        bytes, so a narrow slot waits for a count of ``n`` blocks as its
+        binary digits: 2^k blocks at a time, six waits at most where a
+        wait a block was up to 32."""
+        cnt = live(nb, b0, g)
+        if not narrow:
+            def one(j, carry):
+                for i, (hbm, buf) in enumerate(streams):
+                    pltpu.make_async_copy(
+                        hbm.at[0], buf.at[half, j], sems.at[i, half]).wait()
+                return carry
+            jax.lax.fori_loop(0, cnt, one, 0)
+            return
+        for bit in reversed(range(C.bit_length())):
+            @pl.when((cnt >> bit) & 1 == 1)
+            def _wait_some(n=1 << bit):
+                for i, (_, buf) in enumerate(streams):
+                    # (only the size of what is named is read)
+                    part = buf.at[half, pl.ds(0, n)]
+                    pltpu.make_async_copy(
+                        part, part, sems.at[i, half]).wait()
+
+    nb, b0, n_groups = walk(s)
+    nb1, b01, _ = walk(s + 1)
+
+    @pl.when(s == 0)
+    def _first():
+        half_ref[0] = 0
+        start(0, nb, b0, 0, 0)
+
+    # where this slot's first group lands
+    half0 = half_ref[0]
+
+    @pl.when(n_groups == 0)
+    def _hand_on():
+        start(s + 1, nb1, b01, 0, half0)
 
     m_s[...] = jnp.full_like(m_s, _NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
     acc_s[...] = jnp.zeros_like(acc_s)
-
-    @pl.when(n_groups > 0)
-    def _first():
-        copies(0, 0, "start")
-
     posv = pos_ref[0]                                  # [T*R, 1]
     scale = 1.0 / float(np.sqrt(D))
     # operands go to the MXU in the pool's dtype, accumulation is float32
     prec = _dot_precision(jnp.promote_types(q_ref.dtype, cdtype))
 
-    def group(g, carry):
-        half = g % 2
-
-        @pl.when(g + 1 < n_groups)
-        def _prefetch():
-            copies(g + 1, 1 - half, "start")
-
-        copies(g, half, "wait")
-        cols = g * G + jax.lax.broadcasted_iota(
+    def attend(g, half, under=None):
+        """Fold group ``g``, in buffer ``half``, into the slot's online
+        softmax. ``under`` names a (slot, first block, group, half)
+        whose first ``NI`` copies are started from inside this
+        arithmetic, a share a KV head: straight-line code beside the
+        dots, which the scheduler packs into the same instructions,
+        where a loop before them runs alone."""
+        cols = (b0 * block_size + g * G) + jax.lax.broadcasted_iota(
             jnp.int32, (posv.shape[0], G), 1)
-        if bounded:
-            cols = cols + b0 * block_size
         # a column is attended up to the row's position, and only where
         # the walk reaches (the caller's n_tiles may stop it short)
         ok = (cols <= posv) & (cols < nb * block_size)  # [T*R, G]
@@ -263,11 +351,45 @@ def _kernel(tables_ref, nblk_ref, *refs, block_size, C, kvh, head_dim,
                 precision=prec,
                 preferred_element_type=jnp.float32)    # [T*R, D]
             m_s[h] = m_new
+            if under is not None:
+                # (unrolled where it is lowered: traced once, no loop)
+                jax.lax.fori_loop(
+                    h * NI // kvh, (h + 1) * NI // kvh,
+                    lambda jj, c: start_block(*under, jj) or c, 0,
+                    unroll=True)
+
+    def group(g, carry):
+        half = (half0 + g) % 2
+        # in flight while this group is computed: the slot's next group
+        # or, after its last, the next slot's first
+        last = g + 1 == n_groups
+        ns, nnb = jnp.where(last, s + 1, s), jnp.where(last, nb1, nb)
+        nb0, ng = jnp.where(last, b01, b0), jnp.where(last, 0, g + 1)
+        if not NI:
+            start(ns, nnb, nb0, ng, 1 - half)
+            wait(nb, b0, g, half)
+            attend(g, half)
+            return carry
+        # a next group of NI blocks or more has its first NI started
+        # under this group's arithmetic, the rest before it
+        under = live(nnb, nb0, ng) >= NI
+        start(ns, nnb, nb0, ng, 1 - half, jnp.where(under, NI, 0),
+              unroll=_START_UNROLL)
+        wait(nb, b0, g, half)
+
+        @pl.when(under)
+        def _attend_and_start():
+            attend(g, half, under=(ns, nb0, ng, 1 - half))
+
+        @pl.when(jnp.logical_not(under))
+        def _attend():
+            attend(g, half)
         return carry
 
     jax.lax.fori_loop(0, n_groups, group, 0)
     o_ref[0] = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)).astype(
         o_ref.dtype)
+    half_ref[0] = (half0 + n_groups) % 2
 
 
 @functools.partial(
@@ -285,13 +407,16 @@ def _paged_attention_call(q, k_pool, v_pool, tables, positions,
     # int8 pools dequantise to the queries' dtype, as the jnp walk does
     cdtype = q.dtype if dequant else k_pool.dtype
     kernel = functools.partial(
-        _kernel, block_size=block_size, C=C, kvh=K, head_dim=D,
-        dequant=dequant, cdtype=cdtype, bounded=bounded)
+        _kernel, block_size=block_size, C=C, narrow=TR <= _NARROW_ROWS,
+        kvh=K, head_dim=D, dequant=dequant, cdtype=cdtype, bounded=bounded)
     positions = positions.astype(jnp.int32)
     # each slot walks its own blocks only: its longest row's, capped
-    # by the caller's n_tiles and by the table
-    nblk = jnp.minimum(jnp.max(positions, axis=1) // block_size + 1,
-                       jnp.minimum(jnp.asarray(n_tiles, jnp.int32), MB))
+    # by the caller's n_tiles and by the table. A zero past the last
+    # slot: the slot after it, whose first group nobody has to start
+    pad = (0, 1)
+    nblk = jnp.pad(jnp.minimum(
+        jnp.max(positions, axis=1) // block_size + 1,
+        jnp.minimum(jnp.asarray(n_tiles, jnp.int32), MB)), pad)
     # rows of one KV head together, (t, r)-major: q is laid out once
     # here, not once a tile in the kernel
     q_rows = q.reshape(S, T, K, R, D).transpose(0, 2, 1, 3, 4).reshape(
@@ -305,7 +430,7 @@ def _paged_attention_call(q, k_pool, v_pool, tables, positions,
     prefetch = [tables.astype(jnp.int32), nblk]
     if bounded:
         lower = jnp.maximum(lower.astype(jnp.int32), 0)
-        prefetch.append(jnp.min(lower, axis=1) // block_size)
+        prefetch.append(jnp.pad(jnp.min(lower, axis=1) // block_size, pad))
         in_specs.append(pos_spec)
         args.append(jnp.repeat(lower, R, axis=1)[..., None])
     n_rows = len(args)
@@ -317,15 +442,16 @@ def _paged_attention_call(q, k_pool, v_pool, tables, positions,
     if dequant:
         # Mosaic copies whole 128-lane rows only: the [NB, bs, K] scales
         # (lane-padded in HBM as they are) get their lanes made explicit
-        pad = ((0, 0), (0, 0), (0, -K % _LANES))
+        lanes = ((0, 0), (0, 0), (0, -K % _LANES))
         in_specs += [hbm, hbm]
-        args += [jnp.pad(k_scale, pad), jnp.pad(v_scale, pad)]
+        args += [jnp.pad(k_scale, lanes), jnp.pad(v_scale, lanes)]
         scratch += [pltpu.VMEM((2, C) + args[-1].shape[1:], sc.dtype)
                     for sc in (k_scale, v_scale)]
     scratch += [pltpu.SemaphoreType.DMA((len(args) - n_rows, 2)),
                 pltpu.VMEM((K, TR, 1), jnp.float32),
                 pltpu.VMEM((K, TR, 1), jnp.float32),
-                pltpu.VMEM((K, TR, D), jnp.float32)]
+                pltpu.VMEM((K, TR, D), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(S,),
@@ -362,4 +488,5 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, positions, *,
     return _paged_attention_call(
         q, k_pool, v_pool, tables, positions, n_tiles, k_scale,
         v_scale, lower, block_size=int(block_size), n_rep=int(n_rep),
-        interpret=bool(interpret))
+        interpret=interpret if isinstance(
+            interpret, pltpu.InterpretParams) else bool(interpret))

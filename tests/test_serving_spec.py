@@ -412,27 +412,89 @@ class TestPagedAttentionKernelSeam:
         "n_tiles_one_block_short": (
             3, 1, 4, 2, 8, 16, ["last", 40, "group"], "float32",
             {"n_tiles": -1}),
+        # a slot's first group is started while the slot before it is
+        # computed, into the half that slot's last group leaves free:
+        # "groups" tables of that many groups and four blocks, so slots
+        # of 1 to 4 groups sit side by side in every order
+        "slots_5": (
+            5, 1, 4, 2, 8, 16, ["last", 7, "group", 100, "group*2"],
+            "float32", {"groups": 3}),
+        "slots_33": (
+            33, 1, 4, 2, 8, 16,
+            [0, "group-1", "group", 40, "last", 300, "group*2"] * 4
+            + [9, "last", 0, "group", 511], "float32", {"groups": 2}),
+        "slots_yi_bf16_9": (
+            9, 1, 32, 4, 128, 16,
+            [5, 200, "last", 0, 300, "group", 17, 40, "group-1"],
+            "bfloat16", {}),
+        "slots_position_0_among_long_slots": (
+            4, 1, 4, 2, 8, 16, ["last", 0, "last", "group*2"], "float32",
+            {"groups": 3}),
+        # every block count 0: no copy is started, zeros come out
+        "slots_no_block_at_all": (
+            5, 1, 4, 2, 8, 16, ["last", 0, "group", 100, 3], "float32",
+            {"n_tiles": 0}),
+        "slots_many_groups_then_one": (
+            6, 1, 4, 2, 8, 16,
+            ["group*3-1", 5, "last", 5, "group*2", 9], "float32",
+            {"groups": 3}),
+        "slots_one_group_then_many": (
+            6, 1, 4, 2, 8, 16,
+            [5, "last", 9, "group*3-1", 3, "group*2"], "float32",
+            {"groups": 3}),
+        "slots_verify_rows_mixed_groups": (
+            5, 5, 4, 2, 8, 16, ["group*2", 0, "last", "group-1", 11],
+            "float32", {"groups": 3}),
+        # wide slots (over _NARROW_ROWS query rows a KV head, as a
+        # chunk's tiles are) keep the plain starts and a wait a block
+        "slots_wide_rows_mixed_groups": (
+            4, 20, 4, 2, 8, 16, ["group*2", 0, "last", 700], "float32",
+            {"groups": 3}),
+        # a window layer's walk starts at the slot's first live block
+        "slots_window_many_groups_then_one": (
+            6, 1, 4, 2, 8, 16,
+            ["group*3-1", 5, "last", 5, "group*2", 9], "float32",
+            {"groups": 3, "window": 1100}),
+        "slots_window_one_group_then_many": (
+            6, 1, 4, 2, 8, 16,
+            [5, "last", 9, "group*3-1", 3, "group*2"], "float32",
+            {"groups": 3, "window": 300}),
+        # a slot whose rows see nothing (their first visible position
+        # lies past them) has no group: it starts the next slot's first
+        # copy in its stead
+        "slots_window_slots_that_see_nothing": (
+            6, 1, 4, 2, 8, 16,
+            [5, "last", 9, "group*3-1", 3, "group*2"], "float32",
+            {"groups": 3, "window": 300, "sees_nothing": [0, 3, 4]}),
+        "slots_int8_many_groups_then_one": (
+            6, 1, 4, 2, 8, 16,
+            ["group*3-1", 5, "last", 5, "group*2", 9], "float32",
+            {"groups": 3, "quant": True}),
+        "slots_int8_one_group_then_many": (
+            6, 1, 4, 2, 8, 16,
+            [5, "last", 9, "group*3-1", 3, "group*2"], "float32",
+            {"groups": 3, "quant": True}),
     }
 
-    @pytest.mark.parametrize("name", sorted(RAGGED))
-    def test_kernel_matches_jnp_walk_on_ragged_batches(self, name):
-        """Each slot walks its own blocks only, a group at a time:
-        every way a slot's history can sit against the groups, at the
-        served geometries, agrees with the jnp walk."""
+    def _ragged_case(self, name):
+        """(q, pools, tables, positions, the seam's keywords) of a
+        RAGGED case, seeded by its place in the list."""
         import jax.numpy as jnp
         from paddle_tpu.ops.pallas import paged_attention as pk
-        from paddle_tpu.serving_cache import (absmax_quantize,
-                                              paged_attention)
+        from paddle_tpu.serving_cache import absmax_quantize
         S, T, H, K, D, bs, first, dtype, extra = self.RAGGED[name]
         rng = np.random.default_rng(sorted(self.RAGGED).index(name))
         dt = jnp.dtype(dtype)
         quant = bool(extra.get("quant"))
         geo = (bs, K * D, jnp.int8 if quant else dt, T, H // K)
-        MB = pk.group_tokens(*geo, 1 << 20, quant) // bs + 4
-        group = pk.group_tokens(*geo, MB, quant)
+        group = pk.group_tokens(*geo, 1 << 20, quant)
+        MB = extra.get("groups", 1) * group // bs + 4
+        assert group == pk.group_tokens(*geo, MB, quant)
         assert bs < group < bs * MB, "several blocks, several groups"
         where = {"block-2": bs - 2, "group-1": group - 1, "group": group,
-                 "group-41": group - 41, "last": bs * MB - T}
+                 "group-41": group - 41, "group*2": 2 * group,
+                 "group*3-1": 3 * group - 1, "last": bs * MB - T}
+        assert len(first) == S
         pos = (np.asarray([where.get(f, f) for f in first],
                           np.int32)[:, None]
                + np.arange(T, dtype=np.int32)[None, :])
@@ -452,24 +514,107 @@ class TestPagedAttentionKernelSeam:
         if "n_tiles" in extra:
             kw["n_tiles"] = jnp.asarray(extra["n_tiles"] % (MB + 1),
                                         jnp.int32)
+        if "window" in extra:
+            lower = pos - (extra["window"] - 1)
+            for s_ in extra.get("sees_nothing", ()):
+                lower[s_] = pos[s_] + 2 * bs
+            kw["lower"] = jnp.asarray(lower)
+            # blocks wholly behind a slot's window are gone, as the
+            # table frees them
+            for s_ in range(S):
+                tables[s_, :max(int(pos[s_, 0]) - extra["window"] + 1,
+                                0) // bs] = -1
         if quant:
             kq, ks = absmax_quantize(kp.reshape(NB * bs, K, D))
             vq, vs = absmax_quantize(vp.reshape(NB * bs, K, D))
             kw.update(k_scale=ks.reshape(NB, bs, K),
                       v_scale=vs.reshape(NB, bs, K))
             kp, vp = kq.reshape(kp.shape), vq.reshape(vp.shape)
-        tables, pos = jnp.asarray(tables), jnp.asarray(pos)
+        return q, kp, vp, jnp.asarray(tables), jnp.asarray(pos), kw
+
+    @pytest.mark.parametrize("name", sorted(RAGGED))
+    def test_kernel_matches_jnp_walk_on_ragged_batches(self, name):
+        """Each slot walks its own blocks only, a group at a time:
+        every way a slot's history can sit against the groups, and a
+        slot against the slot before it (whose last group's arithmetic
+        its first copies run under), at the served geometries, agrees
+        with the jnp walk."""
+        from paddle_tpu.ops.pallas import paged_attention as pk
+        from paddle_tpu.serving_cache import paged_attention
+        dtype, extra = self.RAGGED[name][7:]
+        q, kp, vp, tables, pos, kw = self._ragged_case(name)
         ref = paged_attention(q, kp, vp, tables, pos, use_kernel=False,
                               **kw)
         got = pk.paged_attention_kernel(q, kp, vp, tables, pos,
                                         interpret=True, **kw)
         assert got.dtype == ref.dtype and got.shape == ref.shape
+        if extra.get("n_tiles") == 0:
+            assert not np.asarray(got, np.float32).any()
+        for s_ in extra.get("sees_nothing", ()):
+            assert not np.asarray(got[s_], np.float32).any()
         # bfloat16: both paths round p and the output to 8 bits, a
         # group at a time against a block at a time
         tol = 2e-2 if dtype == "bfloat16" else 1e-5
         np.testing.assert_allclose(
             np.asarray(ref, np.float32), np.asarray(got, np.float32),
             rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("name", sorted(RAGGED))
+    def test_kernel_waits_for_the_bytes_it_started(self, name):
+        """`interpret=True` copies at a DMA's start and takes every wait
+        as done, so it cannot see a wait that names the wrong bytes, nor
+        a start that comes after the wait for it. `InterpretParams`
+        counts a semaphore's bytes as the chip does and moves the data
+        at the wait: with a wait of one block too many the call blocks
+        for ever, with one too few the walk's answer is missed by 2.9
+        (both tried by hand on `slots_many_groups_then_one`). Every
+        ragged case, so a narrow slot's waits by binary digit and its
+        starts from inside the arithmetic as well as a wide slot's plain
+        loops, int8 scale streams and window walks included; the call
+        runs in a thread so that a wait nothing satisfies fails the
+        test and does not hang the run."""
+        import threading
+        from jax.experimental.pallas import tpu as pltpu
+        from paddle_tpu.ops.pallas import paged_attention as pk
+        from paddle_tpu.serving_cache import paged_attention
+        dtype = self.RAGGED[name][7]
+        q, kp, vp, tables, pos, kw = self._ragged_case(name)
+        ref = paged_attention(q, kp, vp, tables, pos, use_kernel=False,
+                              **kw)
+        got = []
+        call = threading.Thread(daemon=True, target=lambda: got.append(
+            np.asarray(pk.paged_attention_kernel(
+                q, kp, vp, tables, pos,
+                interpret=pltpu.InterpretParams(), **kw), np.float32)))
+        call.start()
+        call.join(300)
+        assert got, "a wait that no copy satisfies"
+        tol = 2e-2 if dtype == "bfloat16" else 1e-5
+        np.testing.assert_allclose(np.asarray(ref, np.float32), got[0],
+                                   rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize(
+        "name", sorted(n for n in RAGGED if n.startswith("slots_")))
+    def test_a_slot_among_others_gets_bit_for_bit_what_it_gets_alone(
+            self, name):
+        """A call of one slot starts and waits for its own first copy.
+        Among others a slot's first group is started by the slot before
+        it, and its groups land in whichever half that slot left them;
+        they come in the same order through the same arithmetic, so its
+        output is the same to the bit."""
+        from paddle_tpu.ops.pallas import paged_attention as pk
+        q, kp, vp, tables, pos, kw = self._ragged_case(name)
+        lower = kw.pop("lower", None)
+        got = np.asarray(pk.paged_attention_kernel(
+            q, kp, vp, tables, pos, lower=lower, interpret=True, **kw),
+            np.float32)
+        for s_ in range(q.shape[0]):
+            alone = pk.paged_attention_kernel(
+                q[s_:s_ + 1], kp, vp, tables[s_:s_ + 1], pos[s_:s_ + 1],
+                lower=None if lower is None else lower[s_:s_ + 1],
+                interpret=True, **kw)
+            assert np.array_equal(got[s_], np.asarray(alone[0],
+                                                      np.float32)), s_
 
     def test_group_size_comes_from_static_shapes(self):
         """What a group is at the shapes that are served, and where it
@@ -493,6 +638,58 @@ class TestPagedAttentionKernelSeam:
                 ((16, 512, bf16, 256, 8, 128, False), 8)]:  # wide scores
             assert pk.group_blocks(*args) == want, (args, want)
         assert pk.group_tokens(16, 512, bf16, 1, 8, 128) == 512
+
+    @pytest.mark.parametrize("T, n_rep, narrow", [
+        (1, 8, True),        # Yi decode
+        (1, 16, True),       # Command A+ decode: the last narrow shape
+        (5, 8, False),       # the verify window
+        (8, 8, False),       # Yi's chunk of 8 rows
+        (64, 8, False),      # Yi's chunk of 64 rows
+        (64, 16, False)])    # Command A+'s 64-row tiles
+    def test_fast_starts_and_waits_come_from_the_rows_a_kv_head(
+            self, T, n_rep, narrow):
+        """What chooses between the two ways a group's copies are
+        started and waited for is a static shape, the query rows a KV
+        head: a narrow slot (a decode call) gets starts of four a pass,
+        half a group's under the arithmetic and so two copies of it,
+        and waits by the binary digits of the block count; a wide one
+        (a prompt chunk) the plain loops and one copy of the
+        arithmetic. Counted in the jaxpr of the kernel."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas import paged_attention as pk
+        assert (T * n_rep <= pk._NARROW_ROWS) == narrow
+        S, K, D, bs, NB, MB = 2, 1, 8, 16, 64, 64
+        sds = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v, t, p: pk.paged_attention_kernel(
+                q, k, v, t, p, block_size=bs, n_rep=n_rep,
+                interpret=True))(
+            sds((S, T, K * n_rep, D), jnp.float32),
+            sds((NB, bs, K * D), jnp.float32),
+            sds((NB, bs, K * D), jnp.float32),
+            sds((S, MB), jnp.int32), sds((S, T), jnp.int32))
+        count = {}
+
+        def find(j):
+            for e in j.eqns:
+                count[e.primitive.name] = count.get(e.primitive.name, 0) + 1
+                for v in e.params.values():
+                    for x in (v if isinstance(v, (list, tuple)) else [v]):
+                        x = getattr(x, "jaxpr", x)
+                        if hasattr(x, "eqns"):
+                            find(x)
+        find(jaxpr.jaxpr)
+        C = pk.group_blocks(bs, K * D, jnp.float32, T, n_rep, MB)
+        assert C >= 16
+        # a dot for the scores and one for PV, a copy of the arithmetic
+        assert count["dot_general"] == (4 if narrow else 2)
+        # K and V: a wait a binary digit of the count, or one in a loop
+        assert count["dma_wait"] == (2 * C.bit_length() if narrow else 2)
+        # starts: a loop's one (K and V) for the first slot, the
+        # hand-on and a group; a narrow slot's groups also a pass of
+        # four, and the arithmetic a pass of half a group's
+        assert count["dma_start"] == (3 * 2 + 2 + 2 if narrow else 3 * 2)
 
     def test_kernel_sanitizes_recycled_garbage(self):
         """The MASKED-garbage contract, kernel side: an unmapped
@@ -649,18 +846,21 @@ class TestJaxprPins:
         assert offenders == [], offenders
 
 
-    def test_kernel_grid_is_one_step_a_slot_at_the_cells_geometry(self):
+    @pytest.mark.parametrize("S", [32, 33])
+    def test_kernel_grid_is_one_step_a_slot_at_the_cells_geometry(self, S):
         """The serving cell's decode call (32 slots, 128-entry tables
-        of 16-token blocks, Yi's heads, bf16 pool of 4096 blocks): the
-        pallas_call walks at most an eighth of the (slot, table entry)
-        steps the old grid took whatever was live (one a slot, in
-        fact), its pools stay where they are (no block spec brings
-        them in), and nothing outside or inside it is a gather or has
-        a max_seq-sized axis."""
+        of 16-token blocks, Yi's heads, bf16 pool of 4096 blocks; and
+        with a slot more): the pallas_call walks at most an eighth of
+        the (slot, table entry) steps the old grid took whatever was
+        live (one a slot, in fact: a tile of slots a step was measured
+        on the chip and bought nothing, PERF.md section 6, PR 34), its
+        pools stay where they are (no block spec brings them in), and
+        nothing outside or inside it is a gather or has a max_seq-sized
+        axis."""
         import jax
         import jax.numpy as jnp
         from paddle_tpu.ops.pallas import paged_attention as pk
-        S, T, H, K, D, bs, NB, MB = 32, 1, 32, 4, 128, 16, 4096, 128
+        T, H, K, D, bs, NB, MB = 1, 32, 4, 128, 16, 4096, 128
         sds = jax.ShapeDtypeStruct
         jaxpr = jax.make_jaxpr(
             lambda q, k, v, t, p, n: pk.paged_attention_kernel(
@@ -684,7 +884,7 @@ class TestJaxprPins:
         find(jaxpr.jaxpr)
         assert len(calls) == 1
         gm = calls[0].params["grid_mapping"]
-        assert int(np.prod(gm.grid)) == S <= S * MB // 8
+        assert gm.grid == (S,) and S <= S * MB // 8
         pools = [bm for bm in gm.block_mappings
                  if bm.array_aval.shape == (NB, bs, K * D)]
         assert len(pools) == 2

@@ -6,7 +6,8 @@ numbers; it cannot see what Mosaic refuses. Every refusal met while the
 kernel was written showed only here: a bf16 compare on the v5e's VPU, an
 ambient float32 matmul precision on bf16 operands, a DMA of a slab whose
 minor dimension is not whole 128-lane rows (the int8 scales), scoped VMEM at
-wide query tiles. Nor can it see a layout: a pool kept `[NB, bs, KVH, D]` is
+wide query tiles, and (PR 34) it takes a wait as done whatever it names, where
+the chip counts the bytes. Nor can it see a layout: a pool kept `[NB, bs, KVH, D]` is
 tiled `T(4,128)(2,1)` on the v5e, the kernel's `[NB, bs, KVH*D]` operand
 `T(8,128)(2,1)`, and the reshape between them was a copy of the whole pool, K
 and V, a layer a launch (42% of a decode cell's device time, PERF.md). So the
@@ -76,6 +77,11 @@ SHAPES = {
     "dense_engine_tile_128": (4, 1, YI, 128, 64, 16, "bfloat16", "bfloat16", False),
     "dense_engine_prompt_256": (4, 256, YI, 128, 64, 16, "bfloat16", "bfloat16", False),
     "two_token_blocks": (2, 1, YI, 2, 64, 128, "bfloat16", "bfloat16", False),
+    # a slot's first group is started by the slot before it (PR 34): an odd
+    # number of slots, and a window layer's decode call (narrow slots: waits
+    # by binary digit, half a group's starts inside the arithmetic)
+    "yi_decode_33_slots": (33, 1, YI, 16, 4096, 128, "bfloat16", "bfloat16", False),
+    "cmda_window_decode_33_slots": (33, 1, CMDA, 16, 9472, 1024, "bfloat16", "bfloat16", False, True),
 }
 
 
