@@ -21,3 +21,6 @@ from .cohere2_moe import (  # noqa: F401
 from .glm_moe_dsa import (  # noqa: F401
     GlmMoeDsaConfig, GlmMoeDsaForCausalLM,
 )
+from .solar_open2 import (  # noqa: F401
+    SolarOpen2Config, SolarOpen2ForCausalLM,
+)

@@ -26,7 +26,20 @@ pool), ``_sc`` (``paged_attention``: ``_pa_kernel`` says whether the Pallas
 kernel runs; ``paged_index_scores``, ``select_topk``,
 ``paged_latent_attention``), ``block_size``, ``n_rep``, and ``dtype`` /
 ``n_layers`` / ``int8`` for its parameters' layout. ``models/llama.py``,
-``models/cohere2_moe.py`` and ``models/glm_moe_dsa.py`` use it.
+``models/cohere2_moe.py``, ``models/glm_moe_dsa.py`` and
+``models/solar_open2.py`` use it.
+
+**A state a slot.** A layer's spec may name, beside or in place of
+``pools``, ``state``: ``{name: (shape a slot, dtype)}``, kind ``"state"``
+where it owns no pool. Its arrays are ``[max_slots, *shape]``, indexed by
+SLOT and under no table or allocator (a recurrent layer's matrix state and
+convolution tail); they ride in the same donated pytree as the pools and
+reach the layer's step with them. Such a model's step also takes
+``slots=`` (None: row ``s`` is slot ``s``, a decode step; ``[1]``: the slot
+of a prompt chunk, whose program gets it as one more scalar). A chunk that
+starts at position 0 reads the state as zeros; a row with ``wmask`` False
+leaves it as it was. A state cannot be truncated, nor shared by prefix:
+speculation, prefix sharing and an int8 pool are refused for such a model.
 
 **Two program families**, through ``jit.sot.capture_jit``: ``serving.decode``
 (``jit_serving_decode`` in a device trace), one token for every slot at its
@@ -50,6 +63,7 @@ from __future__ import annotations
 
 import functools
 import itertools
+import math
 import queue as _queue
 import threading
 import time
@@ -159,6 +173,19 @@ _M_prefix_reused = _M.counter(
 # the loop keeps one launch ahead of its fetch, so a request that ends
 # on a token (an EOS) or on the clock (a deadline) has a token or two
 # enqueued that nobody will read
+# a layer whose memory is a state a slot starts a request from zeros: the
+# request's first chunk reads no state (no clearing launch)
+_M_state_resets = _M.counter(
+    "state_resets_total",
+    "First prompt chunks enqueued for a model with state layers: the "
+    "slot's recurrent state and convolution tail are read as zeros")
+_G_state_slots = _M.gauge(
+    "state_slots_in_use",
+    "Slots whose per-slot recurrent state a request holds (prefilling or "
+    "decoding), for a model with state layers")
+_G_state_bytes = _M.gauge(
+    "state_bytes",
+    "Bytes of the per-slot state arrays of every state layer, all slots")
 _M_overrun = _M.counter(
     "overrun_tokens_total",
     "Tokens launched for a request and dropped because it had ended "
@@ -282,7 +309,8 @@ class PagedLlamaDecodeEngine:
             raise TypeError(
                 f"{type(model).__name__} has no serve_model(): the engine "
                 f"serves a model only through the seam it hands over "
-                f"(cache_spec, build_params, embed, layer, head)")
+                f"(cache_spec: a layer's block pools and/or its state a "
+                f"slot; build_params, embed, layer, head, aux_names)")
         self._m = model.serve_model()
         if self.int8 and not self._m.supports_int8:
             raise NotImplementedError(
@@ -294,10 +322,27 @@ class PagedLlamaDecodeEngine:
                     f"{type(model).__name__}")
             self._prefix_cache = False
         self.cache_spec = self._m.cache_spec(self.n_layers)
+        for sp in self.cache_spec:      # a state layer may name no pool
+            sp.setdefault("pools", {})
+            sp.setdefault("window", None)
+        # layers whose memory is a fixed-size state a SLOT (`state`:
+        # {name: (shape a slot, dtype)}), not a row a token: no table, no
+        # blocks, nothing to truncate or to share by prefix
+        self._state_names = list(dict.fromkeys(
+            n for sp in self.cache_spec for n in sp.get("state", {})))
+        self._stateful = bool(self._state_names)
+        self._state_bytes = int(max_slots) * sum(
+            math.prod(shape) * jnp.dtype(dt).itemsize
+            for sp in self.cache_spec
+            for shape, dt in sp.get("state", {}).values())
+        if self._stateful and kv_quant == "int8":
+            raise NotImplementedError(
+                "an int8 KV pool is not built for a model with state layers")
         # per-head K/V pools say their heads; a spec that does not (a
         # latent pool: one row a token, nothing per head) has no head
         # width for the paged kernel and no scale a head for int8
-        sp0 = self.cache_spec[0]
+        sp0 = next((sp for sp in self.cache_spec if "kv_heads" in sp),
+                   self.cache_spec[0])
         self.head_dim = sp0.get("head_dim")
         self.n_rep = sp0["q_heads"] // sp0["kv_heads"] \
             if "kv_heads" in sp0 else 1
@@ -357,10 +402,12 @@ class PagedLlamaDecodeEngine:
         from .jit.sot import capture_jit as _capture_jit
         self._capture_jit = _capture_jit
 
-        kinds = sorted({sp["kind"] for sp in self.cache_spec})
+        # (a state layer holds no blocks: it is under no table)
+        kinds = sorted({sp["kind"] for sp in self.cache_spec
+                        if sp["pools"] or not sp.get("state")})
         # a model with window layers gets a table and an allocator a
         # kind; every other model the one table it always had
-        self._kinded = kinds != ["full"]
+        self._kinded = kinds not in ([], ["full"])
         if self._kinded:
             if self.kv_quant == "int8":
                 raise NotImplementedError(
@@ -533,12 +580,14 @@ class PagedLlamaDecodeEngine:
                       if k in meta and meta[k] != v)
 
     def _alloc_pools(self) -> Dict[str, list]:
-        """Fresh zeroed block pools as the cache spec names them: ``{pool
-        name: [one entry a layer]}``, a layer's entry ``[num_blocks of its
-        kind, block_size, row width]`` where its spec owns that pool and
-        None where it does not (an int8 K/V pool also gets its scales).
+        """Fresh zeroed cache arrays as the cache spec names them: ``{name:
+        [one entry a layer]}``. A block pool's entry is ``[num_blocks of its
+        kind, block_size, row width]`` where the layer's spec owns that pool
+        (an int8 K/V pool also gets its scales); a state's entry is
+        ``[max_slots, *shape a slot]`` in the dtype its spec gives, indexed
+        by SLOT, under no table; None where the layer owns no such array.
         Built at boot and again at crash recovery (``reset_state``), where
-        the donated pool pytree may be mid-donation."""
+        the donated pytree may be mid-donation."""
         pool_dt = {"int8": jnp.int8,
                    "bfloat16": jnp.bfloat16}.get(self.kv_quant,
                                                  self.dtype)
@@ -556,12 +605,31 @@ class PagedLlamaDecodeEngine:
             for name in ("ksc", "vsc"):     # a scale a (token, head)
                 kv[name] = [jnp.zeros((nb, bs, sp["kv_heads"]), jnp.float32)
                             for nb, sp in zip(blocks, self.cache_spec)]
+        for name in self._state_names:
+            kv[name] = [
+                jnp.zeros((self.max_slots,) + tuple(sp["state"][name][0]),
+                          jnp.dtype(sp["state"][name][1]))
+                if name in sp.get("state", {}) else None
+                for sp in self.cache_spec]
         return kv
+
+    def state_stats(self) -> Dict[str, int]:
+        """Slots whose state a request holds now (prefilling or decoding)
+        and the bytes of every layer's state arrays, all slots: ``{}`` for a
+        model with no state layer."""
+        if not self._stateful:
+            return {}
+        # (a slot that is prefilling is not active yet)
+        return {"state_slots": self.max_slots,
+                "state_slots_in_use": len(self._prefill_state)
+                + int(self.active.sum()),
+                "state_bytes": self._state_bytes}
 
     def pool_blocks_in_use(self) -> Dict[str, int]:
         """Blocks mapped to slots by pool name, summed over the layers
         that own a pool of that name (a block of a table is one block in
-        every pool under it)."""
+        every pool under it). A state holds no blocks and is not here:
+        ``state_stats`` counts its slots and bytes."""
         used = {k: c.used_blocks() for k, c in self._kv.kinds.items()} \
             if self._kinded else {"full": self._kv.used_blocks()}
         out: Dict[str, int] = {}
@@ -571,11 +639,12 @@ class PagedLlamaDecodeEngine:
 
     def reset_state(self) -> None:
         """Discard ALL slot and cache state — the crash-recovery seam:
-        after a decode-loop crash the donated pool buffers may be
+        after a decode-loop crash the donated cache buffers may be
         mid-donation (deleted). Every owned slot is released as a
         counted EVICTION (its request is being re-admitted or
         quarantined by the supervisor), staged prefills are dropped, and
-        the donated pool pytree is rebuilt as fresh zeros. Compiled
+        the donated pytree (block pools, and a state layer's arrays a
+        slot) is rebuilt as fresh zeros. Compiled
         programs are kept — zero recompiles. An attached draft resets
         in the same call (mirrored slots)."""
         for s in range(self.max_slots):
@@ -631,9 +700,10 @@ class PagedLlamaDecodeEngine:
         One captured executable with the pool pytree donated — the
         copy lands in place in HBM like every other pool write."""
         del params
-        return {name: [None if p is None
-                       else self._sc.copy_block(p, src, dst)
-                       for p in pools]
+        state = self._state_names
+        return {name: pools if name in state     # indexed by slot: no block
+                else [None if p is None
+                      else self._sc.copy_block(p, src, dst) for p in pools]
                 for name, pools in kvs.items()}
 
     def walk_group_tokens(self, T: int = 1) -> Optional[int]:
@@ -651,15 +721,19 @@ class PagedLlamaDecodeEngine:
             self._kv.max_blocks_per_slot, self.kv_quant == "int8")
 
     def _forward_paged(self, params, kv, ids, positions, tables,
-                       n_tiles, wmask):
+                       n_tiles, wmask, slots=None):
         """Shared chunked-prefill/decode body: ids [S, T] -> logits
         [S, T, V]; the pool pytree is donated, writes land in place.
         Also returns the model's counts summed over the layers, and
         ``{layer: what it made and handed on}`` for each layer that did
         not pass its ``carry`` on as it came (a selection ``[S, T, N]``
         bool that the layers after it share; ``{}`` for a model with
-        none)."""
+        none). ``slots`` goes to the layers of a model with state layers:
+        None where row ``s`` of the batch is slot ``s`` (a decode step),
+        else ``[S]`` int32, the slot of each row (a prompt chunk's one)."""
         h = self._m.embed(self, params, ids)
+        # only a model with state layers is asked to take the slots
+        more = {"slots": slots} if self._stateful else {}
         out_kv = {key: [] for key in kv}
         aux = carry = None
         made = {}
@@ -670,7 +744,8 @@ class PagedLlamaDecodeEngine:
             tab = tables[self.cache_spec[li]["kind"]] \
                 if isinstance(tables, dict) else tables
             h, kvl, counts, handed = self._m.layer(
-                self, li, lp, h, kvl, positions, tab, n_tiles, wmask, carry)
+                self, li, lp, h, kvl, positions, tab, n_tiles, wmask, carry,
+                **more)
             if handed is not carry:
                 made[li] = carry = handed
             if counts is not None:       # summed over the layers
@@ -720,21 +795,24 @@ class PagedLlamaDecodeEngine:
                  for li, sel in made.items()})
 
     def _prefill_impl(self, params, kv, ids, table_row, start, nvalid,
-                      true_len):
+                      true_len, slot=None):
         """ONE prompt chunk for ONE slot: ids [1, B] (bucket-padded)
         holds prompt tokens [start, start+nvalid); rows write into the
         slot's blocks and attend to every earlier position (previous
         chunks' blocks + causal within the chunk). Returns the greedy
         token at the prompt's LAST position — meaningful only on the
-        final chunk (the host ignores it before that)."""
+        final chunk (the host ignores it before that). ``slot`` is given
+        for a model with state layers alone: their state is indexed by
+        slot, and a chunk whose ``start`` is 0 reads it as zeros."""
         B = ids.shape[1]
         offs = jnp.arange(B)
         positions = (start + offs)[None, :]             # [1, B]
         wmask = (offs < nvalid)[None, :]
         tables = jax.tree.map(lambda r: r[None, :], table_row)
         n_tiles = (start + nvalid - 1) // self.block_size + 1
-        logits, kv, aux, _ = self._forward_paged(params, kv, ids, positions,
-                                                 tables, n_tiles, wmask)
+        logits, kv, aux, _ = self._forward_paged(
+            params, kv, ids, positions, tables, n_tiles, wmask,
+            None if slot is None else slot[None])
         last = jnp.clip(true_len - 1 - start, 0, B - 1)
         tok = jnp.argmax(logits[0, last, :]).astype(jnp.int32)
         return self._with_aux(tok, aux), kv
@@ -886,11 +964,13 @@ class PagedLlamaDecodeEngine:
         return self
 
     def _refuse_speculation(self) -> None:
-        if not self._m.supports_speculation or self._kinded:
+        if not self._m.supports_speculation or self._kinded \
+                or self._stateful:
             raise NotImplementedError(
                 "speculative decoding is not built for this model: a "
                 "rejected window would have to be rolled back out of a "
-                "window layer's table, whose freed blocks are gone")
+                "window layer's table, whose freed blocks are gone, or out "
+                "of a state, which keeps no history")
 
     def _tables_dev(self, slot: Optional[int] = None):
         """The block table(s) as a launch takes them: the one array, or
@@ -951,6 +1031,9 @@ class PagedLlamaDecodeEngine:
             below = max(min(self.select_k - start, tokens), 0)
             out["selected_tokens"] = below * start \
                 + below * (below + 1) // 2 + (tokens - below) * self.select_k
+        if self._stateful:
+            # the sub-chunks the model's chunk form takes for these rows
+            out["state_subchunks"] = -(-tokens // self._m.state_subchunk)
         return out
 
     def _device_cow(self, slot: int, src: int, dst: int) -> None:
@@ -1079,9 +1162,15 @@ class PagedLlamaDecodeEngine:
                 # own are mapped (a full table's were at admission)
                 self._kv.advance(slot, start, start + c - 1)
             row = self._tables_dev(slot)
+            more = ()
+            if self._stateful:
+                more = (jnp.int32(slot),)
+                if start == 0:      # the program reads the state as zeros
+                    _M_state_resets.inc()
+                    _flight.record("serving", "state_reset", slot=slot)
             tok, self.kvs = self._prefill_program(b)(
                 self.params, self.kvs, jnp.asarray(padded), row,
-                jnp.int32(start), jnp.int32(c), jnp.int32(n))
+                jnp.int32(start), jnp.int32(c), jnp.int32(n), *more)
         st["next"] = start + c
         # what this turn did, for the loop's span and flight event
         self.last_chunk = self._chunk_counts(start, c, b)
@@ -1494,7 +1583,8 @@ class PagedLlamaDecodeEngine:
                 self.params, self.kvs,
                 jnp.asarray(np.zeros((1, b), np.int32)),
                 self._tables_dev(0),
-                _I32, _I32, _I32).compile()
+                _I32, _I32, _I32,
+                *((_I32,) if self._stateful else ())).compile()
         elif prog == "spec_draft":
             draft = self._draft
             if draft is None:
@@ -1608,6 +1698,7 @@ class GenerationServer:
         self.steps_run = 0
         self.launched_ahead = 0     # steps enqueued before the last's fetch
         self._pool_gauged = None    # the pools' block counts last gauged
+        self._state_gauged = None   # and a state model's slots and bytes
         # a model's counts that the collects returned, until a launch's
         # span takes them
         self._aux_carry: Dict[str, int] = {}
@@ -2295,6 +2386,9 @@ class GenerationServer:
         if select_k is not None:
             # what a layer that attends selected positions reads of them
             out["selected_tokens"] = int(np.minimum(ctx, select_k).sum())
+        if getattr(eng, "_stateful", False):
+            # slots whose state the launch reads and writes, a state layer
+            out["state_slots"] = int(ctx.size)
         return out
 
     def _sweep(self) -> None:
@@ -2597,6 +2691,11 @@ class GenerationServer:
             if blocks != self._pool_gauged:     # only when a count moved
                 self._pool_gauged = blocks
                 self.engine._sc.set_pool_gauges(blocks)
+            state = self.engine.state_stats()
+            if state and state != self._state_gauged:
+                self._state_gauged = state
+                _G_state_slots.set(state["state_slots_in_use"])
+                _G_state_bytes.set(state["state_bytes"])
 
     def shutdown(self, drain: bool = True,
                  timeout: Optional[float] = 300.0) -> bool:
@@ -2669,4 +2768,7 @@ class GenerationServer:
                "draining": int(self._stopping.is_set()),
                "drained": int(self._drained.is_set()),
                "kv_pool": self.engine._kv.stats()}
+        state = getattr(self.engine, "state_stats", dict)()
+        if state:       # beside the blocks: the state's slots and bytes
+            out["kv_pool"] = dict(out["kv_pool"], **state)
         return out

@@ -261,3 +261,175 @@ def test_no_launch_copies_a_latent_or_an_index_pool(one_chip, width):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == NB * bs * width * 2
     assert mem.temp_size_in_bytes < 1 << 20
+
+
+# Solar Open 2's served shapes: 64 slots of 64 heads of a float32 [128, 128]
+# state (the pool 1.07 GB a layer), a 512-row chunk and the smallest chunk
+KDA = {"step_64_slots": None, "chunk_512": 512, "chunk_8": 8}
+
+
+@pytest.mark.parametrize("name", sorted(KDA))
+def test_the_kda_kernels_compile_for_the_v5e(one_chip, name):
+    from paddle_tpu.ops.pallas import kda
+    NS, H, d, T = 64, 64, 128, KDA[name]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    rows = lambda n: [sds((n, H, d), "float32")] * 4 + [sds((n, H), "float32")]
+    if T is None:
+        compiled = jax.jit(
+            lambda S, q, k, v, g, b, act: kda.kda_step(
+                S, q, k, v, g, b, act, use_kernel=True),
+            donate_argnums=(0,)).lower(
+            sds((NS, H, d, d), "float32"), *rows(NS), sds((NS,), "bool")).compile()
+    else:
+        compiled = jax.jit(
+            lambda S, slot, fresh, q, k, v, g, b: kda.kda_chunk(
+                S, slot, fresh, q, k, v, g, b, use_kernel=True),
+            donate_argnums=(0,)).lower(
+            sds((NS, H, d, d), "float32"), sds((), "int32"), sds((), "bool"),
+            *rows(T)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the trace and the benchmark's readers find the kernels by these names
+    assert ("_kda_step_call" if T is None else "_kda_chunk_call") in text
+    # the pool is the kernel's input and its output: updated in place
+    assert compiled.memory_analysis().alias_size_in_bytes == NS * H * d * d * 4
+
+
+def test_no_decode_launch_copies_a_state_pool_or_a_kv_pool(one_chip, monkeypatch):
+    """A GQA layer and a KDA layer of Solar Open 2 at the served widths, one
+    decode launch over 64 slots with every pool donated: the K and V rows are
+    scattered in place, the step kernel reads and writes the state where it
+    lies, the convolution's tail (a row a slot: a `[slots, 3, C]` pool came out
+    `{2,0,1}` and was copied every launch) is selected in place, and nothing in
+    the program is as large as a pool."""
+    from paddle_tpu.models import solar_open2 as so
+    # the seams choose by the backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = so.SolarOpen2Config(num_hidden_layers=2, gqa_layers=(0,),
+                              vocab_size=1024, dtype="bfloat16")
+    model = so.SolarOpen2Serve(cfg, (0, 20))
+    NS, NB, bs, MB, C = 64, 20480, 16, 640, 3 * 64 * 128
+    eng = types.SimpleNamespace(
+        block_size=bs, kv_quant=None, _sc=sc, _pa_kernel=True,
+        _kv=types.SimpleNamespace(max_blocks_per_slot=MB))
+    eng._write_kv = lambda *a: PagedLlamaDecodeEngine._write_kv(eng, *a)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    lps = [{k: sds(s, "bfloat16") for k, s in so.layer_shapes(cfg, i, 20).items()}
+           for i in range(2)]
+    spec = model.cache_spec(2)
+    assert spec[1]["state"] == {"S": ((64, 128, 128), "float32"),
+                                "conv": ((3 * C,), "bfloat16")}
+    kvs = [{"k": sds((NB, bs, 1024), "bfloat16"), "v": sds((NB, bs, 1024), "bfloat16")},
+           {n: sds((NS,) + shape, dt) for n, (shape, dt) in spec[1]["state"].items()}]
+
+    def launch(kvs, lps, h, pos, tables, act):
+        out = []
+        for li in range(2):
+            h, kvl, _, _ = model.layer(
+                eng, li, lps[li], h, kvs[li], pos[:, None], tables,
+                jnp.max(pos) // bs + 1, act[:, None], None, slots=None)
+            out.append(kvl)
+        return h, out
+
+    compiled = jax.jit(launch, donate_argnums=(0,)).lower(
+        kvs, lps, sds((NS, 1, 4096), "bfloat16"), sds((NS,), "int32"),
+        sds((NS, MB), "int32"), sds((NS,), "bool")).compile()
+    text = compiled.as_text()
+    for kernel in ("_kda_step_call", "_paged_attention_call",
+                   "_expert_rows_matmul_call"):
+        assert kernel in text
+
+    def made(shape):
+        return set(re.findall(r"= \(?\w+\[%s\]\S* ([\w-]+)\(" % shape, text))
+    assert made("%d,64,128,128" % NS) <= {"parameter", "get-tuple-element",
+                                          "custom-call"}
+    assert made("%d,%d,1024" % (NB, bs)) <= {"parameter", "scatter", "fusion"}
+    assert not made("%d,%d" % (NS, 3 * C)) & {"copy", "transpose", "reshape"}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == (2 * NB * bs * 1024 * 2
+                                       + NS * 64 * 128 * 128 * 4 + NS * 3 * C * 2)
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_512", "chunk_8"])
+def test_the_engine_s_programs_keep_a_float32_state_in_place(
+        one_chip, monkeypatch, program):
+    """The ENGINE's own decode and chunk programs (`_decode_impl`,
+    `_prefill_impl` through `_forward_paged` and `SolarOpen2Serve.layer`) at the
+    served widths, a GQA layer and two KDA layers, every pool donated: each KDA
+    layer's state goes through exactly one `_kda_step_call` (decode) or
+    `_kda_chunk_call` (chunk), a float32 `[64, 64, 128, 128]` operand aliased to
+    the call's output, and the program holds NO other array of a state's shape
+    in any dtype: a state rounded below float32, or kept beside the pool,
+    anywhere in the launch would be one. The benchmark's `recurrence_gap` drives
+    these two calls at these shapes on the chip
+    (`benchmark/runners/serve_paged_kda.py` `recurrence_probe`); this holds the
+    served programs to them."""
+    from paddle_tpu.models import solar_open2 as so
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = so.SolarOpen2Config(num_hidden_layers=3, gqa_layers=(0,),
+                              vocab_size=1024, dtype="bfloat16")
+    NS, NB, bs, MB, V = 64, 2048, 16, 640, 1024
+    eng = object.__new__(PagedLlamaDecodeEngine)
+    eng._m = so.SolarOpen2Serve(cfg, (0, 20))
+    eng.cache_spec = eng._m.cache_spec(3)
+    for sp in eng.cache_spec:
+        sp.setdefault("pools", {})
+    eng._stateful, eng.block_size, eng.kv_quant, eng._sc = True, bs, None, sc
+    eng._pa_kernel, eng.dtype, eng.n_layers = True, jnp.bfloat16, 3
+    eng._kv = types.SimpleNamespace(max_blocks_per_slot=MB)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    params = {"emb": sds((V, 4096), "bfloat16"), "norm": sds((4096,), "bfloat16"),
+              "head": sds((V, 4096), "bfloat16"),
+              "layers": [{k: sds(s, "bfloat16")
+                          for k, s in so.layer_shapes(cfg, i, 20).items()}
+                         for i in range(3)]}
+    state = eng.cache_spec[1]["state"]
+    kvs = {"k": [sds((NB, bs, 1024), "bfloat16"), None, None],
+           "v": [sds((NB, bs, 1024), "bfloat16"), None, None],
+           **{n: [None] + [sds((NS,) + shape, dt)] * 2
+              for n, (shape, dt) in state.items()}}
+    if program == "decode":
+        compiled = jax.jit(eng._decode_impl, donate_argnums=(1,)).lower(
+            params, kvs, sds((NS, 1), "int32"), sds((NS,), "int32"),
+            sds((NS, MB), "int32"), sds((NS,), "bool")).compile()
+        call = "_kda_step_call"
+    else:
+        B = int(program.split("_")[1])
+        scalar = sds((), "int32")
+        compiled = jax.jit(eng._prefill_impl, donate_argnums=(1,)).lower(
+            params, kvs, sds((1, B), "int32"), sds((MB,), "int32"), scalar,
+            scalar, scalar, scalar).compile()
+        call = "_kda_chunk_call"
+    text = compiled.as_text()
+    pool = r"\[%d,64,128,128\]" % NS
+    # every array of a state's shape, by dtype and by the operation that made it
+    made = re.findall(r"= \(?(\w+)%s\S* ([\w-]+)\(" % pool, text)
+    assert {dt for dt, _ in made} == {"f32"}, sorted(set(made))
+    assert {op for _, op in made} <= {"parameter", "get-tuple-element",
+                                      "custom-call"}, sorted(set(made))
+    pools = set(re.findall(r"%%(\S+) = f32%s\S* parameter\(" % pool, text))
+    assert len(pools) == 2                      # one a KDA layer
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and call in line]
+    fed = set()
+    for line in calls:
+        # the call's first output is the operand it names, and that operand is
+        # the donated pool itself: nothing stands between them
+        operands = line.split("custom-call(")[1].split(")")[0]
+        operands = re.sub(r"/\*.*?\*/", "", operands).split(", ")
+        (at,) = re.findall(r"output_to_operand_aliasing=\{\{0\}: \((\d+), \{\}\)",
+                           line)
+        fed.add(operands[int(at)].lstrip("%"))
+    assert len(calls) == 2 and fed == pools, (fed, pools)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * NS * 64 * 128 * 128 * 4
