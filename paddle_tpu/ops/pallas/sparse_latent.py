@@ -10,13 +10,24 @@ form (the up-projection folded into the query and the output, so the
 rows are read as they are stored).
 
 - :func:`index_scores`: ``I(t, c) = sum_j w[t, j] * relu(q[t, j] . k[c])``
-  for query rows ``t`` (``j`` over the index heads) against a slot's keys
-  ``k [N, D]`` laid out by position. One grid step is a tile of rows
-  against a tile of keys: the (row, head) pairs are the rows of one dot
-  (bf16 operands, float32 accumulation), relu and the weighted head sum
-  run on the float32 tile, and only ``[rows, keys]`` scores leave VMEM.
-  Key tiles past a row tile's last visible position are neither fetched
-  (their block index repeats the last live one) nor computed: zeros.
+  for query rows ``t`` (``j`` over the index heads) against the keys of
+  the slot's paged index pool, read through its block table. One grid
+  step is a tile of rows against a tile of ``KEY_TILE`` keys: the (row,
+  head) pairs are the rows of one dot (bf16 operands, float32
+  accumulation), relu and the weighted head sum run on the float32 tile,
+  and only ``[rows, keys]`` scores leave VMEM. A key tile's blocks are
+  copied from HBM, one copy a block, into one half of a two-half buffer
+  while the tile before is scored, once for all of a slot's row tiles;
+  blocks past the slot's last live row and unmapped entries are never
+  copied, and key tiles past a row tile's last position are not
+  computed: zeros. (A gather of the keys into position order first
+  writes and reads every slot's keys to the table's end, 419 MB a layer
+  a 32-slot decode step, for a kernel that reads only the live tiles.)
+  The starts are a loop of their own, not straight-line code among the
+  arithmetic as in ``paged_attention``: that form traced and lowered
+  five times as long, and GLM-5.2's cell paid it in every program of
+  every process (``setup_s`` +12%, PERF.md section 6, PR 36) to save
+  about 0.1 ms a decode step.
 - :func:`latent_attention`: a slot's query rows against the slot's OWN
   blocks of the latent pool, walked through its block table as
   ``paged_attention``'s kernel walks K and V (one grid step a slot, a
@@ -68,51 +79,141 @@ def _on_tpu() -> bool:
 # index scores
 # ---------------------------------------------------------------------------
 
-def _index_kernel(last_ref, q_ref, w_ref, k_ref, o_ref, *, rows, heads,
-                  n_row_tiles):
-    s_, t_, n_ = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    live = n_ <= last_ref[s_ * n_row_tiles + t_]
+def _index_kernel(tables_ref, last_ref, tiles_ref, q_ref, w_ref, pool_hbm,
+                  o_ref, buf, sems, half_ref, *, rows, heads, per, n_slots,
+                  n_key_tiles, n_row_tiles):
+    """Grid step ``(s, n, t)``: row tile ``t`` of slot ``s`` against key
+    tile ``n``, the row tiles innermost, so that a key tile is copied once
+    for all the row tiles of a slot. Scalar prefetch: the block table
+    (entries past a slot's last live block are -1), each row tile's last
+    live key tile, and each key tile's count of copies (-1: no row tile
+    reads it; the tiles that some row tile reads are a slot's first ones).
+    ``pool_hbm [NB, bs, D]`` stays in HBM; scratch: a two-half buffer of
+    one key tile's blocks, a semaphore a half, and the half that the
+    current key tile lands in."""
+    s, n, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    NT = n_key_tiles
+    copies = tiles_ref[s * NT + n]
+    live = n <= last_ref[s * n_row_tiles + t]
+    # the next key tile that some row tile reads: the slot's next one, else
+    # the next slot's first
+    same = (n + 1 < NT) & (tiles_ref[s * NT + jnp.minimum(n + 1, NT - 1)] >= 0)
+    more = same | (s + 1 < n_slots)
+    ns = jnp.where(same, s, jnp.minimum(s + 1, n_slots - 1))
+    nn = jnp.where(same, n + 1, 0)
+
+    def start_mapped(slot, kt, half):
+        """A loop over the tile's blocks, copying the mapped ones."""
+        def one(j, carry):
+            blk = tables_ref[slot, kt * per + j]
+
+            @pl.when(blk >= 0)
+            def _start():
+                pltpu.make_async_copy(pool_hbm.at[blk], buf.at[half, j],
+                                      sems.at[half]).start()
+            return carry
+        jax.lax.fori_loop(0, per, one, 0)
+
+    def wait(count, half):
+        """A semaphore counts bytes: a whole tile is waited for at once,
+        the blocks of a ragged one each."""
+        def one(j, carry):
+            pltpu.make_async_copy(buf.at[half, 0], buf.at[half, 0],
+                                  sems.at[half]).wait()
+            return carry
+
+        @pl.when(count == per)
+        def _whole():
+            pltpu.make_async_copy(buf.at[half], buf.at[half],
+                                  sems.at[half]).wait()
+
+        @pl.when(count < per)
+        def _ragged():
+            jax.lax.fori_loop(0, count, one, 0)
+
+    def score(half):
+        """The row tile against the key tile in ``half``."""
+        k = buf[half].reshape(per * buf.shape[2], buf.shape[3])
+        sc = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            precision=_dot_precision(buf.dtype),
+            preferred_element_type=jnp.float32)          # [rows*heads, TN]
+        sc = jnp.maximum(sc, 0.0) * w_ref[0]
+        for r in range(rows):       # the heads of a row are `heads` sublanes
+            o_ref[0, r:r + 1, :] = jnp.sum(
+                sc[r * heads:(r + 1) * heads], axis=0, keepdims=True)
+
+    first = (s == 0) & (n == 0) & (t == 0)
+
+    @pl.when(first)
+    def _first():
+        half_ref[0] = 0
+
+    half = half_ref[0]
+    # the first grid step that reads this key tile: the next tile's copies
+    # are started (and, on the grid's first step, this one's before them),
+    # this one's waited for
+    fresh = (t == 0) & (copies >= 0)
+
+    @pl.when(fresh & (first | more))
+    def _start():
+        def tile(i, carry):
+            own = first & (i == 0)
+            start_mapped(jnp.where(own, s, ns), jnp.where(own, n, nn),
+                         jnp.where(own, half, 1 - half))
+            return carry
+        jax.lax.fori_loop(0, first.astype(jnp.int32) + more.astype(jnp.int32),
+                          tile, 0)
+
+    @pl.when(fresh)
+    def _wait():
+        wait(copies, half)
 
     @pl.when(live)
     def _score():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            precision=_dot_precision(k_ref.dtype),
-            preferred_element_type=jnp.float32)          # [rows*heads, TN]
-        s = jnp.maximum(s, 0.0) * w_ref[0]
-        for r in range(rows):       # the heads of a row are `heads` sublanes
-            o_ref[0, r:r + 1, :] = jnp.sum(
-                s[r * heads:(r + 1) * heads], axis=0, keepdims=True)
+        score(half)
 
     @pl.when(jnp.logical_not(live))
     def _zero():
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    @pl.when((t == n_row_tiles - 1) & (copies >= 0))
+    def _flip():
+        half_ref[0] = 1 - half
+
 
 @functools.partial(jax.jit, static_argnames=("T", "interpret"))
-def _index_score_call(q, w, keys, last, *, T, interpret=False):
+def _index_score_call(q, w, pool, tables, last, tiles, *, T, interpret=False):
     """q [S, T*J, D] ((row, head) pairs, row-major), w [S, T*J, 1] f32,
-    keys [S, N, D], last [S * T/rows] int32 (last live key tile of each
-    row tile) -> [S, T, N] f32."""
+    pool [NB, bs, D], tables [S, N/bs] int32 (-1 past a slot's live
+    blocks), last [S * T/rows] int32 (last live key tile of each row
+    tile), tiles [S * N/KEY_TILE] int32 (copies of each key tile, -1 where
+    no row tile reads it) -> [S, T, N] f32."""
     S, tj, D = q.shape
-    N = keys.shape[1]
+    _, bs, _ = pool.shape
+    per = KEY_TILE // bs
+    N = tables.shape[1] * bs
     J = tj // T
     rows = min(T, _ROW_TILE)
     n_row_tiles = T // rows
+    n_key_tiles = N // KEY_TILE
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(S, n_row_tiles, N // KEY_TILE),
+        num_scalar_prefetch=3,
+        grid=(S, n_key_tiles, n_row_tiles),
         in_specs=[
-            pl.BlockSpec((1, rows * J, D), lambda s, t, n, last: (s, t, 0)),
-            pl.BlockSpec((1, rows * J, 1), lambda s, t, n, last: (s, t, 0)),
-            pl.BlockSpec((1, KEY_TILE, D), lambda s, t, n, last: (
-                s, jnp.minimum(n, last[s * n_row_tiles + t]), 0)),
+            pl.BlockSpec((1, rows * J, D), lambda s, n, t, *_: (s, t, 0)),
+            pl.BlockSpec((1, rows * J, 1), lambda s, n, t, *_: (s, t, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, rows, KEY_TILE),
-                               lambda s, t, n, last: (s, t, n)),
+                               lambda s, n, t, *_: (s, t, n)),
+        scratch_shapes=[pltpu.VMEM((2, per, bs, D), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32)],
     )
     return pl.pallas_call(
-        functools.partial(_index_kernel, rows=rows, heads=J,
+        functools.partial(_index_kernel, rows=rows, heads=J, per=per,
+                          n_slots=S, n_key_tiles=n_key_tiles,
                           n_row_tiles=n_row_tiles),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, T, N), jnp.float32),
@@ -120,36 +221,54 @@ def _index_score_call(q, w, keys, last, *, T, interpret=False):
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=32 * 1024 * 1024),
         interpret=interpret,
-    )(last, q, w, keys)
+    )(tables, last, tiles, q, w, pool)
 
 
-def index_scores(q, w, keys, positions, use_kernel: Optional[bool] = None,
-                 interpret: bool = False):
-    """Index scores of query rows against a slot's keys.
+def index_scores(q, w, pool, tables, positions, *, block_size: int,
+                 use_kernel: Optional[bool] = None, interpret=False):
+    """Index scores of query rows against their slot's paged keys.
 
     ``q [S, T, J, D]`` (``J`` index heads), ``w [S, T, J]`` float32 head
-    weights, ``keys [S, N, D]`` the slot's index keys by position,
-    ``positions [S, T]`` each row's own position. Returns ``[S, T, N]``
-    float32: ``sum_j w * relu(q_j . k_c)`` for every ``c`` up to the key
-    tile that holds the row tile's last position; what lies past it reads
-    0 there and anything in the jnp form: the caller masks ``c >
-    positions``. Operands in ``keys``' dtype, float32 accumulation."""
+    weights, ``pool [num_blocks, block_size, D]`` the index keys,
+    ``tables [S, max_blocks]``, ``positions [S, T]`` each row's own
+    position. Returns ``[S, T, N]`` float32 with ``N`` the table's
+    positions rounded up to whole key tiles: ``sum_j w * relu(q_j . k_c)``
+    for every ``c`` up to the key tile that holds the row tile's last
+    position, where ``c`` lies in a mapped block up to the slot's last
+    row; what lies elsewhere reads 0 past that tile and anything before
+    it: the caller masks ``c > positions`` and unmapped blocks. Operands
+    in the pool's dtype, float32 accumulation. The kernel copies a slot's
+    blocks through its table row, those up to its last row; the jnp form
+    gathers every block."""
     S, T, J, D = q.shape
-    N = keys.shape[1]
+    bs = int(block_size)
+    per = KEY_TILE // bs if KEY_TILE % bs == 0 else 1
+    tab = jnp.pad(tables.astype(jnp.int32),
+                  ((0, 0), (0, -tables.shape[1] % per)), constant_values=-1)
+    N = tab.shape[1] * bs
     rows = min(T, _ROW_TILE)
     if use_kernel is None:
         use_kernel = _on_tpu()
-    use_kernel = bool(use_kernel or interpret) and N % KEY_TILE == 0 \
+    use_kernel = bool(use_kernel or interpret) and KEY_TILE % bs == 0 \
         and T % rows == 0 and D % 128 == 0 and (rows * J) % 8 == 0
     count_path("index_scores", "pallas" if use_kernel else "reference")
-    q = q.astype(keys.dtype)
+    q = q.astype(pool.dtype)
     if use_kernel:
-        last = jnp.max(positions.reshape(S, T // rows, rows), axis=-1) \
-            // KEY_TILE
-        last = jnp.clip(last, 0, N // KEY_TILE - 1).astype(jnp.int32)
+        NT = N // KEY_TILE
+        top = jnp.max(positions, axis=1)
+        tab = jnp.where(jnp.arange(tab.shape[1])[None, :]
+                        <= (top // bs)[:, None], tab, -1)
+        last = jnp.clip(jnp.max(positions.reshape(S, T // rows, rows),
+                                axis=-1) // KEY_TILE, 0, NT - 1)
+        copies = jnp.sum(tab.reshape(S, NT, per) >= 0, axis=-1,
+                         dtype=jnp.int32)
+        tiles = jnp.where(jnp.arange(NT)[None, :]
+                          <= jnp.max(last, axis=1, keepdims=True), copies, -1)
         return _index_score_call(
             q.reshape(S, T * J, D), w.astype(jnp.float32).reshape(S, T * J, 1),
-            keys, last.reshape(-1), T=T, interpret=bool(interpret))
+            pool, tab, last.reshape(-1).astype(jnp.int32),
+            tiles.reshape(-1), T=T, interpret=interpret)
+    keys = pool[jnp.maximum(tab, 0)].reshape(S, N, D)
     s = jnp.einsum("stjd,snd->stjn", q, keys,
                    preferred_element_type=jnp.float32)
     return jnp.einsum("stjn,stj->stn", jnp.maximum(s, 0.0),

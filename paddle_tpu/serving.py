@@ -359,6 +359,10 @@ class PagedLlamaDecodeEngine:
         # positions a row attends at most where the model selects them
         # (learned sparse attention; None: every visible position)
         self.select_k = getattr(self._m, "select_k", None)
+        # layers whose rows score every live position of their slot through
+        # the block table (a learned indexer's keys, pool `index`)
+        self.index_layers = sum("index" in sp["pools"]
+                                for sp in self.cache_spec)
 
         dt = jnp.bfloat16 if str(cfg.dtype) == "bfloat16" else jnp.float32
         self.dtype = dt
@@ -1021,7 +1025,8 @@ class PagedLlamaDecodeEngine:
         """What a prompt chunk's turn did, for the loop's span and
         flight event; with window layers, also the positions one of
         them reads for these rows; with selected attention, the (row,
-        position) pairs a layer attends."""
+        position) pairs a layer attends and the key blocks the index
+        kernel copies."""
         out = {"start": start, "tokens": tokens, "bucket": bucket}
         if self.window is not None:
             out["window_tokens"] = start + tokens \
@@ -1031,6 +1036,11 @@ class PagedLlamaDecodeEngine:
             below = max(min(self.select_k - start, tokens), 0)
             out["selected_tokens"] = below * start \
                 + below * (below + 1) // 2 + (tokens - below) * self.select_k
+        if self.index_layers:
+            # the key blocks the index kernel copies, its rows' slot read
+            # through the table to the last row
+            out["index_blocks"] = -(-(start + tokens) // self.block_size) \
+                * self.index_layers
         if self._stateful:
             # the sub-chunks the model's chunk form takes for these rows
             out["state_subchunks"] = -(-tokens // self._m.state_subchunk)
@@ -2386,6 +2396,12 @@ class GenerationServer:
         if select_k is not None:
             # what a layer that attends selected positions reads of them
             out["selected_tokens"] = int(np.minimum(ctx, select_k).sum())
+        index_layers = getattr(eng, "index_layers", 0)
+        if index_layers:
+            # the key blocks the index kernel copies for them: each slot's
+            # blocks up to its row, in every indexer layer
+            out["index_blocks"] = int(
+                (-(-ctx // eng.block_size)).sum()) * index_layers
         if getattr(eng, "_stateful", False):
             # slots whose state the launch reads and writes, a state layer
             out["state_slots"] = int(ctx.size)

@@ -1262,20 +1262,17 @@ def paged_index_scores(q, w, k_pool, tables, positions, *, block_size: int,
     [S, T]``. Returns ``(scores [S, T, N] float32, valid [S, T, N])`` with
     ``N >= max_blocks * block_size`` (whole key tiles): ``scores[s, t, c]
     = sum_j w_j relu(q_j . k_c)`` and ``valid`` where ``c <= positions[s,
-    t]`` lies in a mapped block. A slot's keys are gathered through its
-    table row into position order (XLA), then scored by
-    ``ops.pallas.sparse_latent.index_scores``."""
+    t]`` lies in a mapped block. Scored by
+    ``ops.pallas.sparse_latent.index_scores``, which reads the keys where
+    they lie, through the table: a slot's blocks up to its last row."""
     from .ops.pallas import sparse_latent as _sl
-    S, MB = tables.shape
-    per = _sl.KEY_TILE // block_size if _sl.KEY_TILE % block_size == 0 else 1
-    pad = -MB % per
-    tab = jnp.pad(tables, ((0, 0), (0, pad)), constant_values=-1)
-    N = (MB + pad) * block_size
-    keys = k_pool[jnp.maximum(tab, 0)].reshape(S, N, k_pool.shape[-1])
-    scores = _sl.index_scores(q, w, keys, positions, use_kernel=use_kernel)
-    cols = jnp.arange(N)
-    mapped = jnp.repeat(tab >= 0, block_size, axis=1)            # [S, N]
-    valid = (cols[None, None, :] <= positions[:, :, None]) \
+    scores = _sl.index_scores(q, w, k_pool, tables, positions,
+                              block_size=block_size, use_kernel=use_kernel)
+    N = scores.shape[-1]
+    mapped = jnp.pad(tables >= 0, ((0, 0), (0, N // block_size
+                                             - tables.shape[1])))
+    mapped = jnp.repeat(mapped, block_size, axis=1)              # [S, N]
+    valid = (jnp.arange(N)[None, None, :] <= positions[:, :, None]) \
         & mapped[:, None, :]
     return scores, valid
 

@@ -424,25 +424,97 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 # -- the kernels, through the Pallas interpreter ---------------------------------
 
-@pytest.mark.parametrize("S,T", [(3, 1), (1, 16)])
-def test_the_index_score_kernel_matches_its_jnp_form(S, T):
-    rng = np.random.default_rng(S * 10 + T)
-    J, D, N = 8, 128, 2 * sl.KEY_TILE
-    q = jnp.asarray(rng.normal(size=(S, T, J, D)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(S, T, J)), jnp.float32)
-    keys = jnp.asarray(rng.normal(size=(S, N, D)), jnp.float32)
-    pos = jnp.asarray(rng.integers(0, N, (S, T)), jnp.int32)
-    want = np.asarray(sl.index_scores(q, w, keys, pos, use_kernel=False))
-    got = np.asarray(sl.index_scores(q, w, keys, pos, interpret=True))
+def _index_case(name):
+    """(q, w, pool, tables, positions) of an index-score case: blocks of 64, 8
+    index heads of 128, tables drawn at random from the pool (no two blocks of
+    a slot need be neighbours), -1 past a slot's mapped blocks."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    bs, J, D, NB = 64, 8, 128, 96
+    if name == "decode_32_slots":
+        pos = np.concatenate([[0, 63, 64, 2047, 2048, 30000],
+                              rng.integers(0, 3000, 26)])
+        pos[-1] = 0
+    elif name == "chunk_512":           # rows 3900..4411 over three key tiles
+        pos = 3900 + np.arange(512)
+    elif name == "rows_16":             # two row tiles a slot
+        pos = np.sort(rng.integers(0, 5000, (2, 16)), axis=1)
+    else:
+        pos = np.asarray([5000, 100, 4095])
+    pos = pos.reshape(len(pos), -1) if pos.ndim == 2 else (
+        pos[None] if name == "chunk_512" else pos[:, None])
+    S, T = pos.shape
+    MB = 480                            # 30,720 positions, 15 key tiles
+    tables = rng.integers(0, NB, (S, MB)).astype(np.int32)
+    if name == "shuffled_table":        # a slot's blocks in falling order
+        tables[:] = (NB - 1 - np.arange(MB) % NB)[None]
+    mapped = np.maximum(pos.max(axis=1), 0) // bs + 1
+    if name == "unmapped_past_mapped":  # 20 blocks mapped under a row at 5000
+        mapped[0] = 20
+    if name == "decode_32_slots":
+        mapped[-1] = 0                  # a free slot: no live row
+    tables[np.arange(MB)[None, :] >= mapped[:, None]] = -1
+    f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    return (f32(S, T, J, D), f32(S, T, J), f32(NB, bs, D), jnp.asarray(tables),
+            jnp.asarray(pos, jnp.int32))
+
+
+INDEX_CASES = ["decode_32_slots", "shuffled_table", "unmapped_past_mapped",
+               "rows_16", "chunk_512"]
+
+
+def _index_kernel_against_its_jnp_form(name, interpret):
+    q, w, pool, tables, pos = _index_case(name)
+    bs = pool.shape[1]
+    got = np.asarray(sl.index_scores(q, w, pool, tables, pos, block_size=bs,
+                                     interpret=interpret))
+    tab, pos = np.asarray(tables), np.asarray(pos)
+    S, T = pos.shape
+    assert got.shape == (S, T, tab.shape[1] * bs)
     for s in range(S):
+        want = np.asarray(sl.index_scores(
+            q[s:s + 1], w[s:s + 1], pool, tables[s:s + 1], pos[s:s + 1],
+            block_size=bs, use_kernel=False))[0]
+        cols = np.arange(got.shape[-1])
         for t in range(T):
-            p = int(pos[s, t])
-            assert np.abs(got[s, t, :p + 1] - want[s, t, :p + 1]).max() \
-                < 1e-3 * np.abs(want[s, t]).max()
-    # a key tile past every row's position is not scored: zeros
-    low = jnp.minimum(pos, sl.KEY_TILE - 1)
-    assert (np.asarray(sl.index_scores(q, w, keys, low, interpret=True))
-            [..., sl.KEY_TILE:] == 0).all()
+            ok = (cols <= pos[s, t]) & (tab[s, cols // bs] >= 0)
+            if ok.any():
+                assert np.abs(got[s, t, ok] - want[t, ok]).max() \
+                    < 1e-3 * np.abs(want[t, ok]).max()
+        # key tiles past the row tile's last position are not scored: zeros
+        for t0 in range(0, T, min(T, 8)):
+            last = max(pos[s, t0:t0 + 8].max(), 0) // sl.KEY_TILE
+            assert (got[s, t0:t0 + 8, (last + 1) * sl.KEY_TILE:] == 0).all()
+
+
+@pytest.mark.parametrize("name", INDEX_CASES)
+def test_the_index_score_kernel_matches_its_jnp_form(name):
+    """The kernel reads a slot's keys through its table row (interpreted) and
+    scores every valid position as the jnp form does after its gather."""
+    _index_kernel_against_its_jnp_form(name, True)
+
+
+@pytest.mark.parametrize("name", ["decode_32_slots", "chunk_512"])
+def test_the_index_score_kernel_waits_for_the_bytes_it_started(name):
+    """`InterpretParams` counts a semaphore's bytes as the chip does: a wait
+    for more than was started blocks for ever (the call runs in a thread, so
+    the test fails and does not hang), one for less leaves keys behind."""
+    import threading
+    from jax.experimental.pallas import tpu as pltpu
+    err = []
+
+    def run():
+        try:
+            _index_kernel_against_its_jnp_form(name, pltpu.InterpretParams())
+        except BaseException as e:     # noqa: BLE001 — reported below
+            err.append(e)
+        else:
+            err.append(None)
+    call = threading.Thread(daemon=True, target=run)
+    call.start()
+    call.join(300)
+    assert err, "a wait that no copy satisfies"
+    if err[0] is not None:
+        raise err[0]
 
 
 @pytest.mark.parametrize("S,T", [(3, 1), (2, 16), (1, 8)])
@@ -495,6 +567,31 @@ def test_the_spans_count_the_selected_positions():
     llama = PagedLlamaDecodeEngine(LlamaForCausalLM(LlamaConfig.tiny()),
                                    max_slots=2, max_seq=32, block_size=4)
     assert "selected_tokens" not in llama._chunk_counts(0, 5, 8)
+
+
+def test_the_spans_count_the_index_blocks_the_kernel_copies():
+    """`index_blocks`: each slot's key blocks up to its last row, in each of
+    the two indexer layers (`full`, three `shared`, `full`), on a decode span
+    and a prompt chunk's; a model with no indexer carries none."""
+    _, eng = _engine()
+    assert eng.index_layers == 2
+    for start, tokens in [(0, 1), (0, 8), (3, 2), (8, 8), (40, 3)]:
+        assert eng._chunk_counts(start, tokens, 8)["index_blocks"] \
+            == 2 * -(-(start + tokens) // 4)
+    srv = GenerationServer(eng)
+    try:
+        eng.pos[:] = [3, 20, 11]
+        eng.active[:] = [True, True, False]
+        # blocks of 4: position 3 reads block 0, position 20 blocks 0..5
+        assert srv._launch_counts()["index_blocks"] == 2 * (1 + 6)
+        eng.pos[:] = 0
+        eng.active[:] = False
+    finally:
+        srv.shutdown(drain=False, timeout=30)
+    llama = PagedLlamaDecodeEngine(LlamaForCausalLM(LlamaConfig.tiny()),
+                                   max_slots=2, max_seq=32, block_size=4)
+    assert llama.index_layers == 0
+    assert "index_blocks" not in llama._chunk_counts(0, 5, 8)
 
 
 def test_a_launch_hands_back_the_positions_its_rows_attended(monkeypatch):

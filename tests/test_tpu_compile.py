@@ -219,10 +219,11 @@ def test_the_sparse_latent_kernels_compile_for_the_v5e(one_chip, name):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
 
-    compiled = jax.jit(lambda q, w, keys, pos: sl.index_scores(
-        q, w, keys, pos, use_kernel=True)).lower(
+    compiled = jax.jit(lambda q, w, pool, tables, pos: sl.index_scores(
+        q, w, pool, tables, pos, block_size=bs, use_kernel=True)).lower(
         sds((S, T, 32, 128), "bfloat16"), sds((S, T, 32), "float32"),
-        sds((S, N, 128), "bfloat16"), sds((S, T), "int32")).compile()
+        sds((NB, bs, 128), "bfloat16"), sds((S, MB), "int32"),
+        sds((S, T), "int32")).compile()
     # the trace and the benchmark's readers find the kernels by these names
     assert "tpu_custom_call" in compiled.as_text()
     assert "_index_score_call" in compiled.as_text()
@@ -234,6 +235,28 @@ def test_the_sparse_latent_kernels_compile_for_the_v5e(one_chip, name):
         sds((S, T), "int32")).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert "_latent_attention_call" in compiled.as_text()
+
+
+def test_a_decode_step_scores_its_index_keys_where_they_lie(one_chip):
+    """The index scores of a 32-slot decode step read the keys through the
+    block table: no array of every slot's keys in position order (until PR 36
+    XLA gathered them, `bf16[25600,64,128]`, 419 MB a layer) and a few MB of
+    temporaries."""
+    bs, NB, MB, S = 64, 7968, 776, 32
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    compiled = jax.jit(lambda q, w, pool, tables, pos: sc.paged_index_scores(
+        q, w, pool, tables, pos, block_size=bs, use_kernel=True)).lower(
+        sds((S, 1, 32, 128), "bfloat16"), sds((S, 1, 32), "float32"),
+        sds((NB, bs, 128), "bfloat16"), sds((S, MB), "int32"),
+        sds((S, 1), "int32")).compile()
+    text = compiled.as_text()
+    assert "_index_score_call" in text
+    for shape in ("[25600,64,128]", "[32,51200,128]"):
+        assert shape not in text, shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 @pytest.mark.parametrize("width", [640, 128])
