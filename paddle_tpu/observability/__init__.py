@@ -7,7 +7,9 @@ plane (``timeline.StepTimer``) whose counter events merge into
 ``profiler.export_chrome_tracing``, and an always-on flight recorder
 (``flight``: bounded black-box event journal + crash-forensics dumps,
 ``FLAGS_flight_recorder``; see ``python -m paddle_tpu.observability
---flight``).
+--flight``), and the host's own pauses (``host``: every Python garbage
+collection as a ``host.gc`` span and a count by generation, hooked into
+``gc.callbacks`` on import).
 
 Quick tour::
 
@@ -37,12 +39,15 @@ from .metrics import (  # noqa: F401
 )
 from .timeline import StepTimer  # noqa: F401
 from . import flight  # noqa: F401  (after metrics/timeline: it uses both)
+from . import host  # noqa: F401
+
+host.install()
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "Scope",
     "DEFAULT_BUCKETS", "counter", "gauge", "histogram", "scope",
     "default_registry", "enabled", "register_collector", "snapshot",
-    "render_prometheus", "StepTimer", "metrics", "timeline", "flight",
+    "render_prometheus", "StepTimer", "metrics", "timeline", "flight", "host",
     "start_metrics_server",
 ]
 

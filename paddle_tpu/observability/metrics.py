@@ -368,12 +368,14 @@ class Registry:
     def scope(self, prefix: str) -> Scope:
         return Scope(self, prefix)
 
-    def register_collector(self, name: str, fn: Callable) -> None:
+    def register_collector(self, name: str, fn: Callable,
+                           label: str = "key") -> None:
         """``fn() -> {dotted_name: number | {label_value: number}}``,
-        polled only at snapshot/exposition time. Re-registering a name
+        polled only at snapshot/exposition time; ``label`` names the
+        label of a dict's values in the exposition. Re-registering a name
         replaces the callback (module reload safety)."""
         with self._lock:
-            self._collectors[name] = fn
+            self._collectors[name] = (fn, label)
 
     def get(self, name: str) -> Optional[_Instrument]:
         with self._lock:
@@ -390,16 +392,21 @@ class Registry:
             inst.reset()
 
     # -- collection ------------------------------------------------------
-    def _collected(self) -> Dict[str, Any]:
+    def _collected(self, labels: Optional[Dict[str, str]] = None
+                   ) -> Dict[str, Any]:
+        """Every collector's view, merged; ``labels`` (if given) gets each
+        name's label name."""
         out: Dict[str, Any] = {}
         with self._lock:
-            items = list(self._collectors.items())
-        for cname, fn in items:
+            items = list(self._collectors.values())
+        for fn, label in items:
             try:
                 part = fn() or {}
             except Exception:  # noqa: BLE001 — one bad view can't kill all
                 continue
             out.update(part)
+            if labels is not None:
+                labels.update(dict.fromkeys(part, label))
         return out
 
     # -- snapshot --------------------------------------------------------
@@ -487,15 +494,16 @@ class Registry:
                         f"{mname}{_labels_str(key)} "
                         f"{_fmt_num(float(v))}")
         # collectors render as untyped counters
-        for name, v in sorted(self._collected().items()):
+        labels: Dict[str, str] = {}
+        for name, v in sorted(self._collected(labels).items()):
             mname = _sanitize(name.replace(".", "_"))
             lines.append(f"# TYPE {mname} counter")
             if isinstance(v, dict):
-                # single implicit label named after the trailing name
-                # segment's subject ("key")
+                # one label, named by the collector ("key" by default)
+                label = _sanitize(labels[name])
                 for lv, n in sorted(v.items(), key=lambda kv: str(kv[0])):
                     lines.append(
-                        f'{mname}{{key="{_escape_label(str(lv))}"}} '
+                        f'{mname}{{{label}="{_escape_label(str(lv))}"}} '
                         f"{_fmt_num(float(n))}")
             else:
                 lines.append(f"{mname} {_fmt_num(float(v))}")
@@ -529,8 +537,8 @@ def scope(prefix: str) -> Scope:
     return _default.scope(prefix)
 
 
-def register_collector(name: str, fn: Callable) -> None:
-    _default.register_collector(name, fn)
+def register_collector(name: str, fn: Callable, label: str = "key") -> None:
+    _default.register_collector(name, fn, label)
 
 
 def snapshot() -> Dict[str, Any]:

@@ -58,13 +58,25 @@ iteration and a prompt chunk each come as two halves (``step_enqueue`` /
 launch leaves on the device are the next launch's ``last_ids`` there
 (``_next_ids``), and what can be known by counting (``pos``, ``max_new``,
 the tables) is decided at enqueue. Only an EOS needs the token: it is seen
-one launch late, and that launch's token for its slot is dropped."""
+one launch late, and that launch's token for its slot is dropped.
+
+**What an operator reads** (``GenerationServer.metrics_endpoint``:
+``/metrics``), beside the load gauges and latency histograms:
+``serving.failed_total`` and ``deadline_expired_total`` (requests lost, and
+lost to the clock), ``weight_swaps_total`` / ``weight_swaps_rejected_total``
+(whether a deploy landed), ``spec_accepted_total`` over
+``spec_proposed_total`` (a draft's acceptance rate), ``prefix_hits_total``
+and ``prefix_tokens_reused_total`` (the prefill the radix tree saves), and
+``fetch_stalls_total{kind}`` (fetches that outlasted their launch by far,
+each with a ``serving/fetch_stall`` record: ``_FetchWatch``)."""
 from __future__ import annotations
 
+import collections
 import functools
 import itertools
 import math
 import queue as _queue
+import resource
 import threading
 import time
 from typing import Dict, List, Optional
@@ -74,6 +86,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .observability import flight as _flight
+from .observability import host as _host
 from .observability import metrics as _om
 from .profiler import RecordEvent as _span
 from .utils import fault_injection as _fi
@@ -147,15 +160,6 @@ _M_swap_s = _M.histogram(
     "swap_seconds",
     "Wall seconds a weight hot-swap held the decode loop at its step "
     "boundary (weight prep + validation + install)")
-# which implementation the paged_attention seam runs (decided once per
-# engine at program-build time; the compiled steps bake the path in)
-_M_pa_kernel = _M.counter(
-    "paged_attention_kernel_steps_total",
-    "Engine steps whose attention ran the Pallas block-table kernel")
-_M_pa_fallback = _M.counter(
-    "paged_attention_fallback_steps_total",
-    "Engine steps whose attention ran the pure-jnp tile walk (the "
-    "CPU/oracle fallback)")
 # content-addressed prefix sharing (PagedKVCache radix tree):
 # hits/reuse counted at TARGET admission only — an attached draft
 # mirrors every admission, so counting both engines would double
@@ -190,6 +194,11 @@ _M_overrun = _M.counter(
     "overrun_tokens_total",
     "Tokens launched for a request and dropped because it had ended "
     "(EOS, deadline, failure) by the time they were fetched")
+_M_stalls = _M.counter(
+    "fetch_stalls_total",
+    "Fetches that took over max(50 ms, 3 x the moving mean of their "
+    "kind's fetches), by kind (decode/prefill); each left a "
+    "serving/fetch_stall flight event")
 
 # process-unique request trace ids: every lifecycle event of a request
 # carries one, so a flight dump (or GenerationServer.trace) replays a
@@ -223,6 +232,93 @@ def _feed_programs():
     from .jit.sot import jit_named
     return (jit_named(_feed_impl, "serving.feed"),
             jit_named(_feed_first_impl, "serving.feed_first"))
+
+
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
+_metrics_on = _om.flag_info()
+
+
+class _FetchWatch:
+    """How long one engine's fetches take, and a record of each that
+    outlasts its launch by far: a STALL, a fetch longer than
+    ``max(STALL_FLOOR_S, STALL_OVER_MEAN x`` the moving mean of the
+    fetches of its kind that were not). Off with ``FLAGS_metrics``. A
+    fetch's span gets ``ready`` (1: the device had finished before the
+    host arrived, so the whole fetch was host time). A stall sets
+    ``stall=1`` on its span, adds 1 to ``serving.fetch_stalls_total{kind}``
+    and writes the flight event ``serving/fetch_stall``: ``kind``, ``ms``,
+    ``mean_ms``, ``ready``, ``since_enqueue_ms``, ``step`` (the decode
+    launch's number; for a prompt's first token, that of the decode launch
+    its chunk runs after), ``gc_ms`` / ``gc_full`` (collection time and
+    generation-2 collections during the fetch, any thread), and over the
+    fetch the loop thread's ``cpu_ms``, involuntary context switches
+    (``invol_switches``) and ``major_faults``. The newest
+    ``RECORDS_KEPT`` records stay in :meth:`stats`.
+
+    Reading a record: ``gc_ms`` about ``ms``, the collector; ``ready`` 1
+    and ``gc_ms`` about 0, other Python held the interpreter or the copy
+    to the host was slow; ``ready`` 0, little ``cpu_ms`` and switches or
+    faults that jump, the machine; otherwise the runtime did not run the
+    launch."""
+
+    STALL_FLOOR_S = 0.05
+    STALL_OVER_MEAN = 3.0
+    MEAN_WEIGHT = 0.05          # each fetch moves the mean a twentieth
+    RECORDS_KEPT = 64
+
+    def __init__(self):
+        self.mean_s: Dict[str, float] = {}
+        self.stalls = 0
+        self.max_ms = 0.0
+        self.records = collections.deque(maxlen=self.RECORDS_KEPT)
+
+    @staticmethod
+    def begin(handle, span) -> Optional[tuple]:
+        """At the fetch's entry, inside its span: whether the device is
+        done with ``handle``, and the marks a stall's record is taken
+        against (None with metrics off)."""
+        if not _metrics_on.value:
+            return None
+        ready = int(handle.is_ready())
+        span.set(ready=ready)
+        return (ready, time.perf_counter(), sum(_host.seconds),
+                _host.counts[2], resource.getrusage(_RUSAGE_THREAD))
+
+    def end(self, mark: Optional[tuple], span, kind: str,
+            t_enqueue: float, step: int) -> None:
+        """At the fetch's end, inside its span: a stall is recorded, any
+        other fetch moves its kind's mean."""
+        if mark is None:
+            return
+        ready, t0, gc0, full0, ru0 = mark
+        now = time.perf_counter()
+        took = now - t0
+        mean = self.mean_s.get(kind)
+        if mean is None or took <= max(self.STALL_FLOOR_S,
+                                       self.STALL_OVER_MEAN * mean):
+            self.mean_s[kind] = took if mean is None \
+                else mean + self.MEAN_WEIGHT * (took - mean)
+            return
+        ru1 = resource.getrusage(_RUSAGE_THREAD)
+        rec = {"kind": kind, "ms": 1e3 * took, "mean_ms": 1e3 * mean,
+               "ready": ready, "since_enqueue_ms": 1e3 * (now - t_enqueue),
+               "step": step, "gc_ms": 1e3 * (sum(_host.seconds) - gc0),
+               "gc_full": _host.counts[2] - full0,
+               "cpu_ms": 1e3 * (ru1.ru_utime + ru1.ru_stime
+                                - ru0.ru_utime - ru0.ru_stime),
+               "invol_switches": ru1.ru_nivcsw - ru0.ru_nivcsw,
+               "major_faults": ru1.ru_majflt - ru0.ru_majflt}
+        span.set(stall=1)
+        _M_stalls.inc(kind=kind)
+        _flight.record("serving", "fetch_stall", **rec)
+        self.stalls += 1
+        self.max_ms = max(self.max_ms, rec["ms"])
+        self.records.append(rec)
+
+    def stats(self) -> dict:
+        return {"fetch_stalls": self.stalls,
+                "fetch_stall_max_ms": round(self.max_ms, 3),
+                "fetch_stall_records": list(self.records)}
 
 
 class PagedLlamaDecodeEngine:
@@ -389,9 +485,9 @@ class PagedLlamaDecodeEngine:
         from . import serving_cache as _sc
         self._sc = _sc
         # the implementation behind the paged_attention seam — Pallas
-        # kernel vs jnp walk — is decided here ONCE so the per-step path
-        # counters report what the compiled programs actually baked in
-        # (None where the cache has no per-head pool: that seam is not run)
+        # kernel vs jnp walk — is decided here ONCE, for every program the
+        # engine builds (None where the cache has no per-head pool: that
+        # seam is not run)
         self._pa_kernel = None if self.head_dim is None \
             else _sc.use_kernel_default(self.head_dim)
         self._draft: Optional["PagedLlamaDecodeEngine"] = None
@@ -456,6 +552,10 @@ class PagedLlamaDecodeEngine:
         self._ahead: Optional[dict] = None
         self._first_dev: Dict[int, object] = {}
         self._feed, self._feed_first = _feed_programs()
+        # numbers the decode launches, and times the fetches: one far past
+        # its kind's mean is a stall, and goes on record
+        self._launch_seq = itertools.count(1)
+        self._fetches = _FetchWatch()
         # how many times each slot has been activated: a launch keeps
         # the numbers it stepped, and its collect writes last_ids only
         # where the slot still holds that activation (a slot released
@@ -628,6 +728,11 @@ class PagedLlamaDecodeEngine:
                 "state_slots_in_use": len(self._prefill_state)
                 + int(self.active.sum()),
                 "state_bytes": self._state_bytes}
+
+    def host_stats(self) -> dict:
+        """This engine's fetch stalls (``_FetchWatch``): their count, the
+        longest in ms and the newest records."""
+        return self._fetches.stats()
 
     def pool_blocks_in_use(self) -> Dict[str, int]:
         """Blocks mapped to slots by pool name, summed over the layers
@@ -877,14 +982,6 @@ class PagedLlamaDecodeEngine:
         while b < n:
             b *= 2
         return min(b, self.max_seq)
-
-    def _count_pa_path(self, n: int = 1) -> None:
-        """Per-step accounting of which implementation the
-        paged_attention seam ran — Pallas kernel vs jnp walk, decided
-        once at program-build time (``_pa_kernel``), so the counters
-        report what the compiled steps actually baked in."""
-        if self._pa_kernel is not None:
-            (_M_pa_kernel if self._pa_kernel else _M_pa_fallback).inc(n)
 
     def make_draft(self, model,
                    num_layers: Optional[int] = None
@@ -1200,7 +1297,10 @@ class PagedLlamaDecodeEngine:
                 draft.prefill_enqueue(slot)
             self._defer_aux(tok)
             return None
-        first = {"slot": slot, "tok": tok, "aux": self._unfetched_aux()}
+        # a chunk is numbered by the decode launch it runs after
+        first = {"slot": slot, "tok": tok, "aux": self._unfetched_aux(),
+                 "t_enqueue": time.perf_counter(),
+                 "step": (self._ahead or {}).get("step", 0)}
         del self._prefill_state[slot]
         self.pos[slot] = n
         self.active[slot] = True
@@ -1222,10 +1322,13 @@ class PagedLlamaDecodeEngine:
         decode launch has read it there already: that launch's collect
         writes what follows it."""
         slot = first["slot"]
-        with _span("serving.prefill.fetch"):
+        with _span("serving.prefill.fetch") as span:
+            mark = self._fetches.begin(first["tok"], span)
             toks, counts = self._take_aux(np.asarray(first["tok"]),
                                           first["aux"])
             tok = int(toks.reshape(-1)[0])
+            self._fetches.end(mark, span, "prefill", first["t_enqueue"],
+                              first["step"])
         if self._first_dev.get(slot) is first["tok"]:
             del self._first_dev[slot]
             self.last_ids[slot, 0] = tok
@@ -1314,7 +1417,6 @@ class PagedLlamaDecodeEngine:
                     draft._tables_dev(), act)
             nxt, self.kvs, selected = self._decode(
                 self.params, self.kvs, ids, pos, tables, act)
-        self._count_pa_path()
         self.pos[self.active] += 1
         if draft is not None:
             draft.pos[self.active] = self.pos[self.active]
@@ -1326,7 +1428,9 @@ class PagedLlamaDecodeEngine:
         slots = np.flatnonzero(self.active)
         launch = {"out": nxt, "act": act, "slots": slots,
                   "activation": self._activation[slots],
-                  "aux": self._unfetched_aux(), "selected": selected}
+                  "aux": self._unfetched_aux(), "selected": selected,
+                  "t_enqueue": time.perf_counter(),
+                  "step": next(self._launch_seq)}
         self._ahead = launch
         return launch
 
@@ -1338,9 +1442,12 @@ class PagedLlamaDecodeEngine:
         in the batch for the activation the launch stepped: a slot
         released and taken again meanwhile holds another request's
         token, which this launch's must not overwrite."""
-        with _span("serving.decode.fetch"):
+        with _span("serving.decode.fetch") as span:
+            mark = self._fetches.begin(launch["out"], span)
             nxt, counts = self._take_aux(np.asarray(launch["out"]),
                                          launch["aux"])
+            self._fetches.end(mark, span, "decode", launch["t_enqueue"],
+                              launch["step"])
         if self._ahead is launch:
             self._ahead = None
         draft = self._draft
@@ -1429,7 +1536,6 @@ class PagedLlamaDecodeEngine:
         t, n_acc, self.kvs = self._spec_verify(
             self.params, self.kvs, last, draft_tok, pos,
             jnp.asarray(self._kv.block_tables), act)
-        self._count_pa_path()
         toks = np.asarray(t)
         acc = np.asarray(n_acc)
         counts = np.minimum(acc + 1, k).astype(np.int32)
@@ -1453,8 +1559,6 @@ class PagedLlamaDecodeEngine:
             _M_spec_accepted.inc(accepted)
         if rolled:
             _M_spec_rolled.inc(rolled)
-        _flight.record("serving", "spec_step", proposed=proposed,
-                       accepted=accepted, rolled_back=rolled)
         return toks, counts
 
     def decode_steps(self, n: int) -> np.ndarray:
@@ -1496,7 +1600,6 @@ class PagedLlamaDecodeEngine:
                 tables, act)
             ids = nxt[:, None]
             pos = pos + 1
-        self._count_pa_path(n)
         toks = np.asarray(buf)                      # the one fetch
         self.pos += n
         self.last_ids = toks[:, -1:].astype(np.int32).copy()
@@ -2787,4 +2890,8 @@ class GenerationServer:
         state = getattr(self.engine, "state_stats", dict)()
         if state:       # beside the blocks: the state's slots and bytes
             out["kv_pool"] = dict(out["kv_pool"], **state)
+        # where the host paused: the process's collections by generation
+        # ([collections, seconds, longest ms]), the engine's fetch stalls
+        out["host"] = {"gc": _host.gc_stats(),
+                       **getattr(self.engine, "host_stats", dict)()}
         return out

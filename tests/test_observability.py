@@ -517,3 +517,124 @@ class TestIntegration:
         with wd.span("unit_span"):
             pass
         assert _M_span_s.value(name="unit_span")["count"] == c0 + 1
+
+
+# ---------------------------------------------------------------------------
+# the host's own pauses: the collector's hook and the long fetch
+# ---------------------------------------------------------------------------
+
+class TestHostPauses:
+    def test_importing_and_installing_again_leave_one_hook(self):
+        import gc
+        import importlib
+        from paddle_tpu.observability import host
+        importlib.reload(obs)           # the package installs it on import
+        host.install()
+        mine = [cb for cb in gc.callbacks
+                if getattr(cb, "__module__", None) == host.__name__]
+        assert mine == [host._on_gc]
+
+    def test_a_collection_inside_a_metric_s_lock_does_not_hang(self):
+        """A collection can start inside any `with lock:` of its thread,
+        an instrument's own `series()` included: with a collection after
+        every allocation, snapshots and the exposition still return."""
+        import gc
+        import threading
+        done = threading.Event()
+
+        def scrape():
+            for _ in range(300):
+                obs.snapshot()
+                obs.render_prometheus()
+            done.set()
+
+        threshold = gc.get_threshold()
+        gc.set_threshold(1)
+        try:
+            t = threading.Thread(target=scrape, daemon=True)
+            t.start()
+            t.join(60)
+        finally:
+            gc.set_threshold(*threshold)
+        assert done.is_set(), "a collection hung inside a metric's lock"
+
+    def test_the_collections_are_exposed_by_generation(self):
+        import gc
+        from paddle_tpu.observability import host
+        gc.disable()        # no collection between the two readings
+        try:
+            gc.collect(1)
+            text = obs.render_prometheus()
+            counts = list(host.counts)
+        finally:
+            gc.enable()
+        for g in range(3):
+            assert (f'host_gc_collections_total{{generation="{g}"}} '
+                    f'{counts[g]}') in text
+        assert obs.snapshot()["host"]["gc_pause_max_seconds"][1] > 0
+
+    def test_a_long_fetch_leaves_one_record(self):
+        """20 fast fetches, then one that sleeps 40 ms (under the 50-ms
+        floor: no record), then one that sleeps 120 ms: one `fetch_stall`
+        event with every field, one count, and `stats()["host"]` has it.
+        Each launch is done before its fetch, and automatic collections are
+        held off, so a fetch takes what it sleeps."""
+        import gc
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.observability import flight
+        from paddle_tpu.serving import GenerationServer, PagedLlamaDecodeEngine
+        paddle.seed(7)
+        model = LlamaForCausalLM(LlamaConfig.tiny(
+            vocab_size=64, hidden_size=32, intermediate_size=64,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, use_flash_attention=False))
+        eng = PagedLlamaDecodeEngine(model, max_slots=2, max_seq=64,
+                                     block_size=8, prefill_chunk=8)
+        eng.prefill(0, np.arange(1, 6, dtype=np.int32), budget=40)
+        take, sleep = eng._take_aux, {"s": 0.0}
+
+        def slow_take(*args):
+            time.sleep(sleep["s"])
+            return take(*args)
+
+        eng._take_aux = slow_take
+        stalls = obs.default_registry().get("serving.fetch_stalls_total")
+
+        def fetch(seconds):
+            sleep["s"] = seconds
+            launch = eng.step_enqueue()
+            launch["out"].block_until_ready()
+            eng.step_collect(launch)
+
+        gc.disable()
+        try:
+            for _ in range(20):
+                fetch(0.0)
+            n0, c0 = flight.appended(), stalls.value(kind="decode")
+            fetch(0.04)
+            assert flight.appended() == n0
+            assert stalls.value(kind="decode") == c0
+            fetch(0.12)
+            k = flight.appended() - n0
+            new = flight.events()[-k:] if k else []
+        finally:
+            gc.enable()
+        (ev,) = [e for e in new if e["name"] == "fetch_stall"]
+        rec = ev["attrs"]
+        assert set(rec) == {"kind", "ms", "mean_ms", "ready",
+                            "since_enqueue_ms", "step", "gc_ms", "gc_full",
+                            "cpu_ms", "invol_switches", "major_faults"}
+        assert rec["kind"] == "decode" and rec["ready"] == 1
+        assert rec["ms"] >= 120 and rec["since_enqueue_ms"] >= rec["ms"]
+        assert rec["mean_ms"] < 50 and rec["step"] == 22
+        assert rec["gc_ms"] == 0 and rec["gc_full"] == 0
+        assert stalls.value(kind="decode") == c0 + 1
+        srv = GenerationServer(eng)
+        try:
+            host = srv.stats()["host"]
+        finally:
+            srv.shutdown()
+        assert host["fetch_stalls"] == 1
+        assert host["fetch_stall_max_ms"] == pytest.approx(rec["ms"], abs=1e-3)
+        assert host["fetch_stall_records"] == [rec]
+        assert len(host["gc"]) == 3 and all(len(g) == 3 for g in host["gc"])

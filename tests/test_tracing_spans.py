@@ -1,12 +1,14 @@
-"""The program's own spans (ISSUE 26): one primitive, `profiler.RecordEvent`,
-writes the native host plane and, while a `jax.profiler` trace runs, the
-`/host:CPU` plane of the same `.xplane.pb` as the device operations.
+"""The program's own spans: one primitive, `profiler.RecordEvent`, writes the
+native host plane and, while a `jax.profiler` trace runs, the `/host:CPU`
+plane of the same `.xplane.pb` as the device operations; every Python garbage
+collection is such a span too (`host.gc`, `observability/host.py`).
 
 A module-scoped traced run of a tiny paged server (a few requests, a weight
 swap, an idle park) is read back with `jax.profiler.ProfileData`; the tests
 look at names, attributes, nesting, the counts on the spans against the
 flight recorder's own trail, and that tracing changes no served token.
 """
+import gc
 import glob
 import json
 import math
@@ -21,7 +23,8 @@ import paddle_tpu as paddle
 from paddle_tpu import profiler
 from paddle_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
                                      LlamaPretrainingCriterion)
-from paddle_tpu.observability import clock, flight, timeline
+from paddle_tpu.observability import clock, flight, host, timeline
+from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.profiler import Profiler, RecordEvent
 from paddle_tpu.serving import GenerationServer, PagedLlamaDecodeEngine
 
@@ -112,12 +115,12 @@ SERVING_SPANS = {
     "serving.admit": {"admitted"},
     "serving.prefill": {"trace_id", "slot", "start", "tokens", "bucket"},
     "serving.prefill.enqueue": set(),
-    "serving.prefill.fetch": set(),
+    "serving.prefill.fetch": {"ready"},
     "serving.decode": {"step", "rows", "live_tokens", "max_ctx",
                        "walk_tokens", "spec"},
     "serving.decode.prepare": set(),
     "serving.decode.enqueue": set(),
-    "serving.decode.fetch": set(),
+    "serving.decode.fetch": {"ready"},
     "serving.commit": {"tokens"},
     "serving.sweep": set(),
     "serving.swap": set(),
@@ -130,10 +133,22 @@ def test_a_traced_run_has_every_serving_span_with_its_attributes(traced, name):
     spans = _named(traced, name)
     assert spans, f"no {name} span in the trace"
     for _, _, _, stats, _ in spans:
-        # a launch enqueued while the last was not fetched says so
-        assert set(stats) - {"ahead"} == SERVING_SPANS[name], (name, stats)
+        # a launch enqueued while the last was not fetched says so, and
+        # so does a fetch far past its kind's mean (a stall)
+        assert set(stats) - {"ahead", "stall"} == SERVING_SPANS[name], \
+            (name, stats)
         assert stats.get("ahead", 1) == 1 and \
             ("ahead" not in stats or name == "serving.decode")
+        assert stats.get("stall", 1) == 1 and \
+            ("stall" not in stats or name.endswith(".fetch"))
+
+
+def test_a_fetch_span_says_whether_the_device_was_done(traced):
+    """`ready` is read as the fetch begins: 1 where the device had finished
+    the launch before the host came for it, so the whole fetch was host
+    time."""
+    for name in ("serving.decode.fetch", "serving.prefill.fetch"):
+        assert {s[3]["ready"] for s in _named(traced, name)} <= {0, 1}
 
 
 def test_most_decode_launches_go_out_ahead_of_the_last_ones_fetch(traced):
@@ -376,6 +391,57 @@ def test_record_event_nests_lifo_in_both_planes_at_once(tmp_path):
     assert durs[0] < durs[1] and durs[1] >= 3000
     (a0, a1, *_), (b0, b1, *_) = xplane
     assert a0 <= b0 and b1 <= a1
+
+
+# -- the host's own pauses ---------------------------------------------------
+
+def _gc_counts():
+    """Collections and their seconds by generation, as the registry shows
+    them."""
+    snap = obs_metrics.snapshot()["host"]
+    return ([snap["gc_collections_total"][g] for g in range(3)],
+            [snap["gc_seconds_total"][g] for g in range(3)])
+
+
+def test_a_collection_is_a_span_on_the_thread_that_collected(tmp_path):
+    """`gc.collect(2)` under a trace: one `host.gc` span, generation 2, inside
+    the span that called it on its own line; the counts grow by generation
+    (automatic collections are held off meanwhile, so it is the only one)."""
+    n0, s0 = _gc_counts()
+    gc.disable()
+    _trace(tmp_path)
+    try:
+        with RecordEvent("t37.outer"):
+            gc.collect(2)
+    finally:
+        jax.profiler.stop_trace()
+        gc.enable()
+    spans = _host_spans(str(tmp_path), prefixes=("t37.", "host."))
+    (outer,) = [x for x in spans if x[2] == "t37.outer"]
+    (coll,) = [x for x in spans if x[2] == "host.gc"]
+    assert coll[3]["generation"] == 2
+    assert {"collected", "uncollectable"} <= set(coll[3])
+    assert outer[0] <= coll[0] and coll[1] <= outer[1] and coll[4] == outer[4]
+    n1, s1 = _gc_counts()
+    assert n1 == [n0[0], n0[1], n0[2] + 1]
+    assert s1[2] > s0[2] and s1[:2] == s0[:2]
+    assert host.gc_stats()[2][:2] == [n1[2], round(s1[2], 6)]
+    assert host.gc_stats()[2][2] > 0
+
+
+def test_with_metrics_off_a_collection_leaves_neither_span_nor_count(tmp_path):
+    n0, s0 = _gc_counts()
+    gc.disable()
+    paddle.set_flags({"FLAGS_metrics": 0})
+    _trace(tmp_path)
+    try:
+        gc.collect(2)
+    finally:
+        jax.profiler.stop_trace()
+        paddle.set_flags({"FLAGS_metrics": 1})
+        gc.enable()
+    assert _host_spans(str(tmp_path), prefixes=("host.",)) == []
+    assert _gc_counts() == (n0, s0)
 
 
 def test_record_event_without_any_trace_is_cheap_and_silent():
