@@ -24,3 +24,6 @@ from .glm_moe_dsa import (  # noqa: F401
 from .solar_open2 import (  # noqa: F401
     SolarOpen2Config, SolarOpen2ForCausalLM,
 )
+from .brumby import (  # noqa: F401
+    BrumbyConfig, BrumbyForCausalLM,
+)

@@ -427,10 +427,12 @@ class PagedLlamaDecodeEngine:
         self._state_names = list(dict.fromkeys(
             n for sp in self.cache_spec for n in sp.get("state", {})))
         self._stateful = bool(self._state_names)
-        self._state_bytes = int(max_slots) * sum(
+        # every layer's state of one slot, and of the pool
+        self.state_slot_bytes = sum(
             math.prod(shape) * jnp.dtype(dt).itemsize
             for sp in self.cache_spec
             for shape, dt in sp.get("state", {}).values())
+        self._state_bytes = int(max_slots) * self.state_slot_bytes
         if self._stateful and kv_quant == "int8":
             raise NotImplementedError(
                 "an int8 KV pool is not built for a model with state layers")
@@ -506,9 +508,15 @@ class PagedLlamaDecodeEngine:
         kinds = sorted({sp["kind"] for sp in self.cache_spec
                         if sp["pools"] or not sp.get("state")})
         # a model with window layers gets a table and an allocator a
-        # kind; every other model the one table it always had
+        # kind; a model whose every layer keeps a state a slot gets
+        # neither (it is admitted by slot); every other model the one
+        # table it always had
         self._kinded = kinds not in ([], ["full"])
-        if self._kinded:
+        self._pooled = bool(kinds)
+        if not self._pooled:
+            self.num_blocks = 0
+            self._kv = _sc.SlotStates()
+        elif self._kinded:
             if self.kv_quant == "int8":
                 raise NotImplementedError(
                     "an int8 KV pool is not built for window layers")
@@ -1083,6 +1091,8 @@ class PagedLlamaDecodeEngine:
         def dev(t):
             return jnp.asarray((t if slot is None else t[slot]).copy())
         bt = self._kv.block_tables
+        if bt is None:                  # no block pool: nothing to read
+            return None
         if not self._kinded:
             return dev(bt)
         return {k: dev(t) for k, t in bt.items()}
@@ -1368,7 +1378,10 @@ class PagedLlamaDecodeEngine:
     def _extend_tables(self) -> None:
         """Step-boundary block extension: map the block covering each
         active slot's next write position (drawn from its admission
-        reservation, so this cannot fail)."""
+        reservation, so this cannot fail). A model with no block pool
+        has nothing to map."""
+        if not self._pooled:
+            return
         for s in range(self.max_slots):
             if self.active[s]:
                 self._shared_write_guard(s)
@@ -1591,7 +1604,7 @@ class PagedLlamaDecodeEngine:
                 name="serving.paged_decode_window")
         ids = jnp.asarray(self.last_ids)
         pos = jnp.asarray(self.pos)
-        tables = jnp.asarray(self._kv.block_tables)
+        tables = self._tables_dev()
         act = jnp.asarray(self.active)
         buf = jnp.zeros((self.max_slots, n), jnp.int32)
         for i in range(n):
@@ -2508,6 +2521,9 @@ class GenerationServer:
         if getattr(eng, "_stateful", False):
             # slots whose state the launch reads and writes, a state layer
             out["state_slots"] = int(ctx.size)
+            # the state bytes the launch reads and writes: every layer's of
+            # each slot it steps, once each way
+            out["state_bytes_moved"] = 2 * int(ctx.size) * eng.state_slot_bytes
         return out
 
     def _sweep(self) -> None:

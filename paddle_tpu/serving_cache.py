@@ -56,7 +56,7 @@ import numpy as np
 from .observability import flight as _flight
 from .observability import metrics as _om
 
-__all__ = ["PagedKVCache", "KindedKVCache", "paged_attention",
+__all__ = ["PagedKVCache", "KindedKVCache", "SlotStates", "paged_attention",
            "write_kv_tokens", "absmax_quantize", "use_kernel_default",
            "copy_block"]
 
@@ -941,6 +941,74 @@ class KindedKVCache:
 
     def active_tokens(self, pos: np.ndarray, active: np.ndarray) -> int:
         return next(iter(self.kinds.values())).active_tokens(pos, active)
+
+
+class SlotStates:
+    """The host side of a model whose every layer keeps a state a SLOT and
+    no block pool: no block table, no allocator. A request is admitted by
+    its slot alone (the state pool holds every slot's state from boot), so
+    admission never waits for memory and a request's length is bounded by
+    positions, not blocks. It answers the calls an engine makes of a block
+    cache as a cache with no blocks: nothing to map, reserve, roll back or
+    share."""
+
+    block_tables = None            # nothing for a launch to upload
+    num_blocks = 0
+    prefix_enabled = False
+
+    def __init__(self):
+        self._held: set = set()
+        self.evictions = 0
+
+    def available_blocks(self) -> int:
+        return 0
+
+    def used_blocks(self) -> int:
+        return 0
+
+    def admit(self, slot: int, prompt_tokens: int, total_tokens: int,
+              token_ids=None) -> bool:
+        slot = int(slot)
+        if slot in self._held:
+            raise ValueError(f"slot {slot} already holds a request")
+        self._held.add(slot)
+        _flight.record("serving", "slot_admit", slot=slot,
+                       tokens=int(total_tokens))
+        return True
+
+    def release(self, slot: int, evicted: bool = False) -> int:
+        slot = int(slot)
+        if slot in self._held:
+            self._held.discard(slot)
+            self.evictions += bool(evicted)
+            _flight.record("serving", "slot_free", slot=slot,
+                           evicted=bool(evicted))
+        return 0
+
+    # nothing is reserved ahead of a write or shared
+    def reserve_through(self, slot: int, pos: int) -> None:
+        return None
+
+    def matched_tokens(self, slot: int) -> int:
+        return 0
+
+    def take_cow(self, slot: int):
+        return None
+
+    def cow_for_write(self, slot: int, pos: int):
+        return None
+
+    def commit_prefix(self, slot: int, token_ids, tokens_written: int) -> int:
+        return 0
+
+    def reset_prefix_cache(self) -> int:
+        return 0
+
+    def stats(self) -> Dict[str, int]:
+        """No blocks: the slots that hold a request and the evictions (the
+        engine's ``state_stats`` adds the state pool's slots and bytes)."""
+        return {"num_blocks": 0, "blocks_used": 0, "blocks_reserved": 0,
+                "slots_held": len(self._held), "evictions": self.evictions}
 
 
 # ---------------------------------------------------------------------------

@@ -491,6 +491,7 @@ def test_the_spans_count_the_states_and_a_launch_hands_back_the_expert_counts():
         counts = srv._launch_counts()
         assert counts["rows"] == 2 and counts["state_slots"] == 2
         assert counts["live_tokens"] == 4 + 21 and "walk_tokens" in counts
+        assert counts["state_bytes_moved"] == 2 * 2 * eng.state_slot_bytes
         eng.pos[:] = 0
         eng.active[:] = False
     finally:

@@ -456,3 +456,125 @@ def test_the_engine_s_programs_keep_a_float32_state_in_place(
     assert len(calls) == 2 and fed == pools, (fed, pools)
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= 2 * NS * 64 * 128 * 128 * 4
+
+
+# power retention (Brumby): a 32-slot step over the served state (8 KV heads of
+# 8,256 x 128 float32 a slot, the pool 1.08 GB a layer), a 512-row chunk and the
+# smallest chunk
+RETENTION = {"step_32_slots": None, "chunk_512": 512, "chunk_8": 8}
+
+
+@pytest.mark.parametrize("name", sorted(RETENTION))
+def test_the_retention_kernels_compile_for_the_v5e(one_chip, name):
+    from paddle_tpu.ops.pallas import power_retention as pr
+    NS, Hk, r, d, T = 32, 8, 5, 128, RETENTION[name]
+    D = pr.feature_dim(d)
+
+    def sds(shape, dtype="float32"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    pools = (sds((NS, Hk, D, d)), sds((NS, Hk, D)))
+    if T is None:
+        compiled = jax.jit(
+            lambda S, z, q, k, v, g, act: pr.retention_step(
+                S, z, q, k, v, g, act, use_kernel=True),
+            donate_argnums=(0, 1)).lower(
+            *pools, sds((NS, Hk * r, d)), sds((NS, Hk, d)), sds((NS, Hk, d)),
+            sds((NS, Hk)), sds((NS,), "bool")).compile()
+    else:
+        compiled = jax.jit(
+            lambda S, z, slot, fresh, q, k, v, g: pr.retention_chunk(
+                S, z, slot, fresh, q, k, v, g, use_kernel=True),
+            donate_argnums=(0, 1)).lower(
+            *pools, sds((), "int32"), sds((), "bool"), sds((T, Hk * r, d)),
+            sds((T, Hk, d)), sds((T, Hk, d)), sds((T, Hk))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the trace and the benchmark's readers find the kernels by these names
+    assert ("_retention_step_call" if T is None
+            else "_retention_chunk_call") in text
+    # both pools are the kernel's inputs and its outputs: updated in place
+    # (z's rows of 8,256 lanes are padded to whole 128-lane tiles)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == NS * Hk * (D * d + 8320) * 4
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_512"])
+def test_the_retention_engine_keeps_its_float32_state_in_place(
+        one_chip, monkeypatch, program):
+    """The ENGINE's own decode and chunk programs for Brumby (`_decode_impl`,
+    `_prefill_impl` through `BrumbyServe.layer`) at the served widths, two
+    layers, 32 slots, the pools donated and no block table: each layer's `S`
+    and `z` go through exactly one `_retention_step_call` (decode) or
+    `_retention_chunk_call` (chunk) that takes the donated float32 pool and
+    hands it back aliased, and the program holds no other array of the
+    expanded width (8,256) in any dtype: phi of the rows formed in HBM, or a
+    state rounded or copied on the way, would be one. The benchmark's
+    `recurrence_gap` drives these two calls at these shapes on the chip
+    (`benchmark/runners/serve_paged_state.py` `recurrence_probe`)."""
+    from paddle_tpu.models import brumby as bm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    L, NS = 2, 32
+    cfg = bm.BrumbyConfig(num_hidden_layers=L, vocab_size=1024,
+                          dtype="bfloat16")
+    eng = object.__new__(PagedLlamaDecodeEngine)
+    eng._m = bm.BrumbyServe(cfg)
+    eng.cache_spec = eng._m.cache_spec(L)
+    eng._stateful, eng.block_size, eng.kv_quant, eng._sc = True, 16, None, sc
+    eng._pa_kernel, eng.dtype, eng.n_layers = None, jnp.bfloat16, L
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+    H = cfg.hidden_size
+    params = {"emb": sds((1024, H), "bfloat16"), "norm": sds((H,), "bfloat16"),
+              "head": sds((1024, H), "bfloat16"),
+              "layers": [{k: sds(s, "bfloat16")
+                          for k, s in bm.layer_shapes(cfg).items()}
+                         for _ in range(L)]}
+    kvs = {n: [sds((NS,) + shape, dt)] * L
+           for n, (shape, dt) in eng.cache_spec[0]["state"].items()}
+    if program == "decode":
+        compiled = jax.jit(eng._decode_impl, donate_argnums=(1,)).lower(
+            params, kvs, sds((NS, 1), "int32"), sds((NS,), "int32"), None,
+            sds((NS,), "bool")).compile()
+        call = "_retention_step_call"
+    else:
+        scalar = sds((), "int32")
+        compiled = jax.jit(eng._prefill_impl, donate_argnums=(1,)).lower(
+            params, kvs, sds((1, 512), "int32"), None, scalar, scalar, scalar,
+            scalar).compile()
+        call = "_retention_chunk_call"
+    text = compiled.as_text()
+    pool = {r"%d,8,8256,128" % NS, r"%d,8,8256" % NS}
+    # every array of the expanded width, by dtype, shape and maker
+    wide = re.findall(r"= \(?(\w+)\[([\d,]*8256[\d,]*)\]\S* ([\w-]+)\(", text)
+    assert {(dt, shape) for dt, shape, _ in wide} \
+        == {("f32", shape) for shape in pool}, sorted(set(wide))
+    # made by nothing but the pools' own passing; XLA may hold the small z
+    # pool (8.5 MB) in VMEM across the call, a copy in and out of it
+    assert {(shape, op) for _, shape, op in wide} <= {
+        (shape, op) for shape in pool
+        for op in ("parameter", "get-tuple-element", "custom-call")} | {
+        ("%d,8,8256" % NS, "copy-done")}, sorted(set(wide))
+    params_in = set(re.findall(r"%%(\S+) = f32\[(?:%s)\]\S* parameter\("
+                               % "|".join(pool), text))
+    assert len(params_in) == 2 * L              # S and z of each layer
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and call in line]
+    # an operand that XLA moved to VMEM is the pool it was copied from
+    moved = dict(re.findall(r"%(\S+) = \S+ copy-done\(%(\S+)\)", text))
+    moved = {done: src for done, start in moved.items() for src in re.findall(
+        r"%%%s = .* copy-start\(%%(\S+)\)" % re.escape(start), text)}
+    fed = set()
+    for line in calls:
+        # each of the call's first two outputs is the operand it names, and
+        # that operand is the donated pool itself: nothing stands between
+        operands = line.split("custom-call(")[1].split(")")[0]
+        operands = re.sub(r"/\*.*?\*/", "", operands).split(", ")
+        for at in re.findall(r"\{(\d)\}: \((\d+), \{\}\)", line):
+            name = operands[int(at[1])].lstrip("%")
+            fed.add(moved.get(name, name))
+    assert len(calls) == L and fed == params_in, (fed, params_in)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= L * NS * 8 * (8256 * 128 + 8256) * 4

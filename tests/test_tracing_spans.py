@@ -598,3 +598,35 @@ def test_train_step_scopes_reach_the_lowered_program():
     for scope in ("llama.embed", "llama.attn", "llama.mlp", "llama.head",
                   "loss", "optimizer"):
         assert scope in text, scope
+
+
+def test_a_state_only_model_s_spans_count_the_state_they_move(tmp_path):
+    """Brumby (every layer a state a slot, no block pool): each `serving.decode`
+    span carries `state_slots`, the slots its launch steps, and
+    `state_bytes_moved`, every layer's state of those slots read and
+    written; each `serving.prefill` span carries `state_subchunks`, its
+    chunk's rows in the chunk kernel's row tiles. No span counts a walk of
+    blocks."""
+    from paddle_tpu.models import BrumbyConfig, BrumbyForCausalLM
+    paddle.seed(3)
+    eng = PagedLlamaDecodeEngine(BrumbyForCausalLM(BrumbyConfig.tiny()),
+                                 max_slots=2, max_seq=256, prefill_chunk=16)
+    srv = GenerationServer(eng)
+    _serve(srv, [list(range(40, 50))])          # compile outside the trace
+    _trace(tmp_path)
+    try:
+        _serve(srv, [list(range(1, 40)), [5, 9, 11]])
+    finally:
+        jax.profiler.stop_trace()
+        srv.shutdown()
+    spans = _host_spans(str(tmp_path))
+    decodes = [st for _, _, name, st, _ in spans if name == "serving.decode"]
+    prefills = [st for _, _, name, st, _ in spans if name == "serving.prefill"]
+    assert len(decodes) >= NEW and len(prefills) >= 3
+    for st in decodes:
+        assert st["state_slots"] == st["rows"]
+        assert st["state_bytes_moved"] \
+            == 2 * st["rows"] * eng.state_slot_bytes
+        assert "walk_tokens" not in st
+    assert {st["state_subchunks"] for st in prefills} == {1}
+    assert sorted(st["tokens"] for st in prefills) == [3, 7, 16, 16]
